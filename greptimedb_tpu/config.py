@@ -162,7 +162,7 @@ DEFAULTS: dict = {
         # everywhere (interpret mode off-TPU); off keeps the XLA paths.
         "pallas_kernels": "auto",
         "pallas_min_series": 4096,      # kernel grid floor (stay XLA below)
-        "pallas_min_rows": 262144,      # fused merge-gather row floor
+        "pallas_min_rows": 262144,      # kernel row paths below this stay XLA
         "pallas_max_k": 128,            # topk merge kernel O(k^2) cap
     },
     # secondary tag-index dataplane (index/): per-region inverted
@@ -296,8 +296,9 @@ DEFAULTS: dict = {
     # information_schema.device_programs, /debug/prof/device and
     # gtpu_device_program_* metrics; reset with ADMIN
     # reset_device_profiler(). peak_tflops / peak_hbm_gbps at 0 mean
-    # auto: TPU backends default to v5e single-chip numbers (197
-    # TFLOP/s bf16, 819 GB/s HBM); CPU runs report achieved-only.
+    # auto: a TPU's peaks come from device_programs.DEVICE_PEAKS by
+    # its device_kind (v5e: 197 TFLOP/s bf16, 819 GB/s HBM); a TPU
+    # kind not in the table and every CPU run report achieved-only.
     # analysis=false skips the lazy XLA cost/memory analysis (rows
     # keep per-call stats only). trace_dir is where
     # /debug/prof/device/trace?seconds= writes its TensorBoard/

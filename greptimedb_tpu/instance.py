@@ -152,34 +152,43 @@ _ADMITTED_STATEMENTS = (
     A.Select, A.SetOp, A.Tql, A.Insert, A.Delete, A.Copy, A.Explain,
 )
 
-_xla_cache_enabled = False
+_compile_cache_dir: str | None = None
 
 
-def _enable_xla_persistent_cache(data_root: str):
-    """Persist XLA compilations under the data dir so a restarted process
-    skips recompiles (the reference has no compile step; this removes the
-    cold-start cliff unique to the XLA design). First instance in the
-    process wins — the cache is content-addressed, so sharing is safe."""
-    global _xla_cache_enabled
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory, so a restarted process skips recompiles (the
+    reference has no compile step; this removes the cold-start cliff
+    unique to the XLA design).
+
+    One rule: `JAX_COMPILATION_CACHE_DIR`, when set, places the cache
+    and no directory is set in code; otherwise the cache lives at the
+    fixed `<checkout>/.jax_cache` next to this package. The path is
+    part of the cache key's environment, so it never derives from a
+    data home, a temp dir, a pid or the clock — a cache that moves
+    never hits. Both size/time thresholds are lowered either way so
+    the many small query programs are cached too. Failure raises: a
+    server that silently recompiles everything is a different
+    deployment."""
+    global _compile_cache_dir
     import os
 
-    if _xla_cache_enabled or os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        return
-    try:
-        import jax
+    if _compile_cache_dir is not None:
+        return _compile_cache_dir
+    import jax
 
-        path = os.path.join(os.path.abspath(data_root), ".xla_cache")
-        # jax won't create the directory itself; a missing dir turns
-        # every cache write into a warning
-        os.makedirs(path, exist_ok=True)
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache",
+        )
         jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        _xla_cache_enabled = True
-    except Exception as e:  # noqa: BLE001
-        # purely a warm-start optimisation; run uncached without it
-        logging.getLogger("greptimedb_tpu.instance").debug(
-            "xla persistent cache unavailable: %s", e)
+    os.makedirs(path, exist_ok=True)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    _compile_cache_dir = path
+    return path
 
 
 class Standalone:
@@ -194,7 +203,7 @@ class Standalone:
                  cold_store=None):
         cfg = engine_config or EngineConfig(data_root=data_root,
                                             enable_background=False)
-        _enable_xla_persistent_cache(cfg.data_root)
+        enable_compile_cache()
         self.engine = TsdbEngine(cfg, store=store, cold_store=cold_store)
         self.catalog = CatalogManager(self.engine)
         self.query_engine = QueryEngine(prefer_device=prefer_device,
@@ -245,8 +254,9 @@ class Standalone:
 
                     warm_from_snapshots(self.query_engine, self.catalog)
                 except Exception as e:  # noqa: BLE001
-                    # cold caches are only slower, never wrong
-                    logging.getLogger("greptimedb_tpu.instance").debug(
+                    # cold caches are only slower, never wrong — but a
+                    # restart that rebuilds every grid must say why
+                    logging.getLogger("greptimedb_tpu.instance").warning(
                         "device cache warm-start skipped: %s", e)
 
             concurrency.Thread(

@@ -21,7 +21,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from greptimedb_tpu.ops import segment as S
@@ -131,5 +131,5 @@ def build_distributed_query_step(
         mesh=mesh,
         in_specs=(P(None, AXIS_SHARD, AXIS_TIME), P(AXIS_SHARD, AXIS_TIME)),
         out_specs=(P(None, AXIS_SHARD, None), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )

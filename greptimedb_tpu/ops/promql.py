@@ -59,9 +59,9 @@ def eval_range_function(
     if name == "avg_over_time":
         return W.window_avg(vals, has, lo, hi)
     if name == "min_over_time":
-        return W.window_minmax(vals, has, tsg, hi, l_cells, "min")
+        return W.window_minmax(vals, has, tsg, lo, hi, l_cells, "min")
     if name == "max_over_time":
-        return W.window_minmax(vals, has, tsg, hi, l_cells, "max")
+        return W.window_minmax(vals, has, tsg, lo, hi, l_cells, "max")
     if name == "last_over_time":
         v, _, p = W.window_last(vals, has, tsg, lo, hi)
         return jnp.where(p, v, 0), p
@@ -77,17 +77,17 @@ def eval_range_function(
         absent = cnt == 0
         return absent.astype(vals.dtype), absent
     if name == "stddev_over_time":
-        _, sd, p = W.window_stdvar(vals, has, tsg, hi, l_cells)
+        _, sd, p = W.window_stdvar(vals, has, tsg, lo, hi, l_cells)
         return sd, p
     if name == "stdvar_over_time":
-        var, _, p = W.window_stdvar(vals, has, tsg, hi, l_cells)
+        var, _, p = W.window_stdvar(vals, has, tsg, lo, hi, l_cells)
         return var, p
     if name == "quantile_over_time":
         (phi,) = args
-        return W.window_quantile(vals, has, tsg, hi, l_cells, phi)
+        return W.window_quantile(vals, has, tsg, lo, hi, l_cells, phi)
     if name == "mad_over_time":
-        med, p = W.window_quantile(vals, has, tsg, hi, l_cells, 0.5)
-        g_vals, g_has, _ = W.gather_windows(vals, has, tsg, hi, l_cells)
+        med, p = W.window_quantile(vals, has, tsg, lo, hi, l_cells, 0.5)
+        g_vals, g_has, _ = W.gather_windows(vals, has, tsg, lo, hi, l_cells)
         dev = jnp.abs(g_vals - med[:, :, None])
         dev = jnp.where(g_has, dev, jnp.inf)
         sorted_dev = jnp.sort(dev, axis=2)
@@ -104,20 +104,22 @@ def eval_range_function(
     if name == "resets":
         return W.window_pair_count(vals, has, lo, hi, count_changes=False)
     if name == "deriv":
-        slope, _, n = W.window_linear_fit(vals, has, tsg, hi, t_end, l_cells, tps)
+        slope, _, n = W.window_linear_fit(
+            vals, has, tsg, lo, hi, t_end, l_cells, tps
+        )
         p = n >= 2
         return jnp.where(p, slope, 0), p
     if name == "predict_linear":
         (horizon_s,) = args
         slope, intercept, n = W.window_linear_fit(
-            vals, has, tsg, hi, t_end, l_cells, tps
+            vals, has, tsg, lo, hi, t_end, l_cells, tps
         )
         p = n >= 2
         out = intercept + slope * jnp.asarray(horizon_s, vals.dtype)
         return jnp.where(p, out, 0), p
     if name == "holt_winters":
         sf, tf = args
-        return W.window_holt_winters(vals, has, tsg, hi, l_cells, sf, tf)
+        return W.window_holt_winters(vals, has, tsg, lo, hi, l_cells, sf, tf)
     raise ValueError(f"unsupported range function: {name}")
 
 

@@ -315,12 +315,15 @@ def instant_delta(vals, has, tsg, lo, hi, tps, *, is_rate: bool):
 # ----------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("num_cells",))
-def gather_windows(vals, has, tsg, hi, num_cells: int):
+def gather_windows(vals, has, tsg, lo, hi, num_cells: int):
     """Materialize (S, J, L) window tensors: cell hi_j - k for k in [0, L).
-    Cells are in reverse time order (k=0 is the window end)."""
+    Cells are in reverse time order (k=0 is the window end). A window is
+    the cells (lo_j, hi_j]: where hi_j was clipped to the grid's last
+    cell (a step past the end of the data) it holds fewer than L cells,
+    and the lanes at or below lo_j are masked out."""
     k = jnp.arange(num_cells, dtype=jnp.int32)
     idx = hi[None, :, None] - k[None, None, :]        # (1, J, L)
-    ok = idx >= 0
+    ok = (idx >= 0) & (idx > lo[None, :, None])
     idx_s = jnp.maximum(idx, 0)
     g_vals = jnp.take(vals, idx_s[0], axis=1)          # (S, J, L)
     g_has = jnp.take(has, idx_s[0], axis=1) & ok[0]
@@ -329,8 +332,8 @@ def gather_windows(vals, has, tsg, hi, num_cells: int):
 
 
 @functools.partial(jax.jit, static_argnames=("num_cells", "op"))
-def window_minmax(vals, has, tsg, hi, num_cells: int, op: str):
-    g_vals, g_has, _ = gather_windows(vals, has, tsg, hi, num_cells)
+def window_minmax(vals, has, tsg, lo, hi, num_cells: int, op: str):
+    g_vals, g_has, _ = gather_windows(vals, has, tsg, lo, hi, num_cells)
     dt = vals.dtype
     if op == "min":
         fill = jnp.asarray(jnp.inf, dt)
@@ -343,10 +346,11 @@ def window_minmax(vals, has, tsg, hi, num_cells: int, op: str):
 
 
 @functools.partial(jax.jit, static_argnames=("num_cells", "sample_var"))
-def window_stdvar(vals, has, tsg, hi, num_cells: int, *, sample_var: bool = False):
+def window_stdvar(vals, has, tsg, lo, hi, num_cells: int, *,
+                  sample_var: bool = False):
     """Population stddev/stdvar over each window (Prometheus semantics).
     Returns (var, stddev, present)."""
-    g_vals, g_has, _ = gather_windows(vals, has, tsg, hi, num_cells)
+    g_vals, g_has, _ = gather_windows(vals, has, tsg, lo, hi, num_cells)
     dt = vals.dtype
     n = jnp.sum(g_has, axis=2).astype(dt)
     n1 = jnp.maximum(n, 1)
@@ -376,10 +380,10 @@ def _small_sort_lanes(x, length: int):
 
 
 @functools.partial(jax.jit, static_argnames=("num_cells",))
-def window_quantile(vals, has, tsg, hi, num_cells: int, q):
+def window_quantile(vals, has, tsg, lo, hi, num_cells: int, q):
     """phi-quantile with linear interpolation (Prometheus
     quantile_over_time). q may be a scalar or (J,) array."""
-    g_vals, g_has, _ = gather_windows(vals, has, tsg, hi, num_cells)
+    g_vals, g_has, _ = gather_windows(vals, has, tsg, lo, hi, num_cells)
     dt = vals.dtype
     fill = jnp.asarray(jnp.inf, dt)
     masked = jnp.where(g_has, g_vals, fill)
@@ -414,10 +418,10 @@ def window_quantile(vals, has, tsg, hi, num_cells: int, q):
 
 
 @functools.partial(jax.jit, static_argnames=("num_cells",))
-def window_linear_fit(vals, has, tsg, hi, t_end, num_cells: int, tps):
+def window_linear_fit(vals, has, tsg, lo, hi, t_end, num_cells: int, tps):
     """Least-squares line over window samples; t is seconds relative to the
     window end (small, f32-safe). Returns (slope, intercept_at_end, n)."""
-    g_vals, g_has, g_ts = gather_windows(vals, has, tsg, hi, num_cells)
+    g_vals, g_has, g_ts = gather_windows(vals, has, tsg, lo, hi, num_cells)
     dt = vals.dtype
     t = (g_ts.astype(dt) - t_end[None, :, None].astype(dt)) / jnp.asarray(tps, dt)
     m = g_has.astype(dt)
@@ -434,12 +438,12 @@ def window_linear_fit(vals, has, tsg, hi, t_end, num_cells: int, tps):
 
 
 @functools.partial(jax.jit, static_argnames=("num_cells",))
-def window_holt_winters(vals, has, tsg, hi, num_cells: int, sf, tf):
+def window_holt_winters(vals, has, tsg, lo, hi, num_cells: int, sf, tf):
     """Double exponential smoothing (Prometheus holt_winters semantics:
     s0 = x0, b0 = x1 - x0, then s_i = sf*x_i + (1-sf)*(s+b),
     b_i = tf*(s_i - s_prev) + (1-tf)*b). Sequential over window samples,
     vectorized over (S, J) via lax.scan along the window axis."""
-    g_vals, g_has, _ = gather_windows(vals, has, tsg, hi, num_cells)
+    g_vals, g_has, _ = gather_windows(vals, has, tsg, lo, hi, num_cells)
     dt = vals.dtype
     # ascending time order: k = L-1 .. 0
     xs_vals = jnp.flip(g_vals, axis=2)

@@ -19,7 +19,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from greptimedb_tpu.ops import segment as S
@@ -55,7 +55,7 @@ def dist_segment_agg(mesh: Mesh, op: str, num_segments: int):
         mesh=mesh,
         in_specs=(P(AXIS_SHARD), P(AXIS_SHARD), P(AXIS_SHARD)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -134,7 +134,7 @@ def dist_topk(mesh: Mesh, k: int, *, largest: bool = True,
         mesh=mesh,
         in_specs=(P(AXIS_SHARD), P(AXIS_SHARD)),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
 
 

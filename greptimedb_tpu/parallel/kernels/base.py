@@ -1,16 +1,14 @@
 """Kernel availability, interpret-mode threading, and the ring harness.
 
-jax imports stay inside functions: the storage layer reaches this
-module through the fused compaction merge and must remain importable
-in processes without a device runtime.
+jax imports stay inside functions: the planner asks this module for the
+kernel mode in processes that must not pay a jax import for it.
 """
 
 from __future__ import annotations
 
 
 def native_available() -> bool:
-    """True when the Mosaic TPU compiler is behind pallas_call — the
-    async-remote-copy kernel variants only lower there."""
+    """True when the Mosaic TPU compiler is behind pallas_call."""
     import jax
 
     return jax.default_backend() == "tpu"
@@ -70,10 +68,7 @@ def sequential_ring(local, combine, ns: int, axis_name: str | None = None):
     The latches are jnp.where selects (no arithmetic — a select never
     flips -0.0 or perturbs NaN payloads). ppermute is the hop
     primitive: on TPU it lowers to the ICI collective-permute (an
-    async remote copy between neighbors); the in-kernel
-    make_async_remote_copy variant lives in ring_fold and is gated on
-    the native backend because interpret mode cannot express remote
-    DMAs.
+    async remote copy between neighbors).
     """
     import jax
     import jax.numpy as jnp

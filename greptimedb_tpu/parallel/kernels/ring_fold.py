@@ -9,26 +9,23 @@ ring while each shard folds its blocks on top in shard order, and the
 total walks once more so every shard ends with it — 2(ns-1) hops of
 g * nb elements. The fold bodies are Pallas kernels (the same
 unrolled static add chain as dist.left_fold_sum, so the bit-identity
-contract across mesh 1/2/4/8 is preserved by construction); the hops
-are ppermute (ICI collective-permute) in the interpret twin and
-in-kernel async remote copies on the native TPU backend.
+contract across mesh 1/2/4/8 is preserved by construction) — compiled
+by Mosaic on a TPU backend, run by the Pallas interpreter elsewhere —
+and the hops are ppermute (ICI collective-permute) on both.
 """
 
 from __future__ import annotations
 
-import functools
-
 from greptimedb_tpu.parallel.dist import ShardFoldCtx
 from greptimedb_tpu.parallel.kernels.base import (
     interpret_mode,
-    native_available,
     ring_comm_bytes,
     sequential_ring,
 )
 
 
 # ----------------------------------------------------------------------
-# kernel bodies (shared by the interpret twin and the native variants)
+# kernel bodies
 # ----------------------------------------------------------------------
 
 def _fold_seed_kernel(blocks_ref, out_ref):
@@ -96,8 +93,6 @@ def ring_fold_blocks(parts, ns: int, *, interpret: bool):
     """parts: the local (fb_local, g, nb) partial blocks of one shard.
     Returns the (g, nb) global fold, identical on every shard and
     bit-identical to dist.left_fold_sum(dist.gather_blocks(parts))."""
-    if not interpret and native_available():
-        return _tpu_ring_fold(parts, ns)
     seed = _call1(_fold_seed_kernel, parts, interpret=interpret)
 
     def cont(acc):
@@ -138,100 +133,6 @@ def fold_comm_bytes(ns: int, g: int, nb: int, passes: int = 1) -> int:
     """Declared inter-chip traffic of `passes` ring passes over a
     (g, nb) f32 accumulator."""
     return ring_comm_bytes(ns, 4 * int(g) * int(nb)) * max(int(passes), 1)
-
-
-# ----------------------------------------------------------------------
-# native TPU variant: the whole ring in one kernel via async remote
-# copies (SNIPPETS.md [2] / pallas guide ring pattern). Gated on the
-# Mosaic backend — jax 0.4.x interpret mode cannot trace
-# make_async_remote_copy, so the CPU twin above expresses the hops as
-# ppermute around the same fold kernel bodies.
-# ----------------------------------------------------------------------
-
-@functools.lru_cache(maxsize=8)
-def _tpu_ring_fold_call(ns: int, fb_local: int, g: int, nb: int,
-                        axis_name: str):
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    def kernel(parts_ref, out_ref, acc_ref, send_sem, recv_sem):
-        my = jax.lax.axis_index(axis_name)
-        right = jax.lax.rem(my + 1, ns)
-        left = jax.lax.rem(my + ns - 1, ns)
-        # neighbor barrier: both sides of each link must arrive before
-        # any RDMA lands in the double buffer
-        barrier = pltpu.get_barrier_semaphore()
-        pltpu.semaphore_signal(
-            barrier, inc=1, device_id=left,
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
-        )
-        pltpu.semaphore_signal(
-            barrier, inc=1, device_id=right,
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
-        )
-        pltpu.semaphore_wait(barrier, 2)
-        # seed: local left fold (same body as _fold_seed_kernel)
-        acc = parts_ref[0]
-        for i in range(1, fb_local):
-            acc = acc + parts_ref[i]
-        acc_ref[0] = acc
-        out_ref[...] = acc  # placeholder; every shard latches below
-        for step in range(2 * ns - 2):
-            send_slot = step % 2
-            recv_slot = (step + 1) % 2
-            rdma = pltpu.make_async_remote_copy(
-                src_ref=acc_ref.at[send_slot],
-                dst_ref=acc_ref.at[recv_slot],
-                send_sem=send_sem.at[send_slot],
-                recv_sem=recv_sem.at[recv_slot],
-                device_id=(right,),
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
-            )
-            rdma.start()
-            rdma.wait()
-            if step < ns - 1:
-                # fold phase: the shard whose turn it is continues the
-                # left fold; everyone else forwards what arrived
-                cont = acc_ref[recv_slot]
-                for i in range(fb_local):
-                    cont = cont + parts_ref[i]
-                turn = my == step + 1
-                acc_ref[recv_slot] = jnp.where(
-                    turn, cont, acc_ref[recv_slot]
-                )
-                if step == ns - 2:
-                    # the last fold turn (shard ns-1) holds the total
-                    out_ref[...] = jnp.where(
-                        turn, acc_ref[recv_slot], out_ref[...]
-                    )
-            else:
-                # broadcast phase: the total forwards around the ring,
-                # each shard latching it as it passes by
-                out_ref[...] = jnp.where(
-                    my == step - (ns - 1), acc_ref[recv_slot],
-                    out_ref[...],
-                )
-
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((g, nb), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((2, g, nb), jnp.float32),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        compiler_params=pltpu.TPUCompilerParams(collective_id=0),
-        interpret=interpret_mode(),
-    )
-
-
-def _tpu_ring_fold(parts, ns: int):
-    from greptimedb_tpu.parallel.mesh import AXIS_SHARD
-
-    fb_local, g, nb = parts.shape
-    return _tpu_ring_fold_call(ns, fb_local, g, nb, AXIS_SHARD)(parts)
 
 
 # ----------------------------------------------------------------------

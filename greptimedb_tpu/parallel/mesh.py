@@ -56,7 +56,7 @@ class MeshOptions:
     # off = never. Shape floors keep small programs on the XLA paths.
     pallas_kernels: str = "auto"
     pallas_min_series: int = 4096   # kernel grid paths below this stay XLA
-    pallas_min_rows: int = 262144   # fused merge-gather row floor
+    pallas_min_rows: int = 262144   # kernel row paths below this stay XLA
     pallas_max_k: int = 128         # topk merge kernel is O(k^2) per hop
 
 
@@ -132,16 +132,12 @@ def _force_host_devices(n: int) -> bool:
     existing = os.environ.get("XLA_FLAGS", "")
     if "--xla_force_host_platform_device_count" in existing:
         return True  # already pinned (conftest / operator)
-    try:
-        # probe the backend REGISTRY, not jax.extend.backend.backends()
-        # — calling backends() initializes every backend, which would
-        # make this check self-defeating (the flag must land first)
-        from jax._src import xla_bridge as _xb
+    # ask whether a backend exists WITHOUT creating one: jax.devices()
+    # would initialize every backend, which would make this check
+    # self-defeating (the flag must land first)
+    from greptimedb_tpu.telemetry.device_programs import backend_live
 
-        initialized = bool(getattr(_xb, "_backends", None))
-    except Exception:  # noqa: BLE001 - probe API drift: assume live
-        initialized = True
-    if initialized and len(jax.devices()) < n:
+    if backend_live() and len(jax.devices()) < n:
         _log.warning(
             "[mesh] force_host_device_count=%d requested after the jax "
             "backend initialized with %d device(s); set XLA_FLAGS=%r "

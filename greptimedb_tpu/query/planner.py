@@ -231,12 +231,12 @@ def decide_kernel(
     kind: str, *, series: int | None = None, rows: int | None = None,
     k: int | None = None, opts=None,
 ) -> tuple[str, str]:
-    """Choose the program variant for one (already sharded, or — for
-    "merge" — single-device compaction) execution site: "pallas" runs
-    the parallel/kernels ring/merge kernels, "xla" the collective
-    gather paths. Deterministic in its inputs, so execution sites may
-    re-ask with the same arguments without a planner round-trip. `k`
-    caps the topk merge kernel (O(k^2) ranks per hop)."""
+    """Choose the program variant for one already-sharded execution
+    site: "pallas" runs the parallel/kernels ring kernels, "xla" the
+    collective gather paths. Deterministic in its inputs, so execution
+    sites may re-ask with the same arguments without a planner
+    round-trip. `k` caps the topk merge kernel (O(k^2) ranks per
+    hop)."""
     from greptimedb_tpu.parallel import kernels as pk
     from greptimedb_tpu.parallel.mesh import MeshOptions
 
@@ -257,15 +257,14 @@ def decide_kernel(
     if rows is not None and \
             rows < max(getattr(opts, "pallas_min_rows", 262144), 1):
         return "xla", "small_rowset"
-    return "pallas", "fused_gather" if kind == "merge" else "ring_fold"
+    return "pallas", "ring_fold"
 
 
 def record_kernel_decision(kind: str, kernel: str, reason: str) -> None:
     """Surface one kernel-variant choice in EXPLAIN ANALYZE + metrics.
     Rides the existing gtpu_mesh_queries_total counter under the
     "<kind>_kernel" site label so the established mode/reason series
-    are untouched. stats.note no-ops outside a query context, so
-    standalone sites (compaction merge) can call this unguarded."""
+    are untouched."""
     from greptimedb_tpu.query import stats
     from greptimedb_tpu.telemetry import stmt_stats, tracing
     from greptimedb_tpu.telemetry.metrics import global_registry

@@ -346,7 +346,7 @@ def _sharded_fused_program(mesh, kernel: bool = False):
     ever extracts masked one-nonzero winner values."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from greptimedb_tpu.parallel.dist import ShardFoldCtx
@@ -441,7 +441,7 @@ def _sharded_fused_program(mesh, kernel: bool = False):
             in_specs=(P(None, AXIS_SHARD), P(None, AXIS_SHARD),
                       P(AXIS_SHARD), P(AXIS_SHARD), P(AXIS_SHARD)),
             out_specs=(P(None, AXIS_SHARD, None), P()),
-            check_rep=False,
+            check_vma=False,
         )(vals, masks, gid, tshi, tslo)
 
     return program

@@ -404,10 +404,7 @@ DEVICE_THRESHOLD = 262_144
 def _x64_enabled() -> bool:
     import jax
 
-    try:
-        return bool(jax.config.read("jax_enable_x64"))
-    except Exception:  # noqa: BLE001 - config API drift
-        return False
+    return bool(jax.config.read("jax_enable_x64"))
 
 
 def _split_two_float(x: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
@@ -589,7 +586,7 @@ def _rows_pre_halo_program(mesh, k: int):
     only cross-device traffic — one (k,) ppermute per input."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from greptimedb_tpu.parallel.dist import halo_prev_1d
@@ -614,7 +611,7 @@ def _rows_pre_halo_program(mesh, k: int):
             local, mesh=mesh,
             in_specs=(P(AXIS_SHARD), P(AXIS_SHARD), P(AXIS_SHARD)),
             out_specs=(P(AXIS_SHARD), P(AXIS_SHARD)),
-            check_rep=False,
+            check_vma=False,
         )(x, cnt, fs)
 
     return program

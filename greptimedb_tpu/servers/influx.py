@@ -8,6 +8,7 @@ FIELD columns, ts -> TIME INDEX), created or widened on first sight.
 
 from __future__ import annotations
 
+import logging
 import time
 from collections import defaultdict
 
@@ -200,6 +201,11 @@ try:
     from greptimedb_tpu.native import _lineproto as _native_lineproto
 except ImportError:   # pragma: no cover - build-artifact dependent
     _native_lineproto = None
+    # the .so is a git-ignored build product: say which parser serves,
+    # a silent fallback reads as a slow server
+    logging.getLogger("greptimedb_tpu.servers.influx").warning(
+        "native line-protocol tokenizer not built (make -C "
+        "greptimedb_tpu/native); using the pure-Python parser")
 
 
 def parse_payload(body: str) -> list:

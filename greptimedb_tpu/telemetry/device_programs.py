@@ -62,13 +62,17 @@ from greptimedb_tpu.telemetry.metrics import (
 # configuration
 # ---------------------------------------------------------------------------
 
-# v5e single-chip roofline peaks (Google Cloud TPU v5e system
-# architecture docs): 197 TFLOP/s bf16 MXU peak, 819 GB/s HBM
-# bandwidth. Used when the backend is a TPU and the [profiling] knobs
-# leave a peak at 0 (= auto); every other platform reports
-# achieved-only unless both peaks are configured explicitly.
-V5E_PEAK_TFLOPS = 197.0
-V5E_PEAK_HBM_GBPS = 819.0
+# Single-chip roofline peaks, keyed by `jax.devices()[0].device_kind`:
+# (bf16 MXU peak TFLOP/s, HBM GB/s). Source: Google Cloud TPU
+# documentation, "TPU v5e" system architecture page (197 TFLOP/s bf16,
+# 819 GB/s HBM per chip). Used when the backend is a TPU and the
+# [profiling] knobs leave a peak at 0 (= auto). A TPU kind that is not
+# in this table reports `unknown_device_kind` and achieved-only — never
+# another chip's peaks; every non-TPU platform reports achieved-only
+# unless both peaks are configured explicitly.
+DEVICE_PEAKS: dict[str, tuple[float, float]] = {
+    "TPU v5 lite": (197.0, 819.0),   # what a v5e chip reports
+}
 
 
 class ProfilingConfig:
@@ -197,20 +201,31 @@ def _quantile(buckets: list[int], q: float) -> float:
     return metrics.bucket_quantile(buckets, _EXEC_BUCKETS_MS, q)
 
 
-def _platform() -> str:
-    """The active jax backend platform, WITHOUT forcing jax to
-    initialize: a process that never dispatched a program must be able
-    to scrape /metrics without paying a backend bring-up."""
+def backend_live() -> bool:
+    """True once THIS process has created a jax backend — asked without
+    creating one (jax.devices() / default_backend() / live_arrays()
+    all would). A scrape or a health probe must never be what brings a
+    backend up: on a one-chip host the chip belongs to the
+    device-owning role process. The one place that touches the private
+    xla_bridge module."""
     import sys
 
     if "jax" not in sys.modules:
-        return "none"
-    try:
-        import jax
+        return False
+    from jax._src import xla_bridge
 
-        return str(jax.default_backend())
-    except Exception:  # noqa: BLE001 - no usable backend
-        return "none"
+    return xla_bridge.backends_are_initialized()
+
+
+def _device_identity() -> tuple[str, str]:
+    """(platform, device_kind) of the live jax backend, ("none", "")
+    while this process has none."""
+    if not backend_live():
+        return "none", ""
+    import jax
+
+    dev = jax.devices()[0]
+    return str(dev.platform), str(dev.device_kind)
 
 
 def _prog_id(site: str, key) -> str:
@@ -562,13 +577,16 @@ class DeviceProgramRegistry:
         when unknown (achieved-only reporting)."""
         pf = self.config.peak_tflops
         pb = self.config.peak_hbm_gbps
-        plat = _platform()
+        plat, kind = _device_identity()
         if pf > 0 and pb > 0:
             return pf, pb, plat, "configured"
         if plat == "tpu":
-            return (pf if pf > 0 else V5E_PEAK_TFLOPS,
-                    pb if pb > 0 else V5E_PEAK_HBM_GBPS,
-                    plat, "v5e_default")
+            known = DEVICE_PEAKS.get(kind)
+            if known is None:
+                return 0.0, 0.0, plat, "unknown_device_kind"
+            return (pf if pf > 0 else known[0],
+                    pb if pb > 0 else known[1],
+                    plat, f"device_kind:{kind}")
         return 0.0, 0.0, plat, "achieved_only"
 
     # -- lazy XLA analysis ---------------------------------------------

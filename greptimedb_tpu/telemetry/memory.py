@@ -354,7 +354,13 @@ class MemoryAccountant:
         try:
             import jax
 
-            arrays = jax.live_arrays()
+            from greptimedb_tpu.telemetry.device_programs import (
+                backend_live,
+            )
+
+            # a process that never created a backend has no live
+            # arrays — and a scrape must not be what creates one
+            arrays = jax.live_arrays() if backend_live() else []
         except Exception:  # noqa: BLE001 - no jax backend: census empty
             arrays = []
         for a in arrays:

@@ -139,21 +139,30 @@ def build_node_stats(inst) -> dict:
 _DEVICE_PROBE_TTL_S = 60.0
 _device_probe: tuple[float, bool, str] = (-1e18, False, "never ran")
 
+# roles that run no device program of their own: their health check
+# must not be the thing that creates a backend (on a one-chip host the
+# chip belongs to the device-owning role process)
+_NO_DEVICE_ROLES = frozenset({"frontend", "metasrv"})
+
 
 def _check(fn) -> dict:
     t0 = time.perf_counter()
+    extra: dict = {}
     try:
-        ok, detail = fn()
+        ok, detail, *rest = fn()
+        if rest:
+            extra = rest[0]
     except Exception as e:  # noqa: BLE001 - a probe failure IS the result
         ok, detail = False, f"{type(e).__name__}: {e}"
     out = {"ok": bool(ok),
            "ms": round((time.perf_counter() - t0) * 1000.0, 2)}
     if detail:
         out["detail"] = str(detail)
+    out.update(extra)
     return out
 
 
-def _probe_device() -> tuple[bool, str]:
+def _dispatch_probe() -> tuple[bool, str]:
     global _device_probe
     now = time.monotonic()
     ts, ok, detail = _device_probe
@@ -171,6 +180,35 @@ def _probe_device() -> tuple[bool, str]:
         ok, detail = False, f"{type(e).__name__}: {e}"
     _device_probe = (now, ok, detail)
     return ok, detail
+
+
+def _probe_device(role: str = "standalone") -> tuple[bool, str, dict]:
+    """The `device` health check: a cached dispatch probe plus the
+    device identity as jax reports it IN THIS PROCESS — platform,
+    device_kind, count and every device's bytes in use (None where the
+    backend keeps no memory stats, e.g. CPU). Identity and memory are
+    read fresh on every call; only the dispatch is cached."""
+    if role in _NO_DEVICE_ROLES:
+        from greptimedb_tpu.telemetry.device_programs import backend_live
+
+        if not backend_live():
+            return True, "no backend initialized (role owns no device)", {}
+    ok, detail = _dispatch_probe()
+    if not ok:
+        return ok, detail, {}
+    import jax
+
+    devs = jax.devices()
+    in_use = []
+    for d in devs:
+        st = d.memory_stats()
+        in_use.append(None if not st else int(st.get("bytes_in_use", 0)))
+    return ok, detail, {
+        "platform": devs[0].platform,
+        "device_kind": devs[0].device_kind,
+        "count": len(devs),
+        "bytes_in_use": in_use,
+    }
 
 
 def deep_health(inst) -> dict:
@@ -214,7 +252,7 @@ def deep_health(inst) -> dict:
 
             checks["object_store"] = _check(store_reachable)
 
-    checks["device"] = _check(_probe_device)
+    checks["device"] = _check(lambda: _probe_device(role))
 
     meta = getattr(inst, "meta", None)
     if meta is not None:

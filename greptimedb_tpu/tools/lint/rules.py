@@ -736,7 +736,7 @@ class FullBufferReadback(Rule):
     description = (
         "np.asarray()/jax.device_get() on a device result buffer (a "
         "name this function called .block_until_ready() on) reads the "
-        "WHOLE buffer back across the host<->device tunnel, "
+        "WHOLE buffer back in one device->host transfer, "
         "unattributed. Route result readbacks through "
         "query/readback.read_full (bytes land on "
         "gtpu_readback_bytes_total) or read_delta (a since-cursor poll "
@@ -1627,7 +1627,7 @@ def fetch(url):
 '''),
     "GT013": ('''\
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 def run(mesh, x):
@@ -1638,7 +1638,7 @@ def run(mesh, x):
                      out_specs=P())(x)
 ''', '''\
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 def run(mesh, x):

@@ -580,3 +580,21 @@ def test_mesh_twins_get_distinct_rows(tmp_path, rng, devices, registry,
         assert rows[0]["program"] != rows[1]["program"]
     finally:
         inst.close()
+
+
+def test_peaks_come_from_the_device_kind_table(monkeypatch):
+    """A TPU whose device_kind is not in DEVICE_PEAKS reports
+    unknown_device_kind and achieved-only — never another chip's
+    peaks; a known kind gets its own row; configured peaks win."""
+    reg = DP.DeviceProgramRegistry()
+    monkeypatch.setattr(DP, "_device_identity",
+                        lambda: ("tpu", "TPU v9 hypothetical"))
+    assert reg.peaks() == (0.0, 0.0, "tpu", "unknown_device_kind")
+    monkeypatch.setattr(DP, "_device_identity",
+                        lambda: ("tpu", "TPU v5 lite"))
+    assert reg.peaks() == (197.0, 819.0, "tpu",
+                           "device_kind:TPU v5 lite")
+    reg.config = DP.ProfilingConfig(peak_tflops=1.0, peak_hbm_gbps=2.0)
+    monkeypatch.setattr(DP, "_device_identity",
+                        lambda: ("tpu", "TPU v9 hypothetical"))
+    assert reg.peaks() == (1.0, 2.0, "tpu", "configured")

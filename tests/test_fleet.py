@@ -68,7 +68,17 @@ def test_deep_health_ok_and_degraded(inst, monkeypatch):
     assert doc["status"] == "ok"
     assert doc["checks"]["engine"]["ok"]
     assert doc["checks"]["wal_appendable"]["ok"]
-    assert doc["checks"]["device"]["ok"]
+    dev = doc["checks"]["device"]
+    assert dev["ok"]
+    # device identity as jax reports it in THIS process (what
+    # chip_smoke.py reads): platform, kind, count, bytes in use per
+    # device (None on backends without memory stats, e.g. CPU)
+    import jax
+
+    assert dev["platform"] == jax.devices()[0].platform == "cpu"
+    assert dev["device_kind"] == jax.devices()[0].device_kind
+    assert dev["count"] == len(jax.devices())
+    assert len(dev["bytes_in_use"]) == dev["count"]
     assert all("ms" in c for c in doc["checks"].values())
     # one failing subsystem degrades the verdict without erroring the
     # probe (and without hiding the other checks)
@@ -81,6 +91,21 @@ def test_deep_health_ok_and_degraded(inst, monkeypatch):
     assert not doc["checks"]["engine"]["ok"]
     assert "boom" in doc["checks"]["engine"]["detail"]
     assert doc["checks"]["device"]["ok"]   # others still ran
+
+
+def test_device_check_never_creates_a_backend_for_frontend(monkeypatch):
+    """A role that owns no device must not grab one through its own
+    health probe (one device-owning process per chip)."""
+    from greptimedb_tpu.telemetry import device_programs
+    from greptimedb_tpu.telemetry import node_stats as ns
+
+    monkeypatch.setattr(device_programs, "backend_live", lambda: False)
+    monkeypatch.setattr(
+        ns, "_dispatch_probe",
+        lambda: (_ for _ in ()).throw(AssertionError("dispatched")),
+    )
+    ok, detail, info = ns._probe_device("frontend")
+    assert ok and "no backend" in detail and info == {}
 
 
 # ---------------------------------------------------------------------

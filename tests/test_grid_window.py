@@ -133,11 +133,12 @@ def test_window_first_last(gridded):
 
 def test_window_minmax_quantile(gridded):
     rows, spec, windows, (vals, has, tsg) = gridded
+    lo = jnp.array(windows.lo)
     hi = jnp.array(windows.hi)
     l_cells = windows.num_cells_per_window
-    mn, mp = W.window_minmax(vals, has, tsg, hi, l_cells, "min")
-    mx, _ = W.window_minmax(vals, has, tsg, hi, l_cells, "max")
-    md, qp = W.window_quantile(vals, has, tsg, hi, l_cells, 0.5)
+    mn, mp = W.window_minmax(vals, has, tsg, lo, hi, l_cells, "min")
+    mx, _ = W.window_minmax(vals, has, tsg, lo, hi, l_cells, "max")
+    md, qp = W.window_quantile(vals, has, tsg, lo, hi, l_cells, 0.5)
     mn, mx, md, mp = map(np.asarray, (mn, mx, md, mp))
     for s in range(5):
         for j in range(0, windows.num_steps, 4):

@@ -1,18 +1,16 @@
-"""Pallas kernel twins (parallel/kernels): interpret-mode parity against
-the XLA collective paths on the 8-virtual-device CPU mesh, the plane
-codec bit-exactness contract, and the fused compaction merge's
-readback-is-output-only regression (ISSUE 17)."""
+"""Pallas kernels (parallel/kernels): interpret-mode parity against
+the XLA collective paths on the 8-virtual-device CPU mesh
+(ISSUE 17)."""
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 import pytest
 
 from greptimedb_tpu.parallel import dist, mesh as M
 from greptimedb_tpu.parallel import kernels as K
-from greptimedb_tpu.parallel.kernels import merge_gather as mg
 from greptimedb_tpu.parallel.kernels import topk_merge as tm
 
 NS = 8
@@ -21,21 +19,6 @@ NS = 8
 @pytest.fixture(scope="module")
 def mesh8():
     return M.make_mesh(jax.devices())  # shard=8, time=1
-
-
-@pytest.fixture
-def kernels_on():
-    """Force the fused-merge planner gate open (and restore after):
-    merge_rows reads mesh.global_mesh_opts(), not an engine."""
-    with M._state_lock:
-        old = M._global_opts
-        M._global_opts = M.MeshOptions(
-            enabled=False, pallas_kernels="on",
-            pallas_min_rows=1, pallas_min_series=1,
-        )
-    yield
-    with M._state_lock:
-        M._global_opts = old
 
 
 def _bits(a: np.ndarray) -> np.ndarray:
@@ -54,7 +37,7 @@ def _smap(mesh, body, spec_in, *args):
     ]
     return shard_map(
         body, mesh=mesh, in_specs=tuple(spec_in),
-        out_specs=P(M.AXIS_SHARD), check_rep=False,
+        out_specs=P(M.AXIS_SHARD), check_vma=False,
     )(*darg)
 
 
@@ -190,118 +173,6 @@ def test_dist_topk_kernel_parity(mesh8, rng, largest):
                           _bits(np.asarray(v1)[fin]))
     assert np.array_equal(np.asarray(i0)[fin], np.asarray(i1)[fin])
 
-
-def test_plane_codec_bit_exact_roundtrip():
-    cases = [
-        np.array([0, 1, -1, 2**62, -2**62, 2**63 - 1, -2**63],
-                 np.int64),
-        np.array([0.0, -0.0, np.nan, np.inf, -np.inf, 1.5e-310],
-                 np.float64),
-        np.array([0.0, -0.0, np.nan, np.inf, -np.inf, 1e-40],
-                 np.float32),
-        np.array([0, 1, 2**64 - 1, 2**32], np.uint64),
-        np.array([-128, 0, 127], np.int8),
-        np.array([True, False, True], np.bool_),
-        np.array([0.5, -0.5, 65504.0], np.float16),
-        np.array([0, 1, 2**40], "int64").view("datetime64[ms]"),
-        np.array([3, 1, 4, 1, 5], np.uint16),
-    ]
-    for col in cases:
-        assert mg.packable(col.dtype)
-        planes = mg.pack_planes(col)
-        assert planes.dtype == np.uint32
-        assert planes.shape == (mg.plane_count(col.dtype), len(col))
-        back = mg.unpack_planes(planes, col.dtype, len(col))
-        assert back.dtype == col.dtype
-        assert np.array_equal(col.view(np.uint8), back.view(np.uint8)), \
-            col.dtype
-    assert not mg.packable(np.dtype(object))
-    assert not mg.packable(np.dtype("U4"))
-
-
-def test_gather_planes_matches_host_take(rng):
-    p, n, n_out = 5, 200, 64
-    planes = rng.integers(0, 2**32, (p, n)).astype(np.uint32)
-    src = rng.integers(0, n, n_out).astype(np.int32)
-    run = mg.gather_program(p, n, n_out, True)
-    got = np.asarray(run(jnp.asarray(planes), jnp.asarray(src)))
-    assert np.array_equal(got, planes[:, src])
-
-
-# ----------------------------------------------------------------------
-# fused compaction merge: readback == output columns (satellite 2)
-# ----------------------------------------------------------------------
-
-def _merge_rows_input(n, seed=7):
-    from greptimedb_tpu.storage.memtable import (
-        OP_DELETE, OP_PUT, ColumnarRows,
-    )
-
-    rng = np.random.default_rng(seed)
-    sid = rng.integers(0, 16, n).astype(np.int32)
-    ts = rng.integers(0, 60, n).astype(np.int64) * 1000  # heavy dedup
-    seq = np.arange(n, dtype=np.uint64)
-    rng.shuffle(seq)
-    op = np.where(rng.random(n) < 0.1, OP_DELETE, OP_PUT).astype(np.uint8)
-    f = rng.standard_normal(n)
-    f[rng.random(n) < 0.02] = np.nan
-    return ColumnarRows(
-        sid=sid, ts=ts, seq=seq, op=op,
-        fields={"a": f, "b": rng.standard_normal(n).astype(np.float32)},
-        field_valid={"a": rng.random(n) < 0.7, "b": rng.random(n) < 0.95},
-    )
-
-
-def test_fused_merge_readback_is_output_only(kernels_on):
-    from greptimedb_tpu.query import readback
-    from greptimedb_tpu.storage import device_merge as dm
-    from greptimedb_tpu.storage.device_merge import host_merge, merge_rows
-
-    n = 4000
-    rows = _merge_rows_input(n)
-    rb0 = readback.readback_bytes("full")
-    out, path = merge_rows(rows, merge_mode="last_non_null",
-                           drop_deletes=True, device_min_rows=1,
-                           verify=True)
-    fused_rb = readback.readback_bytes("full") - rb0
-    assert path == "device"
-    host = host_merge(_merge_rows_input(n), merge_mode="last_non_null",
-                      drop_deletes=True)
-    assert len(out) == len(host) < n // 2  # the dedup really happened
-    # exact fused readback: the 4-byte kept-count plus the gathered
-    # output planes — keep group (sid+ts+seq+op+valids) and one group
-    # per backfilled field (value+valid). NOTHING proportional to the
-    # input row count (the classic path reads order/keep/fills back at
-    # O(input pad)).
-    n_out = dm._pad_to_bucket(len(out))
-    keep_planes = 1 + 2 + 2 + 1                # sid ts seq op
-    grp_a = 2 + 1     # backfilled f64 field + its valid (own src group)
-    grp_b = 1 + 1     # backfilled f32 field + its valid
-    expected = 4 + 4 * n_out * (keep_planes + grp_a + grp_b)
-    assert fused_rb == expected, (fused_rb, expected)
-    # regression pin: the classic per-input-run index readback
-    # (order int64 + keep bool + two int64 fill maps over the input
-    # bucket) does not come back on the fused path
-    pad = dm._pad_to_bucket(n)
-    classic_rb = pad * (8 + 1 + 8 + 8)
-    assert fused_rb < classic_rb
-
-
-def test_fused_merge_records_kernel_decision(kernels_on):
-    from greptimedb_tpu.storage.device_merge import merge_rows
-    from greptimedb_tpu.telemetry.metrics import global_registry
-
-    ctr = global_registry.counter(
-        "gtpu_mesh_queries_total",
-        "Mesh execution decisions by mode/reason/site",
-        labels=("kind", "mode", "reason"),
-    ).labels("merge_kernel", "pallas", "fused_gather")
-    before = ctr.value
-    _out, path = merge_rows(_merge_rows_input(2048),
-                            merge_mode="last_row", drop_deletes=False,
-                            device_min_rows=1, verify=True)
-    assert path == "device"
-    assert ctr.value == before + 1
 
 
 def test_collective_attribution_on_program_registry():

@@ -606,9 +606,8 @@ def test_gt027_negative_parent_captured_and_plain_work():
 # ---------------------------------------------------------------------------
 
 def test_shipped_kernels_clean_under_dataflow_rules():
-    """The three production kernels must produce no ACTIVE GT023-GT027
-    findings (contract-commented suppressions are allowed and
-    expected: merge_gather's (P, 1) blocks are deliberate)."""
+    """The production kernels must produce no ACTIVE GT023-GT027
+    findings."""
     import os
 
     from greptimedb_tpu.tools.lint.runner import lint_paths

@@ -173,7 +173,7 @@ def test_gt004_positive_inside_shard_map_body():
     hits = rules_hit("""
         import jax
         import numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         def run(mesh, x):
@@ -188,7 +188,7 @@ def test_gt004_positive_inside_shard_map_body():
 
 def test_gt005_positive_inside_shard_map_body():
     hits = rules_hit("""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         def run(mesh, x):
@@ -456,7 +456,7 @@ def test_gt010_negative_private_none_tuple():
 def test_gt013_positive_unbound_literal_axis():
     hits = rules_hit("""
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         def run(mesh, x):
@@ -473,7 +473,7 @@ def test_gt013_positive_unresolved_identifier_axis():
     # both sides unresolved identifiers: compared by name
     hits = rules_hit("""
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from somewhere import AXIS_A, AXIS_B
 
@@ -491,7 +491,7 @@ def test_gt013_positive_module_constant_resolution():
     # module constants resolve to their string values before comparing
     hits = rules_hit("""
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         AXIS_S = "shard"
@@ -510,7 +510,7 @@ def test_gt013_negative_bound_axis_and_mixed_spaces():
     # bound literal axis: clean
     assert rules_hit("""
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         def run(mesh, x):
@@ -523,7 +523,7 @@ def test_gt013_negative_bound_axis_and_mixed_spaces():
     # module constant on both sides: resolves and matches
     assert rules_hit("""
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         AXIS_S = "shard"
@@ -538,7 +538,7 @@ def test_gt013_negative_bound_axis_and_mixed_spaces():
     # unresolved identifier vs literal specs: can't compare, stays quiet
     assert rules_hit("""
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from somewhere import AXIS_T
 
@@ -578,7 +578,7 @@ def test_gt014_positive_tracing_span_in_jit():
 def test_gt014_positive_stats_and_metric_in_shard_map_body():
     hits = rules_hit("""
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from greptimedb_tpu.query import stats
         from greptimedb_tpu.telemetry.metrics import global_registry
@@ -1816,7 +1816,7 @@ def test_gt022_negative_threaded_interpret():
 def test_gt022_positive_unbound_device_id_axis():
     hits = rules_hit("""
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.experimental.pallas import tpu as pltpu
         from jax.sharding import PartitionSpec as P
 
@@ -1839,7 +1839,7 @@ def test_gt022_negative_bound_or_computed_device_id():
     # mesh-form device_id naming the bound axis: clean
     assert rules_hit("""
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.experimental.pallas import tpu as pltpu
         from jax.sharding import PartitionSpec as P
 
@@ -1859,7 +1859,7 @@ def test_gt022_negative_bound_or_computed_device_id():
     # not axis names; the axis_index subtree is GT013's domain
     assert rules_hit("""
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.experimental.pallas import tpu as pltpu
         from jax.sharding import PartitionSpec as P
 

@@ -400,8 +400,11 @@ def test_session_strand_would_be_visible_as_unaccounted(inst):
 
 
 def test_device_span_carries_pool_bytes_attribution(inst):
-    from greptimedb_tpu.telemetry import tracing
+    from greptimedb_tpu.telemetry import memory, tracing
 
+    # expire the 0.5 s device-bytes TTL: a warm jit cache lets this
+    # test reach its span before an earlier test's cached 0 ages out
+    memory.global_accountant._dev_bytes_cache = (-1e18, 0)
     _seed_device_table(inst)
     _run_range(inst)
     dev_spans = [

@@ -5,7 +5,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 import pytest
 
 from greptimedb_tpu.ops import segment as S
@@ -66,7 +66,7 @@ def test_halo_exchange_window_sum(mesh8, rng):
         windowed, mesh=mesh8,
         in_specs=P(M.AXIS_SHARD, M.AXIS_TIME),
         out_specs=P(M.AXIS_SHARD, M.AXIS_TIME),
-        check_rep=False,
+        check_vma=False,
     )(dx))
     c = np.cumsum(np.pad(x, ((0, 0), (halo, 0))), axis=1)
     want = c[:, halo:] - c[:, :-halo]

@@ -99,6 +99,36 @@ def test_fast_matches_generic(inst, promql):
         )
 
 
+@pytest.mark.parametrize("promql", [
+    "max by (host) (max_over_time(req_total[1m]))",
+    "min by (host) (min_over_time(req_total[2m]))",
+    "sum by (dc) (quantile_over_time(0.5, req_total[1m]))",
+    "sum by (dc) (stddev_over_time(req_total[1m]))",
+])
+def test_window_past_data_end_matches_generic(inst, promql):
+    """Steps past the last sample: the grid clips their window END to
+    its last cell, and the gather-family range functions must then see
+    a SHORTER window (cells (lo, hi]), not the same length slid back
+    over samples at or before t - range (chip_smoke found
+    max_over_time including the sample at exactly t - range)."""
+    ts = setup_metrics(inst)
+    end = int(ts[-1])
+    fast_val, slow_val, _ = run_both(
+        inst, promql, end - 60_000, end + 90_000, 15_000
+    )
+    fm, sm = as_map(fast_val), as_map(slow_val)
+    sm = {k: v for k, v in sm.items() if v[1].any()}
+    assert set(fm) == set(sm)
+    for key in fm:
+        fv, fp = fm[key]
+        sv, sp = sm[key]
+        np.testing.assert_array_equal(fp, sp, err_msg=promql)
+        np.testing.assert_allclose(
+            np.where(fp, fv, 0), np.where(sp, sv, 0),
+            rtol=1e-5, atol=1e-6, err_msg=promql,
+        )
+
+
 def test_fast_path_taken_and_invalidated(inst):
     ts = setup_metrics(inst)
     eng = PromEngine(inst)

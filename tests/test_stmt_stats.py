@@ -144,7 +144,7 @@ def test_device_attribution_one_row_for_repeated_polls(tmp_path):
         n = 6
         for _ in range(n):
             assert inst.sql(FLAGSHIP).num_rows > 0
-        # delta poll: only the steps past the cursor cross the tunnel
+        # delta poll: only the steps past the cursor are read back
         # (the seeded data spans ~640s => ~11 one-minute align steps;
         # a cursor in the middle leaves a non-empty unseen tail)
         ctx = QueryContext()
