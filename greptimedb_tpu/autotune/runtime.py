@@ -1,17 +1,18 @@
 """Controller runtime: one low-frequency tick loop per process.
 
 The loop rides the concurrency facade (gtsan-instrumentable thread +
-event; no bare threading), wraps each tick in an ``autotune.tick``
-span, and isolates controllers the way engine.run_maintenance isolates
-regions: a controller whose sensor or actuator raises logs the error,
+event; no bare threading), times each tick as the background task
+``autotune.tick`` (telemetry/tracing.py background_span: counted, in
+the trace ring only when slow), and isolates controllers the way
+engine.run_maintenance isolates regions: a controller whose sensor or actuator raises logs the error,
 ticks ``gtpu_autotune_controller_errors_total{controller=...}``, and
 the REMAINING controllers still run — one bad sensor never kills the
 control plane.
 
 Freeze semantics (`ADMIN autotune_freeze()` / `[autotune] enable`):
 - disabled (`enable = false`): tick_once is a bit-for-bit no-op —
-  no span, no sensor reads, no knob reads, zero decisions.
-- frozen: the loop keeps ticking (span + counter, so operators can
+  no timing, no sensor reads, no knob reads, zero decisions.
+- frozen: the loop keeps ticking (timed + counted, so operators can
   see it is alive) but no controller runs and no knob moves;
   ``gtpu_autotune_frozen`` reads 1. ADMIN set_config stays available —
   freezing hands control back to the operator, it does not take the
@@ -87,11 +88,9 @@ class AutotuneRuntime:
             return 0
         from greptimedb_tpu.telemetry import tracing
 
-        with tracing.span("autotune.tick", frozen=int(self._frozen),
-                          controllers=len(self.controllers)) as sp:
+        with tracing.background_span("autotune.tick"):
             _TICKS.inc()
             if self._frozen:
-                sp.attributes["decisions"] = 0
                 return 0
             n = 0
             for c in self.controllers:
@@ -103,7 +102,6 @@ class AutotuneRuntime:
                     _ERRORS.labels(c.name).inc()
                     _log.warning("[autotune] controller %r failed "
                                  "this tick", c.name, exc_info=True)
-            sp.attributes["decisions"] = n
             return n
 
     # ---- lifecycle ----------------------------------------------------
@@ -112,11 +110,10 @@ class AutotuneRuntime:
             return
         self._stop.clear()
         # contract: the controller loop is a process-lifetime daemon
-        # with no submitting request — every autotune.tick span is
-        # DELIBERATELY its own root trace, not a child of whichever
-        # request happened to call start()
+        # with no submitting request — its ticks are background tasks,
+        # never children of whichever request happened to call start()
         self._thread = concurrency.Thread(
-            target=self._run,  # gtlint: disable=GT027
+            target=self._run,
             name="gtpu-autotune", daemon=True,
         )
         self._thread.start()

@@ -106,6 +106,7 @@ def start_heartbeat(meta_addr: str, node_id: int, inst, *,
     attaches the node-stats payload on the [fleet] stats cadence."""
     from greptimedb_tpu.dist.client import MetaClient
     from greptimedb_tpu.telemetry import node_stats as _ns
+    from greptimedb_tpu.telemetry import tracing
 
     interval = float(interval_s if interval_s is not None
                      else _cfg["heartbeat_interval_s"])
@@ -120,70 +121,71 @@ def start_heartbeat(meta_addr: str, node_id: int, inst, *,
         last_leader = client.addr
         last_stats = -1e18
         while True:   # register immediately, THEN pace by the interval
-            try:
-                if client.addr != last_leader:
-                    # leader moved: its memory has no liveness record of
-                    # us — re-register before the next heartbeat
-                    registered = False
-                    last_leader = client.addr
-                if not registered:
-                    client.register(node_id, addr, role=role)
-                    registered = True
-                stats = {}
+            with tracing.background_span("fleet.heartbeat"):
                 try:
-                    for t in inst.catalog.all_tables():
-                        for r in t.regions:
-                            stats[str(r.meta.region_id)] = {
-                                "rows": int(getattr(r.memtable, "rows",
-                                                    0)),
-                            }
-                except Exception as e:  # noqa: BLE001
-                    # stats are advisory; heartbeat with what we have
-                    _log.debug("region stat collection: %s", e)
-                payload = None
-                now = time.monotonic()
-                if (_cfg["enable"]
-                        and now - last_stats >= _cfg["stats_interval_s"]):
+                    if client.addr != last_leader:
+                        # leader moved: its memory has no liveness record of
+                        # us — re-register before the next heartbeat
+                        registered = False
+                        last_leader = client.addr
+                    if not registered:
+                        client.register(node_id, addr, role=role)
+                        registered = True
+                    stats = {}
                     try:
-                        payload = _ns.build_node_stats(inst)
-                        last_stats = now
-                    except Exception as e:  # noqa: BLE001 - telemetry
-                        # must never break liveness
-                        _log.debug("node-stats build failed: %s", e)
-                instructions = client.heartbeat(node_id, stats,
-                                                node_stats=payload,
-                                                role=role, addr=addr)
-                inst.fleet_heartbeat_at = time.monotonic()
-                _heartbeat_counter().labels("ok").inc()
-                for ins in instructions:
-                    if ins.get("type") == "grant_lease":
-                        rs = getattr(inst, "region_server", None)
-                        if rs is not None:
-                            rs.renew_leases(
-                                ins.get("regions") or [],
-                                float(ins.get("lease_secs", 10.0)),
-                            )
-                    else:
-                        # other mailbox instructions are logged; region
-                        # movement is driven by the metasrv directly
-                        # over Flight (dist/wire_cluster.py)
-                        print(f"# metasrv instruction: {ins}",
-                              flush=True)
-            except Exception:
-                registered = False
-                _heartbeat_counter().labels("error").inc()
-            # lease enforcement runs even (especially) when heartbeats
-            # fail: a partitioned node fences its regions instead of
-            # split-braining with a failover target. Nothing here may
-            # kill the loop — a dead loop means no fencing at all.
-            try:
-                rs = getattr(inst, "region_server", None)
-                if rs is not None:
-                    for rid in rs.enforce_leases():
-                        print(f"# region {rid} lease expired: fenced",
-                              flush=True)
-            except Exception as e:  # noqa: BLE001
-                print(f"# lease enforcement failed: {e}", flush=True)
+                        for t in inst.catalog.all_tables():
+                            for r in t.regions:
+                                stats[str(r.meta.region_id)] = {
+                                    "rows": int(getattr(r.memtable, "rows",
+                                                        0)),
+                                }
+                    except Exception as e:  # noqa: BLE001
+                        # stats are advisory; heartbeat with what we have
+                        _log.debug("region stat collection: %s", e)
+                    payload = None
+                    now = time.monotonic()
+                    if (_cfg["enable"]
+                            and now - last_stats >= _cfg["stats_interval_s"]):
+                        try:
+                            payload = _ns.build_node_stats(inst)
+                            last_stats = now
+                        except Exception as e:  # noqa: BLE001 - telemetry
+                            # must never break liveness
+                            _log.debug("node-stats build failed: %s", e)
+                    instructions = client.heartbeat(node_id, stats,
+                                                    node_stats=payload,
+                                                    role=role, addr=addr)
+                    inst.fleet_heartbeat_at = time.monotonic()
+                    _heartbeat_counter().labels("ok").inc()
+                    for ins in instructions:
+                        if ins.get("type") == "grant_lease":
+                            rs = getattr(inst, "region_server", None)
+                            if rs is not None:
+                                rs.renew_leases(
+                                    ins.get("regions") or [],
+                                    float(ins.get("lease_secs", 10.0)),
+                                )
+                        else:
+                            # other mailbox instructions are logged; region
+                            # movement is driven by the metasrv directly
+                            # over Flight (dist/wire_cluster.py)
+                            print(f"# metasrv instruction: {ins}",
+                                  flush=True)
+                except Exception:
+                    registered = False
+                    _heartbeat_counter().labels("error").inc()
+                # lease enforcement runs even (especially) when heartbeats
+                # fail: a partitioned node fences its regions instead of
+                # split-braining with a failover target. Nothing here may
+                # kill the loop — a dead loop means no fencing at all.
+                try:
+                    rs = getattr(inst, "region_server", None)
+                    if rs is not None:
+                        for rid in rs.enforce_leases():
+                            print(f"# region {rid} lease expired: fenced",
+                                  flush=True)
+                except Exception as e:  # noqa: BLE001
+                    print(f"# lease enforcement failed: {e}", flush=True)
             if stop.wait(interval):
                 return
 

@@ -494,9 +494,12 @@ class FlowManager:
     # tick / writeback
     # ------------------------------------------------------------------
     def _tick_loop(self):
+        from greptimedb_tpu.telemetry import tracing
+
         while not self._stop.wait(self.tick_interval_s):
             try:
-                self.flush_all()
+                with tracing.background_span("flow.tick"):
+                    self.flush_all()
             except Exception:
                 import traceback
 

@@ -289,11 +289,7 @@ class Standalone:
         outputs = []
         t0 = _time.perf_counter()
         trace_id = None
-        # per-statement fingerprints resolved from the raw TEXT (the
-        # AST has no literal spans left to fold); aligned with
-        # parse_sql's statement order by the shared ';' split
-        fps = (stmt_stats.fingerprint_sql(sql)
-               if stmt_stats.enabled() else [])
+        fps = []
         try:
             # one span per statement batch: the root on wires that
             # carry no traceparent (mysql/postgres/flight), a child of
@@ -302,7 +298,16 @@ class Standalone:
             with tracing.span("sql.execute", db=ctx.database,
                               channel=ctx.channel) as root:
                 trace_id = root.trace_id or None
-                for i, stmt in enumerate(parse_sql(sql)):
+                # per-statement fingerprints resolved from the raw TEXT
+                # (the AST has no literal spans left to fold); aligned
+                # with parse_sql's statement order by the shared ';'
+                # split
+                if stmt_stats.enabled():
+                    with tracing.child_span("sql.fingerprint"):
+                        fps = stmt_stats.fingerprint_sql(sql)
+                with tracing.child_span("sql.parse"):
+                    stmts = parse_sql(sql)
+                for i, stmt in enumerate(stmts):
                     token = stmt_stats.bind_fingerprint(
                         fps[i] if i < len(fps) else None
                     )
@@ -936,22 +941,25 @@ class Standalone:
             raise InvalidArgumentError(
                 f"INSERT missing TIME INDEX column {ts_name}"
             )
-        n = len(data[ts_name])
+        from greptimedb_tpu.telemetry import tracing
+
         tags = {}
         fields = {}
         fvalid = {}
-        for cname, arr in data.items():
-            cs = schema.column(cname)
-            if cs.is_time_index:
-                continue
-            if cs.is_tag:
-                tags[cname] = np.asarray(
-                    ["" if v is None else str(v) for v in arr], object
-                )
-            else:
-                fields[cname] = arr
-                if cname in valid and not valid[cname].all():
-                    fvalid[cname] = valid[cname]
+        with tracing.child_span("write.tag_columns"):
+            for cname, arr in data.items():
+                cs = schema.column(cname)
+                if cs.is_time_index:
+                    continue
+                if cs.is_tag:
+                    tags[cname] = np.asarray(
+                        ["" if v is None else str(v) for v in arr],
+                        object
+                    )
+                else:
+                    fields[cname] = arr
+                    if cname in valid and not valid[cname].all():
+                        fvalid[cname] = valid[cname]
         ts = np.asarray(data[ts_name], np.int64)
         return table.write(tags, ts, fields, field_valid=fvalid or None)
 
@@ -1005,15 +1013,17 @@ class Standalone:
                 )
 
                 return query_pg_catalog(self, stmt, ctx)
-            db, name = self._resolve(stmt.from_table, ctx)
-            table = self.catalog.table(db, name)
-            ts_name = table.ts_name
-            tag_names = table.tag_names
-            all_columns = table.schema.column_names
         from greptimedb_tpu.telemetry import tracing
 
+        # binding the table and its columns is planning too
         with tracing.child_span("query.plan",
                                 table=stmt.from_table or ""):
+            if stmt.from_table:
+                db, name = self._resolve(stmt.from_table, ctx)
+                table = self.catalog.table(db, name)
+                ts_name = table.ts_name
+                tag_names = table.tag_names
+                all_columns = table.schema.column_names
             plan = plan_select(
                 stmt, ts_name=ts_name, tag_names=tag_names,
                 all_columns=all_columns,

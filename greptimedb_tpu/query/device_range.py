@@ -48,6 +48,7 @@ import numpy as np
 from greptimedb_tpu.errors import UnsupportedError
 from greptimedb_tpu.program_cache import ProgramCache
 from greptimedb_tpu.sql import ast as A
+from greptimedb_tpu.telemetry import tracing
 
 from greptimedb_tpu import concurrency
 
@@ -1198,6 +1199,13 @@ def _ensure_states_locked(entry, plan, table, items, cache, jnp) -> bool:
             missing.setdefault(fname, set()).update(want)
     if not missing:
         return True
+    # the same scan, assembly and upload as a build, for the states
+    # this entry lacks
+    with tracing.child_span("grid.build", grow=True):
+        return _grow_states_locked(entry, table, missing, cache)
+
+
+def _grow_states_locked(entry, table, missing, cache) -> bool:
     # growing the entry in place must respect the same AGGREGATE HBM
     # budget that gated its construction
     add = 0
@@ -1302,8 +1310,6 @@ def run_prelude(entry: _Entry, sid_mask: np.ndarray, lo: int, hi: int):
 
     if _PRELUDE is None:
         _PRELUDE = _prelude_program()
-    mask = (jnp.asarray(sid_mask) if sid_mask is not None
-            else jnp.ones((entry.num_series,), bool))
     from greptimedb_tpu.telemetry import device_trace
 
     # the prelude runs before every device RANGE query; it registers
@@ -1314,6 +1320,10 @@ def run_prelude(entry: _Entry, sid_mask: np.ndarray, lo: int, hi: int):
     with device_trace.device_call(
             "range_prelude",
             key=("prelude", tuple(entry.nrow.shape))) as dcall:
+        # the mask's upload and the four scalars' readback are this
+        # call's own transfers: inside its span
+        mask = (jnp.asarray(sid_mask) if sid_mask is not None
+                else jnp.ones((entry.num_series,), bool))
         act_d, c_lo, i_lo, c_hi, i_hi = dcall.run(
             _PRELUDE, entry.nrow, entry.imin, entry.imax, mask,
             np.int32(_clamp_i32(lo)), np.int32(_clamp_i32(hi)),
@@ -1324,14 +1334,14 @@ def run_prelude(entry: _Entry, sid_mask: np.ndarray, lo: int, hi: int):
         # active-sid mask crosses at the blessed readback boundary
         act = _readback.read_full(act_d)
         dcall.transfer(act.nbytes)
-    if not act.any():
-        out = (act, None, None)
-    else:
-        out = (
-            act,
-            entry.t0c + int(c_lo) * entry.res + int(i_lo),
-            entry.t0c + int(c_hi) * entry.res + int(i_hi),
-        )
+        if not act.any():
+            out = (act, None, None)
+        else:
+            out = (
+                act,
+                entry.t0c + int(c_lo) * entry.res + int(i_lo),
+                entry.t0c + int(c_hi) * entry.res + int(i_hi),
+            )
     entry.prelude[key] = out
     return out
 
@@ -1829,264 +1839,283 @@ def execute_range_device(engine, plan, table):
 
     from greptimedb_tpu.query import stats
 
-    version = table.data_version()
-    cache: DeviceRangeCache = engine.range_cache
-    tkey = (table.info.database, table.info.name, id(table))
-    entry = cache.lookup_compatible(tkey, version, r0, plan.align_to)
-    hit_note = "hit"
-    if entry is None and getattr(engine, "persist_device_cache", True):
-        with stats.timed("grid_cache_restore_ms"), _restore_lock(tkey):
-            # the warm thread may have restored while we waited
-            entry = cache.lookup_compatible(tkey, version, r0,
-                                            plan.align_to)
-            if entry is None:
-                entry = load_entry_snapshot(
-                    table, r0, plan.align_to,
+    # the grid entry of this table: looked up, restored from its
+    # snapshot or built; `grid_cache` says which
+    with tracing.child_span("query.grid") as grid_span:
+        version = table.data_version()
+        cache: DeviceRangeCache = engine.range_cache
+        tkey = (table.info.database, table.info.name, id(table))
+        entry = cache.lookup_compatible(tkey, version, r0, plan.align_to)
+        hit_note = "hit"
+        if entry is None and getattr(engine, "persist_device_cache", True):
+            with stats.timed("grid_cache_restore_ms"), _restore_lock(tkey):
+                # the warm thread may have restored while we waited
+                entry = cache.lookup_compatible(tkey, version, r0,
+                                                plan.align_to)
+                if entry is None:
+                    entry = load_entry_snapshot(
+                        table, r0, plan.align_to,
+                        mesh=getattr(engine, "mesh", None),
+                        mesh_opts=getattr(engine, "mesh_opts", None),
+                        byte_budget=cache.byte_budget,
+                    )
+                    if entry is not None:
+                        cache.insert((tkey, entry.res, entry.phase), entry)
+                        hit_note = "miss(restored)"
+        if entry is None:
+            with stats.timed("grid_cache_build_ms"), \
+                    tracing.child_span("grid.build"):
+                entry = build_entry(
+                    plan, table, items,
                     mesh=getattr(engine, "mesh", None),
                     mesh_opts=getattr(engine, "mesh_opts", None),
                     byte_budget=cache.byte_budget,
+                    keep_host=getattr(engine, "persist_device_cache", True),
                 )
-                if entry is not None:
-                    cache.insert((tkey, entry.res, entry.phase), entry)
-                    hit_note = "miss(restored)"
-    if entry is None:
-        with stats.timed("grid_cache_build_ms"):
-            entry = build_entry(
-                plan, table, items,
-                mesh=getattr(engine, "mesh", None),
-                mesh_opts=getattr(engine, "mesh_opts", None),
-                byte_budget=cache.byte_budget,
-                keep_host=getattr(engine, "persist_device_cache", True),
+            if entry is None:
+                return None
+            stats.note("grid_cache", "miss(build)")
+            grid_span.attributes["grid_cache"] = "miss"
+            cache.insert((tkey, entry.res, entry.phase), entry)
+            persist_entry_async(entry, table)
+        else:
+            stats.note("grid_cache", hit_note)
+            grid_span.attributes["grid_cache"] = (
+                "hit" if hit_note == "hit" else "restore")
+            with stats.timed("grid_cache_ensure_ms"):
+                ok = ensure_states(entry, plan, table, items, cache=cache)
+            if not ok:
+                return None
+        stats.add("grid_cache_bytes", entry.bytes())
+        if getattr(engine, "mesh", None) is not None:
+            from greptimedb_tpu.query.planner import (
+                MeshDecision, record_mesh_decision,
             )
-        if entry is None:
+            from greptimedb_tpu.parallel.mesh import shard_count
+
+            dec = getattr(entry, "mesh_decision", None)
+            if dec is None:
+                dec = MeshDecision(
+                    "shard" if getattr(entry, "mesh", None) is not None
+                    else "replicate", "cached",
+                    devices=shard_count(engine.mesh),
+                )
+            record_mesh_decision(dec, "range")
+
+    # which series and which cells: the WHERE's ts bounds as cell
+    # bounds, its matchers through the tag index as a series mask
+    with tracing.child_span("query.select_series"):
+        res = entry.res
+        # WHERE ts bounds must land on cell edges or partials can't honor them
+        s = plan.scan
+        if s.ts_min is not None and (s.ts_min - entry.t0c) % res != 0:
             return None
-        stats.note("grid_cache", "miss(build)")
-        cache.insert((tkey, entry.res, entry.phase), entry)
-        persist_entry_async(entry, table)
-    else:
-        stats.note("grid_cache", hit_note)
-        with stats.timed("grid_cache_ensure_ms"):
-            ok = ensure_states(entry, plan, table, items, cache=cache)
-        if not ok:
+        if s.ts_max is not None and (s.ts_max + 1 - entry.t0c) % res != 0:
             return None
-    stats.add("grid_cache_bytes", entry.bytes())
-    if getattr(engine, "mesh", None) is not None:
-        from greptimedb_tpu.query.planner import (
-            MeshDecision, record_mesh_decision,
-        )
-        from greptimedb_tpu.parallel.mesh import shard_count
+        lo = ((s.ts_min - entry.t0c) // res if s.ts_min is not None
+              else -(2**31) + 1)
+        hi = ((s.ts_max + 1 - entry.t0c) // res if s.ts_max is not None
+              else 2**31 - 1)
 
-        dec = getattr(entry, "mesh_decision", None)
-        if dec is None:
-            dec = MeshDecision(
-                "shard" if getattr(entry, "mesh", None) is not None
-                else "replicate", "cached",
-                devices=shard_count(engine.mesh),
-            )
-        record_mesh_decision(dec, "range")
+        names = [nm for _, nm in plan.post_items]
+        empty = engine._empty_result(names)
+        sid_mask = None
+        mask_key = None
+        from greptimedb_tpu.query.planner import record_scan_path
 
-    res = entry.res
-    # WHERE ts bounds must land on cell edges or partials can't honor them
-    s = plan.scan
-    if s.ts_min is not None and (s.ts_min - entry.t0c) % res != 0:
-        return None
-    if s.ts_max is not None and (s.ts_max + 1 - entry.t0c) % res != 0:
-        return None
-    lo = ((s.ts_min - entry.t0c) // res if s.ts_min is not None
-          else -(2**31) + 1)
-    hi = ((s.ts_max + 1 - entry.t0c) // res if s.ts_max is not None
-          else 2**31 - 1)
+        if s.matchers:
+            from greptimedb_tpu import index as _index
 
-    names = [nm for _, nm in plan.post_items]
-    empty = engine._empty_result(names)
-    sid_mask = None
-    mask_key = None
-    from greptimedb_tpu.query.planner import record_scan_path
-
-    if s.matchers:
-        from greptimedb_tpu import index as _index
-
-        record_scan_path(_index.enabled())
-        sids = _index.match_sids(entry.registry, s.matchers)
-        if len(sids) == 0:
-            return empty
-        sid_mask = np.zeros(entry.num_series, bool)
-        sid_mask[sids[sids < entry.num_series]] = True
-        # memo on the canonical matcher key + registry version instead
-        # of hashing an O(num_series) mask per query
-        mask_key = (_index.matcher_key(s.matchers),
-                    entry.registry.version)
-    else:
-        record_scan_path(False)
+            record_scan_path(_index.enabled())
+            sids = _index.match_sids(entry.registry, s.matchers)
+            if len(sids) == 0:
+                return empty
+            sid_mask = np.zeros(entry.num_series, bool)
+            sid_mask[sids[sids < entry.num_series]] = True
+            # memo on the canonical matcher key + registry version instead
+            # of hashing an O(num_series) mask per query
+            mask_key = (_index.matcher_key(s.matchers),
+                        entry.registry.version)
+        else:
+            record_scan_path(False)
 
     active, ts_min_f, ts_max_f = run_prelude(entry, sid_mask, lo, hi)
-    if ts_min_f is None:
-        return empty
-    if plan.grid_ts_min is not None:
-        # distributed fill-grid override (see dist/dist_query.py): use
-        # the negotiated global extent so per-datanode grids match
-        ts_min_f = plan.grid_ts_min
-        ts_max_f = plan.grid_ts_max
+    # the selected series as the program takes them: the window they
+    # span, then (memoized) the group ids of the active ones and the
+    # device-side mask and bounds
+    with tracing.child_span("query.select_series", memo="hit") as sel_span:
+        if ts_min_f is None:
+            return empty
+        if plan.grid_ts_min is not None:
+            # distributed fill-grid override (see dist/dist_query.py): use
+            # the negotiated global extent so per-datanode grids match
+            ts_min_f = plan.grid_ts_min
+            ts_max_f = plan.grid_ts_max
 
-    # window math — identical to the host path (executor._execute_range)
-    align_to = plan.align_to % align if plan.align_to else 0
-    max_range = max(r.range_ms for r in plan.range_items)
-    j_first = -((-(ts_min_f - max_range + 1 - align_to)) // align)
-    j_last = (ts_max_f - align_to) // align
-    n_steps = int(j_last - j_first + 1)
-    if n_steps <= 0:
-        return empty
-    stride = align // res
-    t0q = align_to + j_first * align
-    delta = (t0q - entry.t0c) // res
-    if not (-(2**31) < delta < 2**31):
-        return None  # query window absurdly far from the data grid
-    lo_c = _clamp_i32(lo)
-    hi_c = _clamp_i32(hi)
+        # window math — identical to the host path (executor._execute_range)
+        align_to = plan.align_to % align if plan.align_to else 0
+        max_range = max(r.range_ms for r in plan.range_items)
+        j_first = -((-(ts_min_f - max_range + 1 - align_to)) // align)
+        j_last = (ts_max_f - align_to) // align
+        n_steps = int(j_last - j_first + 1)
+        if n_steps <= 0:
+            return empty
+        stride = align // res
+        t0q = align_to + j_first * align
+        delta = (t0q - entry.t0c) // res
+        if not (-(2**31) < delta < 2**31):
+            return None  # query window absurdly far from the data grid
+        lo_c = _clamp_i32(lo)
+        hi_c = _clamp_i32(hi)
 
-    memo_key = (
-        mask_key,
-        tuple(k.expr.name for k in plan.keys),
-        delta, lo_c, hi_c,
-    )
-    uploaded_bytes = 0
-    memo = entry.query_memo.get(memo_key)
-    if memo is None:
-        gid_full, g, key_cols = _group_ids_from_sids(
-            plan, entry.registry, active
+        memo_key = (
+            mask_key,
+            tuple(k.expr.name for k in plan.keys),
+            delta, lo_c, hi_c,
         )
-        # identity grouping (each real series is its own group, padded
-        # tail routed past g) needs no fold: the per-series state IS the
-        # group state. num_series is FOLD_BLOCKS-padded, so compare the
-        # real prefix, not the whole axis.
-        fold = not (g <= entry.num_series
-                    and np.array_equal(gid_full[:g], np.arange(g))
-                    and (gid_full[g:] == g).all())
-        _, put1 = _make_put(getattr(entry, "mesh", None))
-        dmask = (put1(sid_mask & active) if sid_mask is not None
-                 else put1(active))
-        memo = {
-            "gid": put1(gid_full), "mask": dmask, "g": g,
-            "key_cols": key_cols, "fold": fold,
-            "delta": jnp.int32(delta), "lo": jnp.int32(lo_c),
-            "hi": jnp.int32(hi_c),
-        }
-        # host-side sizes as the upload proxy (the devices hold the
-        # padded copies): per-query upload bytes for the trace span
-        uploaded_bytes = int(gid_full.nbytes) + int(active.nbytes)
-        if len(entry.query_memo) >= 32:
-            entry.query_memo.pop(next(iter(entry.query_memo)))
-        entry.query_memo[memo_key] = memo
-    g = memo["g"]
-    key_cols = memo["key_cols"]
-    for item in plan.range_items:
-        w_i = item.range_ms // res
-        nb_i = (n_steps - 1) * (align // res) + w_i
-        if g * nb_i > 256_000_000:
-            return None
-    step_ts = (align_to + (j_first + np.arange(n_steps)) * align).astype(
-        np.int64
-    )
-
-    prog_items = tuple(
-        (op, it.range_ms // res, fname)
-        for (fname, op), it in zip(items, plan.range_items)
-    )
-    arrs = {}
-    for fname, op in items:
-        d = arrs.setdefault(fname, {})
-        for bk in _STATE_KEYS[op]:
-            d[bk] = entry.fields[fname][bk]
-    nanenc = all(
-        entry.nan_ok.get(fname, fname == "__rows__") for fname, _ in items
-    )
-    program = get_program()
-    prog_tag = "single"
-    comm_bytes = 0
-    entry_mesh = getattr(entry, "mesh", None)
-    if entry_mesh is not None:
-        if (not memo["fold"]
-                or _fold_blocks(g, entry.nb, entry.num_series) != 1):
-            # explicit-collective shard_map program with the blocked
-            # exact fold (bit-identical across mesh sizes)
-            program = get_sharded_program(entry_mesh)
-            prog_tag = "sharded"
-            # kernel variant: same decision decide_mesh_execution
-            # recorded at plan time (deterministic in the same inputs,
-            # so no double count here)
-            from greptimedb_tpu.query.planner import decide_kernel
-
-            kern, _ = decide_kernel(
-                "range", series=entry.num_series,
-                opts=getattr(engine, "mesh_opts", None),
+        uploaded_bytes = 0
+        memo = entry.query_memo.get(memo_key)
+        if memo is None:
+            sel_span.attributes["memo"] = "miss"
+            gid_full, g, key_cols = _group_ids_from_sids(
+                plan, entry.registry, active
             )
-            if kern == "pallas":
-                program = get_sharded_program(entry_mesh, kernel=True)
-                prog_tag = "sharded_pallas"
-                from greptimedb_tpu.parallel.kernels.ring_fold import (
-                    fold_comm_bytes,
+            # identity grouping (each real series is its own group,
+            # padded tail routed past g) needs no fold: the per-series
+            # state IS the group state. num_series is
+            # FOLD_BLOCKS-padded, so compare the real prefix, not the
+            # whole axis.
+            fold = not (g <= entry.num_series
+                        and np.array_equal(gid_full[:g], np.arange(g))
+                        and (gid_full[g:] == g).all())
+            _, put1 = _make_put(getattr(entry, "mesh", None))
+            dmask = (put1(sid_mask & active) if sid_mask is not None
+                     else put1(active))
+            memo = {
+                "gid": put1(gid_full), "mask": dmask, "g": g,
+                "key_cols": key_cols, "fold": fold,
+                "delta": jnp.int32(delta), "lo": jnp.int32(lo_c),
+                "hi": jnp.int32(hi_c),
+            }
+            # host-side sizes as the upload proxy (the devices hold the
+            # padded copies): per-query upload bytes for the trace span
+            uploaded_bytes = int(gid_full.nbytes) + int(active.nbytes)
+            if len(entry.query_memo) >= 32:
+                entry.query_memo.pop(next(iter(entry.query_memo)))
+            entry.query_memo[memo_key] = memo
+    # the program for this shape and its inputs: state planes, spec,
+    # mesh variant, the session buffer of a repeated poll
+    with tracing.child_span("query.plan", phase="program"):
+        g = memo["g"]
+        key_cols = memo["key_cols"]
+        for item in plan.range_items:
+            w_i = item.range_ms // res
+            nb_i = (n_steps - 1) * (align // res) + w_i
+            if g * nb_i > 256_000_000:
+                return None
+        step_ts = (align_to + (j_first + np.arange(n_steps)) * align).astype(
+            np.int64
+        )
+
+        prog_items = tuple(
+            (op, it.range_ms // res, fname)
+            for (fname, op), it in zip(items, plan.range_items)
+        )
+        arrs = {}
+        for fname, op in items:
+            d = arrs.setdefault(fname, {})
+            for bk in _STATE_KEYS[op]:
+                d[bk] = entry.fields[fname][bk]
+        nanenc = all(
+            entry.nan_ok.get(fname, fname == "__rows__") for fname, _ in items
+        )
+        program = get_program()
+        prog_tag = "single"
+        comm_bytes = 0
+        entry_mesh = getattr(entry, "mesh", None)
+        if entry_mesh is not None:
+            if (not memo["fold"]
+                    or _fold_blocks(g, entry.nb, entry.num_series) != 1):
+                # explicit-collective shard_map program with the blocked
+                # exact fold (bit-identical across mesh sizes)
+                program = get_sharded_program(entry_mesh)
+                prog_tag = "sharded"
+                # kernel variant: same decision decide_mesh_execution
+                # recorded at plan time (deterministic in the same inputs,
+                # so no double count here)
+                from greptimedb_tpu.query.planner import decide_kernel
+
+                kern, _ = decide_kernel(
+                    "range", series=entry.num_series,
+                    opts=getattr(engine, "mesh_opts", None),
                 )
-                from greptimedb_tpu.parallel.mesh import shard_count
+                if kern == "pallas":
+                    program = get_sharded_program(entry_mesh, kernel=True)
+                    prog_tag = "sharded_pallas"
+                    from greptimedb_tpu.parallel.kernels.ring_fold import (
+                        fold_comm_bytes,
+                    )
+                    from greptimedb_tpu.parallel.mesh import shard_count
 
-                ns_ = shard_count(entry_mesh)
-                for op_i, w_i, _f in prog_items:
-                    nb_i = (n_steps - 1) * stride + w_i
-                    planes = 1 + len(_STATE_COMBINE.get(op_i, ()))
-                    comm_bytes += fold_comm_bytes(ns_, g, nb_i, planes)
-        else:
-            # oversized blocked fold (FOLD_BLOCKS*g*nb past the partial
-            # budget): stays on the auto-SPMD jit program — still
-            # sharded, but XLA picks the combine order, so this is a
-            # DOCUMENTED bit-identity exception; surface it
-            stats.note("mesh_fold_range", "auto_spmd(oversized_fold)")
-            prog_tag = "auto_spmd"
-    prog_spec = (stride, n_steps, g, memo["fold"], nanenc, prog_items)
-    from greptimedb_tpu.query import readback, sessions
-    from greptimedb_tpu.telemetry import device_trace
+                    ns_ = shard_count(entry_mesh)
+                    for op_i, w_i, _f in prog_items:
+                        nb_i = (n_steps - 1) * stride + w_i
+                        planes = 1 + len(_STATE_COMBINE.get(op_i, ()))
+                        comm_bytes += fold_comm_bytes(ns_, g, nb_i, planes)
+            else:
+                # oversized blocked fold (FOLD_BLOCKS*g*nb past the partial
+                # budget): stays on the auto-SPMD jit program — still
+                # sharded, but XLA picks the combine order, so this is a
+                # DOCUMENTED bit-identity exception; surface it
+                stats.note("mesh_fold_range", "auto_spmd(oversized_fold)")
+                prog_tag = "auto_spmd"
+        prog_spec = (stride, n_steps, g, memo["fold"], nanenc, prog_items)
+        from greptimedb_tpu.query import readback, sessions
+        from greptimedb_tpu.telemetry import device_trace
 
-    # delta-poll cursor: j0 = first step whose __ts is past the
-    # client's watermark. With FILL the full grid must assemble first
-    # (PREV/LINEAR carry from pre-cursor steps), so the cursor moves
-    # to cell emission; otherwise only delta steps are read back.
-    since = sessions.current_since()
-    has_fill = plan.fill is not None or any(
-        r.fill is not None for r in plan.range_items
-    )
-    j0 = 0
-    if since is not None and not has_fill:
-        j0 = int(np.searchsorted(step_ts, since, side="right"))
-        if j0 >= n_steps:
-            return empty  # the client has every step already
+        # delta-poll cursor: j0 = first step whose __ts is past the
+        # client's watermark. With FILL the full grid must assemble first
+        # (PREV/LINEAR carry from pre-cursor steps), so the cursor moves
+        # to cell emission; otherwise only delta steps are read back.
+        since = sessions.current_since()
+        has_fill = plan.fill is not None or any(
+            r.fill is not None for r in plan.range_items
+        )
+        j0 = 0
+        if since is not None and not has_fill:
+            j0 = int(np.searchsorted(step_ts, since, side="right"))
+            if j0 >= n_steps:
+                return empty  # the client has every step already
 
-    # persistent query session: the folded RESULT buffer of this exact
-    # query shape stays HBM-resident across polls — a repeated
-    # dashboard query skips the program dispatch round trip entirely
-    # (each dispatch is a host->device round trip) and the
-    # delta path slices the resident buffer device-side below
-    # keyed to THIS grid entry (id): two engines over the same table
-    # (e.g. the sharded and single-device twins in the parity fuzz)
-    # must not blindly share buffers across entries, and the cache
-    # releases an entry's buffers when it drops the entry
-    # (DeviceRangeCache._release — id reuse can never serve stale).
-    # Tables assembled per-call (datanode partials) opt out — their
-    # entry ids never repeat, so puts could only accumulate dead
-    # buffers.
-    use_sessions = getattr(table, "session_cacheable", True)
-    session_tkey = ("range", id(entry))
-    session_key = (memo_key, prog_spec)
-    out_dev = (sessions.global_sessions.get(
-        session_tkey, session_key, entry.version
-    ) if use_sessions else None)
-    # device-time attribution: one span per query carrying compile
-    # (first-call vs cache-hit), block_until_ready execute time and
-    # transfer bytes — the transfer cost becomes a named span on the
-    # trace. Attribution comes from device_trace's PROCESS-level memo,
-    # matching the jit cache's scope (the entry-level program_specs
-    # memo resets with every rebuilt grid entry — e.g. each datanode
-    # partial builds a fresh table — and would mislabel warm programs
-    # as first_call). A session hit keeps the span (execute is the
-    # skipped dispatch, ~0) so traces always show the device leg.
-    first_spec = prog_spec not in entry.program_specs
+        # persistent query session: the folded RESULT buffer of this exact
+        # query shape stays HBM-resident across polls — a repeated
+        # dashboard query skips the program dispatch round trip entirely
+        # (each dispatch is a host->device round trip) and the
+        # delta path slices the resident buffer device-side below
+        # keyed to THIS grid entry (id): two engines over the same table
+        # (e.g. the sharded and single-device twins in the parity fuzz)
+        # must not blindly share buffers across entries, and the cache
+        # releases an entry's buffers when it drops the entry
+        # (DeviceRangeCache._release — id reuse can never serve stale).
+        # Tables assembled per-call (datanode partials) opt out — their
+        # entry ids never repeat, so puts could only accumulate dead
+        # buffers.
+        use_sessions = getattr(table, "session_cacheable", True)
+        session_tkey = ("range", id(entry))
+        session_key = (memo_key, prog_spec)
+        out_dev = (sessions.global_sessions.get(
+            session_tkey, session_key, entry.version
+        ) if use_sessions else None)
+        # device-time attribution: one span per query carrying compile
+        # (first-call vs cache-hit), block_until_ready execute time and
+        # transfer bytes — the transfer cost becomes a named span on the
+        # trace. Attribution comes from device_trace's PROCESS-level memo,
+        # matching the jit cache's scope (the entry-level program_specs
+        # memo resets with every rebuilt grid entry — e.g. each datanode
+        # partial builds a fresh table — and would mislabel warm programs
+        # as first_call). A session hit keeps the span (execute is the
+        # skipped dispatch, ~0) so traces always show the device leg.
+        first_spec = prog_spec not in entry.program_specs
     # program identity carries the mesh variant (single-device vs
     # shard_map twin vs auto-SPMD fold): the profiler must never
     # cross-serve mesh twins under one registry row
@@ -2125,36 +2154,37 @@ def execute_range_device(engine, plan, table):
         sliced = out_dev if memo["fold"] else out_dev[:, :g]
         out = readback.read_delta(sliced, j0, axis=-1)
         dcall.transfer(out.nbytes, "readback")
-    if first_spec:
-        entry.program_specs[prog_spec] = True
-        concurrency.Thread(
-            target=_persist_program_specs, args=(entry, table),
-            daemon=True, name="program-specs-persist",
-        ).start()
-    step_ts_eff = step_ts[j0:] if j0 else step_ts
-    n_steps_eff = n_steps - j0
-    stats.add("device_readback_bytes", out.nbytes)
-    stats.add("range_groups", g)
-    stats.add("range_steps", n_steps)
-    n_items = len(plan.range_items)
-    vals = out[:n_items].astype(np.float64)
-    if nanenc:
-        pres = np.empty_like(vals, dtype=bool)
-        for i, (fname, op) in enumerate(items):
-            if op == "count":
-                pres[i] = vals[i] > 0
-            else:
-                pres[i] = np.isfinite(vals[i])
-    else:
-        pres = out[n_items:] > 0.5
+    # host arrays -> QueryResult, from the values read back on
+    with tracing.child_span("query.assemble"):
+        if first_spec:
+            entry.program_specs[prog_spec] = True
+            concurrency.Thread(
+                target=_persist_program_specs, args=(entry, table),
+                daemon=True, name="program-specs-persist",
+            ).start()
+        step_ts_eff = step_ts[j0:] if j0 else step_ts
+        n_steps_eff = n_steps - j0
+        stats.add("device_readback_bytes", out.nbytes)
+        stats.add("range_groups", g)
+        stats.add("range_steps", n_steps)
+        n_items = len(plan.range_items)
+        vals = out[:n_items].astype(np.float64)
+        if nanenc:
+            pres = np.empty_like(vals, dtype=bool)
+            for i, (fname, op) in enumerate(items):
+                if op == "count":
+                    pres[i] = vals[i] > 0
+                else:
+                    pres[i] = np.isfinite(vals[i])
+        else:
+            pres = out[n_items:] > 0.5
 
-    item_vals = {}
-    item_present = {}
-    for i, item in enumerate(plan.range_items):
-        item_vals[item.key] = vals[i]
-        item_present[item.key] = pres[i]
-    return engine._assemble_range_result(
-        plan, table, item_vals, item_present, key_cols, step_ts_eff,
-        g, n_steps_eff,
-        since_ms=since if has_fill else None,
-    )
+        item_vals = {}
+        item_present = {}
+        for i, item in enumerate(plan.range_items):
+            item_vals[item.key] = vals[i]
+            item_present[item.key] = pres[i]
+        return engine._assemble_range_traced(
+            plan, table, item_vals, item_present, key_cols, step_ts_eff,
+            g, n_steps_eff, since if has_fill else None,
+        )

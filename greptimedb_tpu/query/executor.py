@@ -699,6 +699,18 @@ class QueryEngine:
         fill math still runs over the full grid first, so PREV/LINEAR
         carry from pre-cursor steps stays identical to the full
         result)."""
+        from greptimedb_tpu.telemetry import tracing
+
+        with tracing.child_span("query.assemble"):
+            return self._assemble_range_traced(
+                plan, table, item_vals, item_present, key_cols,
+                step_ts, g, n_steps, since_ms)
+
+    def _assemble_range_traced(self, plan, table, item_vals,
+                               item_present, key_cols, step_ts, g,
+                               n_steps, since_ms) -> QueryResult:
+        """The assembly itself; the device path calls it inside its own
+        `query.assemble` span, which starts at the readback."""
         ts_type = table.schema.time_index.data_type
         names = [nm for _, nm in plan.post_items]
         any_present = np.zeros((g, n_steps), dtype=bool)

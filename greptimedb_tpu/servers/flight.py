@@ -495,25 +495,33 @@ class FlightServer(flight.FlightServerBase):
         if "." in name:
             db, name = name.split(".", 1)
         table = inst.catalog.table(db, name)
-        for chunk in reader:
-            batch = chunk.data
-            data: dict = {}
-            valid: dict = {}
-            for i in range(batch.num_columns):
-                cname = batch.schema.field(i).name
-                arr = batch.column(i)
-                if pa.types.is_timestamp(arr.type):
-                    # normalize to ms before the shared converter so null
-                    # timestamps fill to int 0, not float NaN
-                    arr = arr.cast(pa.timestamp("ms"))
-                hc = HostColumn.from_arrow(cname, arr)
-                data[cname] = hc.values
-                valid[cname] = hc.valid_mask
-            try:
-                inst._write_columns(table, data, valid)
-            except Exception as e:  # noqa: BLE001 - RPC boundary
-                raise wrap_flight_error(e) from e
-            inst._notify_flows(db, name, table, data, valid)
+        from greptimedb_tpu.telemetry import tracing
+
+        # one root per stream, its chunks' stages beneath it (the ring
+        # keeps a trace's first spans; the per-name times count all)
+        with tracing.start_remote(None, "flight.do_put",
+                                  table=f"{db}.{name}"):
+            for chunk in reader:
+                batch = chunk.data
+                data: dict = {}
+                valid: dict = {}
+                with tracing.child_span("write.decode"):
+                    for i in range(batch.num_columns):
+                        cname = batch.schema.field(i).name
+                        arr = batch.column(i)
+                        if pa.types.is_timestamp(arr.type):
+                            # normalize to ms before the shared
+                            # converter so null timestamps fill to
+                            # int 0, not float NaN
+                            arr = arr.cast(pa.timestamp("ms"))
+                        hc = HostColumn.from_arrow(cname, arr)
+                        data[cname] = hc.values
+                        valid[cname] = hc.valid_mask
+                try:
+                    inst._write_columns(table, data, valid)
+                except Exception as e:  # noqa: BLE001 - RPC boundary
+                    raise wrap_flight_error(e) from e
+                inst._notify_flows(db, name, table, data, valid)
 
     def _do_put_regions(self, reader):
         """Per-region columnar writes: each batch's app_metadata names

@@ -35,7 +35,7 @@ from greptimedb_tpu.promql.engine import (
 )
 from greptimedb_tpu.servers import influx, prom_store
 from greptimedb_tpu.session import QueryContext
-from greptimedb_tpu.telemetry import global_registry
+from greptimedb_tpu.telemetry import global_registry, tracing
 from greptimedb_tpu.version import __version__
 
 from greptimedb_tpu import concurrency
@@ -179,11 +179,12 @@ def _make_handler(instance, user_provider=None, *, enable_scripts=False,
         # ------------------------------------------------------------------
         def _send(self, code: int, body: bytes,
                   content_type: str = "application/json"):
-            self.send_response(code)
-            self.send_header("Content-Type", content_type)
-            self.send_header("Content-Length", str(len(body)))
-            self.end_headers()
-            self.wfile.write(body)
+            with tracing.child_span("http.send"):
+                self.send_response(code)
+                self.send_header("Content-Type", content_type)
+                self.send_header("Content-Length", str(len(body)))
+                self.end_headers()
+                self.wfile.write(body)
             _REQS.labels(self._route(), str(code)).inc()
 
         _KNOWN_ROUTES = (
@@ -265,8 +266,6 @@ def _make_handler(instance, user_provider=None, *, enable_scripts=False,
                      "/v1/cluster/metrics", "/v1/cluster/health")
 
         def _dispatch(self, method: str):
-            from greptimedb_tpu.telemetry import tracing
-
             path = self._raw_path()
             t0 = time.perf_counter()
             if path in self._UNTRACED or path.startswith("/v1/traces/"):
@@ -614,7 +613,8 @@ def _make_handler(instance, user_provider=None, *, enable_scripts=False,
 
         # ------------------------------------------------------------------
         def _handle_sql(self):
-            params = self._form()
+            with tracing.child_span("http.read"):
+                params = self._form()
             sql = params.get("sql")
             if not sql:
                 return self._error(400, "missing sql parameter")
@@ -676,29 +676,33 @@ def _make_handler(instance, user_provider=None, *, enable_scripts=False,
                     200, body.encode(),
                     "text/csv" if fmt == "csv" else "text/plain",
                 )
-            out_json = []
-            partial = None
-            for o in outputs:
-                if o.result is not None:
-                    out_json.append(result_to_json(o.result))
-                    if getattr(o.result, "partial", False):
-                        partial = {
-                            "partial": True,
-                            "missing_regions": int(getattr(
-                                o.result, "missing_regions", 0)),
-                        }
-                else:
-                    out_json.append({"affectedrows": o.affected_rows or 0})
-            doc = {
-                "output": out_json,
-                "execution_time_ms": round(elapsed, 3),
-            }
-            if partial is not None:
-                # graceful degradation is EXPLICIT: a client must be
-                # able to tell a complete answer from a shed-datanode
-                # one ([scheduler] allow_partial_results)
-                doc.update(partial)
-            self._json(200, doc)
+            with tracing.child_span("http.encode"):
+                out_json = []
+                partial = None
+                for o in outputs:
+                    if o.result is not None:
+                        out_json.append(result_to_json(o.result))
+                        if getattr(o.result, "partial", False):
+                            partial = {
+                                "partial": True,
+                                "missing_regions": int(getattr(
+                                    o.result, "missing_regions", 0)),
+                            }
+                    else:
+                        out_json.append(
+                            {"affectedrows": o.affected_rows or 0})
+                doc = {
+                    "output": out_json,
+                    "execution_time_ms": round(elapsed, 3),
+                }
+                if partial is not None:
+                    # graceful degradation is EXPLICIT: a client must
+                    # be able to tell a complete answer from a
+                    # shed-datanode one ([scheduler]
+                    # allow_partial_results)
+                    doc.update(partial)
+                body = json.dumps(doc).encode()
+            self._send(200, body)
 
         # ------------------------------------------------------------------
         def _handle_prom_api(self, endpoint: str):

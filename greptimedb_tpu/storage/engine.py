@@ -498,9 +498,12 @@ class TsdbEngine:
     def _background_loop(self):
         # the interval wait rides the concurrency facade's Event so
         # gtsan sees (and can fail) the loop's blocking behavior
+        from greptimedb_tpu.telemetry import tracing
+
         while not self._stop.wait(self.config.background_interval_s):
             try:
-                self.run_maintenance()
+                with tracing.background_span("engine.maintenance"):
+                    self.run_maintenance()
             except Exception:  # pragma: no cover - keep the loop alive
                 import traceback
 

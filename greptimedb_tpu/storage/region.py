@@ -34,6 +34,7 @@ from greptimedb_tpu.storage.sst import (
     write_sst,
 )
 from greptimedb_tpu.storage.wal import RegionWal
+from greptimedb_tpu.telemetry import tracing
 
 from greptimedb_tpu import concurrency
 
@@ -341,8 +342,6 @@ class Region:
                     "fmt": 2, "op": op, "base_seq": base_seq,
                     "new_series": [[sid, tags] for sid, tags in delta],
                 })
-                from greptimedb_tpu.telemetry import tracing
-
                 try:
                     # joins the INSERT's trace when one is active (the
                     # background paths carry none, so this is free
@@ -359,19 +358,21 @@ class Region:
                     self._pending_new_series.extend(new_series)
                     raise
                 self._pending_new_series = []
-            self.memtable.append(rows)
+            with tracing.child_span("memtable.append"):
+                self.memtable.append(rows)
             return base_seq
 
     def _make_rows(self, tag_columns, ts, fields, field_valid, op, base_seq):
         """Intern tags and normalize fields into sid-resolved ColumnarRows.
         Returns (rows, new_series_delta)."""
         n = len(ts)
-        sids, new_series = self.series.intern_rows_delta(
-            [np.asarray(tag_columns[name], object) if name in tag_columns
-             else np.full(n, "", object)
-             for name in self.meta.tag_names],
-            n=n,
-        )
+        with tracing.child_span("write.intern"):
+            sids, new_series = self.series.intern_rows_delta(
+                [np.asarray(tag_columns[name], object)
+                 if name in tag_columns else np.full(n, "", object)
+                 for name in self.meta.tag_names],
+                n=n,
+            )
         full_fields, valids = self._normalize_fields(n, fields, field_valid)
         rows = ColumnarRows(
             sid=sids,
@@ -472,8 +473,6 @@ class Region:
 
     def flush(self) -> SstMeta | None:
         """Freeze the memtable, write an SST, commit manifest, trim WAL."""
-        from greptimedb_tpu.telemetry import tracing
-
         with tracing.child_span("region.flush",
                                 region=self.meta.region_id):
             return self._flush_traced()

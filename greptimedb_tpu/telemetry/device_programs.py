@@ -114,6 +114,12 @@ _M_CALLS = global_registry.counter(
     "device program dispatches per (site, program)",
     labels=("site", "program"),
 )
+_M_COMPILES = global_registry.counter(
+    "gtpu_device_program_compiles_total",
+    "first successful dispatches of a program row per site: the calls "
+    "whose wall time is compile_ms",
+    labels=("site",),
+)
 _M_EXEC = global_registry.counter(
     "gtpu_device_program_execute_ms_total",
     "cumulative steady-state execute ms per (site, program) "
@@ -536,10 +542,15 @@ class DeviceProgramRegistry:
                run_start: float | None = None,
                collective: bool = False, comm_bytes: int = 0):
         with self._lock:
+            cold = row.compile_ms is None
             row.fold_call(execute_ms, upload, readback,
                           dispatch_only=dispatch_only,
                           run_start=run_start,
                           collective=collective, comm_bytes=comm_bytes)
+            compiled = cold and row.compile_ms is not None
+        if compiled:
+            # rare (once a program row): the one push-model family here
+            _M_COMPILES.labels(row.site).inc()
 
     def _metric_prog_locked(self, prog_id: str) -> str:
         if prog_id in self._metric_progs:
@@ -837,10 +848,17 @@ def capture_trace(seconds: float, out_dir: str | None = None) -> dict:
         os.makedirs(path, exist_ok=True)
         import jax
 
+        from greptimedb_tpu.telemetry import tracing
+
         jax.profiler.start_trace(path)
         try:
+            # spans, background ticks and collections now also open
+            # `gtpu:<name>` events on their threads' lines: the host's
+            # stages lie on the profiler's clock beside the device's
+            tracing.set_annotating(True)
             time.sleep(seconds)
         finally:
+            tracing.set_annotating(False)
             jax.profiler.stop_trace()
     finally:
         with _capture_lock:

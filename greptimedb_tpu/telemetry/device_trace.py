@@ -21,10 +21,9 @@ Each wrapped call produces one `device.execute` span carrying:
   (block_until_ready), excluding result readback
 - upload_bytes / readback_bytes: host->device and device->host traffic
   attributable to this call
-- program + roofline attribution (telemetry/device_programs.py): the
-  program-registry id, and — once the program's XLA cost analysis has
-  run — flops, bound=compute|memory and this call's achieved GFLOP/s
-  / %-of-peak
+- program: the program-registry id (telemetry/device_programs.py);
+  the roofline numbers live on the registry row and in EXPLAIN
+  ANALYZE's `roofline_<site>` note, not on the span
 
 Dispatching THROUGH `device_call.run(fn, *args, **kw)` additionally
 folds the call into the process-wide device-program registry
@@ -169,10 +168,11 @@ class device_call:
 
     def _fold_program(self, sp, rec, *, dispatched: bool):
         """Fold the dispatch into the program registry (when one
-        happened) + attach the program / roofline attribution to the
-        span, EXPLAIN ANALYZE stats and the statement observation.
-        A no-dispatch path (session hit) attributes without folding —
-        and without per-call achieved rates, since no compute ran."""
+        happened) + attach the program id to the span and the
+        statement observation, and the program / roofline notes to
+        EXPLAIN ANALYZE's stats. A no-dispatch path (session hit)
+        attributes without folding — and without per-call achieved
+        rates, since no compute ran."""
         from greptimedb_tpu.telemetry import device_programs
 
         reg = device_programs.global_programs
@@ -188,54 +188,47 @@ class device_call:
             # the program ids its executions used (dispatched, or
             # served from the program's session buffer)
             stmt_stats.note_program(rec.prog_id)
-        roof = None
-        if rec.analysis == "ok":
-            pf, pb, _plat, _src = reg.peaks()
-            bound, _pct = rec.roofline(pf, pb)
-            gflops = gbps = pct = 0.0
-            if (dispatched and self._exec_ms and self._exec_ms > 0
-                    and not self._dispatch_only and not self._first):
-                s = self._exec_ms / 1000.0
-                gflops = rec.flops / s / 1e9
-                gbps = rec.bytes_accessed / s / 1e9
-                if bound == "compute":
-                    pct = gflops / (pf * 1e3) * 100.0
-                elif bound == "memory":
-                    pct = gbps / pb * 100.0
-            roof = (bound, gflops, gbps, pct)
-        traced = sp is not None and sp.trace_id
-        if traced:
+        if sp is not None and sp.trace_id:
             sp.attributes["program"] = rec.prog_id
-            if roof is not None:
-                sp.attributes["flops"] = rec.flops
-                if roof[0]:
-                    sp.attributes["roofline_bound"] = roof[0]
-                    if dispatched:
-                        sp.attributes["pct_of_peak"] = round(roof[3], 3)
-                if dispatched:
-                    sp.attributes["achieved_gflops"] = round(roof[1], 3)
         from greptimedb_tpu.query import stats as qstats
 
-        if qstats.active() is not None:
-            qstats.note(f"device_program_{self.site}", rec.prog_id)
-            if roof is not None and roof[0]:
-                if dispatched:
-                    qstats.note(
-                        f"roofline_{self.site}",
-                        f"{roof[0]}-bound {roof[3]:.1f}% of peak "
-                        f"({roof[1]:.1f} GFLOP/s, {roof[2]:.1f} GB/s)",
-                    )
-                else:
-                    # steady-state row numbers: this call served from
-                    # the session buffer, no program ran
-                    _bound, row_pct = rec.roofline(pf, pb)
-                    g, b = rec.achieved()
-                    qstats.note(
-                        f"roofline_{self.site}",
-                        f"{roof[0]}-bound {row_pct:.1f}% of peak at "
-                        f"p50 ({g:.1f} GFLOP/s, {b:.1f} GB/s; served "
-                        "from the session buffer)",
-                    )
+        if qstats.active() is None:
+            return
+        # EXPLAIN ANALYZE only: the roofline note is computed for the
+        # operator who asked, never for a span
+        qstats.note(f"device_program_{self.site}", rec.prog_id)
+        if rec.analysis != "ok":
+            return
+        pf, pb, _plat, _src = reg.peaks()
+        bound, row_pct = rec.roofline(pf, pb)
+        if not bound:
+            return
+        if not dispatched:
+            # steady-state row numbers: this call served from the
+            # session buffer, no program ran
+            g, b = rec.achieved()
+            qstats.note(
+                f"roofline_{self.site}",
+                f"{bound}-bound {row_pct:.1f}% of peak at "
+                f"p50 ({g:.1f} GFLOP/s, {b:.1f} GB/s; served "
+                "from the session buffer)",
+            )
+            return
+        gflops = gbps = pct = 0.0
+        if (self._exec_ms and self._exec_ms > 0
+                and not self._dispatch_only and not self._first):
+            s = self._exec_ms / 1000.0
+            gflops = rec.flops / s / 1e9
+            gbps = rec.bytes_accessed / s / 1e9
+            if bound == "compute":
+                pct = gflops / (pf * 1e3) * 100.0
+            else:
+                pct = gbps / pb * 100.0
+        qstats.note(
+            f"roofline_{self.site}",
+            f"{bound}-bound {pct:.1f}% of peak "
+            f"({gflops:.1f} GFLOP/s, {gbps:.1f} GB/s)",
+        )
 
     def __exit__(self, exc_type, exc, tb):
         sp = self._span

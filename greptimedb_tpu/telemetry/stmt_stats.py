@@ -43,6 +43,7 @@ from collections import OrderedDict
 
 from greptimedb_tpu import concurrency
 from greptimedb_tpu.telemetry import metrics as _metrics
+from greptimedb_tpu.telemetry import tracing
 from greptimedb_tpu.telemetry.metrics import (
     global_registry,
     set_child_value as _set_counter,
@@ -686,8 +687,6 @@ class _Observation:
         if (not reg.config.enable or fp is None
                 or _current.get() is not None):
             return None
-        from greptimedb_tpu.telemetry import tracing  # cycle-safe lazy
-
         ctx = self._ctx
         db = getattr(ctx, "database", "") or "public"
         obs = _Obs(fp, db, getattr(ctx, "username", "") or db,
@@ -763,7 +762,11 @@ class StmtStatsRegistry:
         with self._lock:
             self._pending.append((obs, elapsed_ms, code))
             if len(self._pending) >= self._PENDING_MAX:
-                self._drain_locked()
+                # the whole fold runs on this statement's thread: the
+                # span names the request that paid for it
+                with tracing.child_span("stmt_stats.drain",
+                                        observations=len(self._pending)):
+                    self._drain_locked()
 
     def _drain_locked(self):
         for obs, elapsed_ms, code in self._pending:
@@ -832,7 +835,8 @@ class StmtStatsRegistry:
         the bases under the collapsed rows' own labels. The publish
         lock covers snapshot AND writes: publishes serialize, so each
         scrape exposes a consistent, never-older aggregate."""
-        with self._publish_lock:
+        with tracing.background_span("stmt_stats.publish"), \
+                self._publish_lock:
             self._publish_locked()
 
     def _publish_locked(self):
@@ -915,6 +919,7 @@ class StmtStatsRegistry:
 
 
 global_stmt_stats = StmtStatsRegistry()
+tracing.declare("stmt_stats.drain")
 # scrape-time publisher: /metrics (and runtime_metrics, and the
 # self-export loop) refresh the gtpu_stmt_* families from the registry
 # rows on every render — zero prometheus work on the statement hot path

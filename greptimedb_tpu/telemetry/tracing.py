@@ -28,12 +28,31 @@ error traces, slow traces (>= slow_ms) and explicitly marked traces
 Timestamps: `start_ms` is epoch milliseconds (display/correlation);
 durations are computed on the MONOTONIC clock (an NTP slew must never
 produce negative or absurd span durations — gtlint GT011).
+
+Every finished span also exports its time BY NAME: wall time into the
+histogram `gtpu_span_seconds{name}`, thread CPU time into the counter
+`gtpu_span_cpu_seconds_total{name}` (folded into a per-name table on
+the span's own thread, published at scrape time like the statement
+statistics). Span names are code literals plus bounded f-strings
+(`http <route>`, `sql.<kind>`, `dist.<stage>`, `recovery.<stage>`), so
+the label set is bounded. Pauses no request owns are counted beside
+them: `background_span` for periodic loops
+(`gtpu_background_task_seconds{task}`; never in the ring unless slower
+than `slow_ms`) and a `gc.callbacks` hook
+(`gtpu_runtime_gc_pause_seconds{generation}`). While a device trace
+capture runs, spans, ticks and collections also open a
+`jax.profiler.TraceAnnotation("gtpu:<name>")`, so they lie in the
+capture on the profiler's clock beside the device's operations.
 """
 
 from __future__ import annotations
 
+import bisect
+import collections
 import contextlib
 import contextvars
+import gc
+import itertools
 import random
 import secrets
 
@@ -41,6 +60,7 @@ import time
 from dataclasses import dataclass, field
 
 from greptimedb_tpu import concurrency
+from greptimedb_tpu.telemetry import metrics as _metrics
 
 _current_span: contextvars.ContextVar["Span | None"] = (
     contextvars.ContextVar("gtpu_span", default=None)
@@ -98,7 +118,144 @@ def ring_unbounded() -> bool:
     return global_traces.cap <= 0
 
 
-@dataclass
+# ---------------------------------------------------------------------------
+# time by name — PULL-model like the statement statistics: a finished
+# span folds (count, wall, thread CPU, one bucket) into a per-name row
+# under one short lock; the gtpu_span_* / gtpu_background_task_* /
+# gtpu_runtime_gc_pause_* families are refreshed from the rows at
+# scrape time, so no span touches a prometheus child lock.
+# ---------------------------------------------------------------------------
+
+# the statement-latency bounds (stmt_stats._BUCKETS_MS), in seconds
+_BOUNDS_S = (
+    0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
+    1.0, 2.5, 5.0, 10.0, 30.0, 60.0,
+)
+
+_SPAN_SECONDS = _metrics.global_registry.histogram(
+    "gtpu_span_seconds",
+    "wall time of finished trace spans, by span name",
+    labels=("name",), buckets=_BOUNDS_S,
+)
+_SPAN_CPU = _metrics.global_registry.counter(
+    "gtpu_span_cpu_seconds_total",
+    "thread CPU time spent inside trace spans, by span name",
+    labels=("name",),
+)
+_BACKGROUND_SECONDS = _metrics.global_registry.histogram(
+    "gtpu_background_task_seconds",
+    "wall time of periodic background work no request owns, by task",
+    labels=("task",), buckets=_BOUNDS_S,
+)
+_GC_PAUSE_SECONDS = _metrics.global_registry.histogram(
+    "gtpu_runtime_gc_pause_seconds",
+    "wall time of Python garbage collections, by generation",
+    labels=("generation",), buckets=_BOUNDS_S,
+)
+
+
+class _TimeRow:
+    __slots__ = ("count", "wall_s", "cpu_s", "buckets")
+
+    def __init__(self):
+        self.count = 0
+        self.wall_s = 0.0
+        self.cpu_s = 0.0
+        self.buckets = [0] * (len(_BOUNDS_S) + 1)
+
+
+_rows_lock = concurrency.Lock()
+_span_rows: dict[str, _TimeRow] = {}
+_background_rows: dict[str, _TimeRow] = {}
+# one row a generation, made up front and written WITHOUT the lock: a
+# collection can start inside any allocation, also one made under
+# `_rows_lock` by the same thread, and collections never overlap
+_gc_rows: dict[str, _TimeRow] = {g: _TimeRow() for g in "012"}
+
+
+def _fold(rows: dict, name: str, wall_s: float, cpu_s: float = 0.0):
+    # first bound >= the value, or the trailing OVERFLOW slot
+    # (metrics.observe_bucket's layout)
+    slot = bisect.bisect_left(_BOUNDS_S, wall_s)
+    with _rows_lock:
+        row = rows.get(name)
+        if row is None:
+            row = rows[name] = _TimeRow()
+        row.count += 1
+        row.wall_s += wall_s
+        row.cpu_s += cpu_s
+        row.buckets[slot] += 1
+
+
+def declare(*names: str):
+    """Export a zero row for span names that finish rarely, so a
+    reader of deltas finds the series before the first one does."""
+    with _rows_lock:
+        for name in names:
+            _span_rows.setdefault(name, _TimeRow())
+
+
+def _publish_rows():
+    """MetricsRegistry collector: refresh the four families from the
+    per-name rows."""
+    with _rows_lock:
+        snap = [
+            (fam, name, row.count, row.wall_s, row.cpu_s,
+             list(row.buckets))
+            for fam, rows in ((_SPAN_SECONDS, _span_rows),
+                              (_BACKGROUND_SECONDS, _background_rows),
+                              (_GC_PAUSE_SECONDS, _gc_rows))
+            for name, row in rows.items()
+        ]
+    for fam, name, count, wall_s, cpu_s, buckets in snap:
+        hist = fam.labels(name)
+        with hist._lock:
+            cum = 0
+            # the trailing OVERFLOW slot only reaches the +Inf bucket,
+            # which the exposition derives from `count`
+            for i in range(len(_BOUNDS_S)):
+                cum += buckets[i]
+                hist.counts[i] = cum
+            hist.count = count
+            hist.total = wall_s
+        if fam is _SPAN_SECONDS:
+            _metrics.set_child_value(_SPAN_CPU.labels(name), cpu_s)
+
+
+_metrics.global_registry.register_collector(_publish_rows)
+
+# Thread CPU time is read for one local root in `_CPU_EVERY` and for
+# the spans beneath it, and counted `_CPU_EVERY` times over: where
+# `time.thread_time()` is a real system call (6 us alone and 15 us a
+# call in a serving process on the benchmark's machine, two calls a
+# span, seventeen spans a query) reading it on every request cost 0.5 ms
+# of a 10 ms query. The estimate is unbiased; a test sets it to 1.
+_CPU_EVERY = 32
+_cpu_turn = itertools.count()
+
+
+# True while a device trace capture runs (device_programs.capture_trace
+# sets it): spans, background ticks and collections then also open a
+# profiler annotation. Off the capture this is one boolean test.
+_annotating = False
+
+
+def set_annotating(on: bool):
+    global _annotating
+    _annotating = bool(on)
+
+
+def _annotate(name: str):
+    """Open a `gtpu:<name>` event on this thread's line of the running
+    capture; the caller closes it with `__exit__`."""
+    import jax
+
+    ann = jax.profiler.TraceAnnotation("gtpu:" + name)
+    ann.__enter__()
+    return ann
+
+
+@dataclass(slots=True)
 class Span:
     trace_id: str
     span_id: str
@@ -113,6 +270,12 @@ class Span:
     # deliberately does not propagate to descendants — a child exit
     # must never roll the sampling dice while the root is in flight)
     remote: bool = False
+    # the ring's list of this trace (set when the store records the
+    # span): a child appends itself there without the store's lock
+    sink: list | None = field(default=None, repr=False, compare=False)
+    # this request's tree is one of those whose thread CPU time is read
+    # (see _CPU_EVERY); children follow their parent
+    cpu: bool = field(default=False, repr=False, compare=False)
 
     def to_json(self) -> dict:
         return {
@@ -137,8 +300,11 @@ class _TraceStore:
 
     def __init__(self, cap: int = _MAX_TRACES):
         self._lock = concurrency.Lock()
-        self._spans: dict[str, list[Span]] = {}
-        self._order: list[str] = []
+        # insertion-ordered: the oldest trace is the first key, so
+        # eviction and the sampled-out drop are both O(1)
+        self._spans: collections.OrderedDict[str, list[Span]] = (
+            collections.OrderedDict()
+        )
         self._kept: set[str] = set()
         # local roots currently in flight per trace (a client may send
         # one traceparent on several concurrent requests): a sampled-
@@ -163,7 +329,8 @@ class _TraceStore:
     SPAN_EST_BYTES = 512
 
     def _mem_stats(self) -> dict:
-        with self._lock:
+        # scrape-time work under the lock every span takes
+        with background_span("trace_ring.stats"), self._lock:
             n_spans = sum(len(s) for s in self._spans.values())
             return {
                 "bytes": n_spans * self.SPAN_EST_BYTES,
@@ -181,21 +348,30 @@ class _TraceStore:
     def _evict_locked(self):
         if self.cap <= 0:
             return  # unbounded (bench.py refuses to run like this)
-        while len(self._order) > self.cap:
-            victim = self._order.pop(0)
-            self._spans.pop(victim, None)
+        while len(self._spans) > self.cap:
+            victim, _ = self._spans.popitem(last=False)
             self._kept.discard(victim)
             self.evicted_traces += 1
 
     def record(self, span: Span):
+        parent_sink = span.sink
+        if parent_sink is not None:
+            # a child of a span recorded here: straight into its
+            # trace's list (one append; the bound may be overshot by
+            # the few threads of one request racing, never unboundedly).
+            # A trace dropped or evicted meanwhile keeps its orphaned
+            # list until its last span finishes.
+            if len(parent_sink) < self.MAX_SPANS_PER_TRACE:
+                parent_sink.append(span)
+            return
         with self._lock:
-            if span.trace_id not in self._spans:
-                self._spans[span.trace_id] = []
-                self._order.append(span.trace_id)
+            spans = self._spans.get(span.trace_id)
+            if spans is None:
+                spans = self._spans[span.trace_id] = []
                 self._evict_locked()
-            spans = self._spans[span.trace_id]
             if len(spans) < self.MAX_SPANS_PER_TRACE:
                 spans.append(span)
+            span.sink = spans
 
     def enter_root(self, trace_id: str):
         with self._lock:
@@ -238,10 +414,6 @@ class _TraceStore:
                 # must still be able to keep the trace).
                 self._spans.pop(tid, None)
                 self._kept.discard(tid)
-                try:
-                    self._order.remove(tid)
-                except ValueError:
-                    pass
 
     def ingest(self, span_dicts: list, limit: int = _MAX_EXPORT_SPANS):
         """Record spans exported by ANOTHER process (gtdb:spans
@@ -267,8 +439,11 @@ class _TraceStore:
     def traces(self, limit: int = 50) -> list[dict]:
         with self._lock:
             out = []
-            for tid in reversed(self._order[-limit:]):
-                spans = self._spans.get(tid, [])
+            newest = reversed(self._spans)
+            if limit > 0:
+                newest = itertools.islice(newest, int(limit))
+            for tid in newest:
+                spans = self._spans[tid]
                 out.append({
                     "trace_id": tid,
                     "spans": [s.to_json() for s in spans],
@@ -282,7 +457,6 @@ class _TraceStore:
     def clear(self):
         with self._lock:
             self._spans.clear()
-            self._order.clear()
             self._kept.clear()
             self._active.clear()
 
@@ -306,7 +480,7 @@ class span:
     Nests under the current span; starts a new trace at the root."""
 
     __slots__ = ("name", "attributes", "_parent", "_span", "_token",
-                 "_mono0", "_local_root")
+                 "_mono0", "_cpu0", "_ann", "_local_root")
 
     def __init__(self, name: str, _parent: Span | None = None,
                  **attributes):
@@ -316,6 +490,8 @@ class span:
         self._span: Span | None = None
         self._token = None
         self._mono0 = 0.0
+        self._cpu0 = 0.0
+        self._ann = None
         self._local_root = False
 
     def __enter__(self) -> Span:
@@ -335,23 +511,43 @@ class span:
             # epoch-ms START timestamp for display/correlation; the
             # duration below comes from the monotonic clock (GT011)
             start_ms=time.time() * 1000.0,
-            attributes=dict(self.attributes),
+            # the constructor's kwargs dict is this span's own
+            attributes=self.attributes,
         )
-        self._mono0 = time.monotonic()
-        self._token = _current_span.set(self._span)
+        sp = self._span
+        self._token = _current_span.set(sp)
         if self._local_root:
-            global_traces.enter_root(self._span.trace_id)
+            global_traces.enter_root(sp.trace_id)
+            sp.cpu = next(_cpu_turn) % _CPU_EVERY == 0
+        else:
+            sp.sink = parent.sink
+            sp.cpu = parent.cpu
         # recorded at START: /v1/traces shows in-flight spans (duration
         # null) and a span is never missing just because its exit races
         # a reader; __exit__ finalizes the same object in place
-        global_traces.record(self._span)
-        return self._span
+        global_traces.record(sp)
+        if _annotating:
+            self._ann = _annotate(self.name)
+        # the clocks start last and stop first, so the span's own
+        # bookkeeping stays out of the time it reports; the CPU reading
+        # lies inside the wall reading, so CPU does not exceed wall
+        self._mono0 = time.monotonic()
+        if sp.cpu:
+            self._cpu0 = time.thread_time()
+        return sp
 
     def __exit__(self, exc_type, exc, tb):
         sp = self._span
         if self._token is None:
             return False  # disabled at __enter__ time
-        sp.end_ms = sp.start_ms + (time.monotonic() - self._mono0) * 1000.0
+        cpu_s = ((time.thread_time() - self._cpu0) * _CPU_EVERY
+                 if sp.cpu else 0.0)
+        wall_s = time.monotonic() - self._mono0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
+        sp.end_ms = sp.start_ms + wall_s * 1000.0
+        _fold(_span_rows, self.name, wall_s, cpu_s)
         if exc is not None:
             sp.attributes["error"] = f"{type(exc).__name__}: {exc}"
         _current_span.reset(self._token)
@@ -412,12 +608,91 @@ def event_span(name: str, duration_ms: float, **attributes):
         trace_id=parent.trace_id, span_id=_new_id(8),
         parent_id=parent.span_id, name=name,
         start_ms=now - dur, end_ms=now,
-        attributes=dict(attributes),
+        attributes=dict(attributes), sink=parent.sink,
     )
     global_traces.record(sp)
+    _fold(_span_rows, name, dur / 1000.0)
     col = _collector.get()
     if col is not None and len(col) < _MAX_EXPORT_SPANS:
         col.append(sp)
+
+
+class background_span:
+    """`with tracing.background_span("engine.maintenance"):` around one
+    round of a periodic loop that no request owns (engine maintenance,
+    flow tick, heartbeat, scrape-time publishers). Its time goes to
+    `gtpu_span_seconds{name}` and `gtpu_background_task_seconds{task}`
+    and, during a capture, into the profile; the trace ring only ever
+    sees a round slower than `[tracing] slow_ms` (a 1 Hz tick must not
+    churn the query traces out). Spans opened inside it find no parent,
+    so `child_span` stays a no-op there."""
+
+    __slots__ = ("name", "_mono0", "_cpu0", "_ann")
+
+    def __init__(self, name: str):
+        self.name = name
+        self._mono0 = None
+        self._cpu0 = 0.0
+        self._ann = None
+
+    def __enter__(self):
+        if _config.enabled:
+            if _annotating:
+                self._ann = _annotate(self.name)
+            self._mono0 = time.monotonic()
+            self._cpu0 = time.thread_time()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if self._mono0 is None:
+            return False
+        cpu_s = time.thread_time() - self._cpu0
+        wall_s = time.monotonic() - self._mono0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
+        _fold(_span_rows, self.name, wall_s, cpu_s)
+        _fold(_background_rows, self.name, wall_s, cpu_s)
+        if wall_s * 1000.0 >= _config.slow_ms:
+            now = time.time() * 1000.0
+            sp = Span(
+                trace_id=_new_id(16), span_id=_new_id(8), parent_id=None,
+                name=self.name, start_ms=now - wall_s * 1000.0,
+                end_ms=now, attributes={"background": True},
+            )
+            global_traces.record(sp)
+            global_traces.decide(sp)
+        return False
+
+
+# the collection in flight: (monotonic start, annotation). One slot is
+# enough, since a collection runs start -> stop on one thread and no
+# other starts in between.
+_gc_open: list = [None]
+
+
+def _on_gc(phase: str, info: dict):
+    if not _config.enabled:
+        return
+    if phase == "start":
+        ann = (_annotate(f"gc.gen{info['generation']}")
+               if _annotating else None)
+        _gc_open[0] = (time.monotonic(), ann)
+        return
+    opened, _gc_open[0] = _gc_open[0], None
+    if opened is None:
+        return      # enabled between its start and its stop
+    wall_s = time.monotonic() - opened[0]
+    if opened[1] is not None:
+        opened[1].__exit__(None, None, None)
+    row = _gc_rows[str(info["generation"])]
+    row.count += 1
+    row.wall_s += wall_s
+    row.buckets[bisect.bisect_left(_BOUNDS_S, wall_s)] += 1
+
+
+if _on_gc not in gc.callbacks:
+    gc.callbacks.append(_on_gc)
 
 
 def current_span() -> Span | None:

@@ -392,3 +392,411 @@ def test_sibling_root_drop_cannot_destroy_errored_trace():
         assert {s["name"] for s in spans} >= {"request-a", "request-b"}
     finally:
         tracing.configure({})
+
+
+# ---------------------------------------------------------------------------
+# one stage tree per request, time by name, pauses no request owns
+# ---------------------------------------------------------------------------
+
+STAGES = (
+    "http.read", "sql.fingerprint", "sql.parse", "sched.admit",
+    "query.plan", "query.select_series", "query.grid", "device.execute",
+    "query.assemble", "http.encode", "http.send", "stmt_stats.drain",
+)
+
+
+def _family(text: str, family: str, **labels) -> float | None:
+    """Sum of `family`'s samples in a /metrics text whose labels
+    include `labels`; None where no such sample is there."""
+    want = [f'{k}="{v}"' for k, v in labels.items()]
+    total, seen = 0.0, False
+    for ln in text.splitlines():
+        if ln.startswith("#") or not ln.startswith(family):
+            continue
+        head, _, value = ln.rpartition(" ")
+        name, _, lbs = head.partition("{")
+        if name == family and all(w in lbs for w in want):
+            total += float(value)
+            seen = True
+    return total if seen else None
+
+
+def _render() -> str:
+    from greptimedb_tpu.telemetry.metrics import global_registry
+
+    return global_registry.render()
+
+
+def _post_sql(port: int, sql: str, **headers) -> bytes:
+    import urllib.parse
+
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{port}/v1/sql",
+        data=urllib.parse.urlencode({"sql": sql}).encode(),
+        headers=headers)
+    return urllib.request.urlopen(req, timeout=60).read()
+
+
+def _finished_trace(trace_id: str) -> list[dict]:
+    """The trace once its root has finished: the client has its last
+    byte before the server closes `http.send` and the root."""
+    import time
+
+    for _ in range(200):
+        spans = tracing.global_traces.trace(trace_id)
+        if spans and all(s["duration_ms"] is not None for s in spans):
+            return spans
+        time.sleep(0.01)
+    raise AssertionError(f"trace {trace_id} never finished: {spans}")
+
+
+@pytest.fixture
+def panel(tmp_path, monkeypatch):
+    """A server holding a small `cpu` table on the device path, warm
+    for the panel query's shape; yields (port, query maker)."""
+    pytest.importorskip("jax")
+    from greptimedb_tpu.servers.http import HttpServer
+    from greptimedb_tpu.telemetry import stmt_stats
+
+    inst = Standalone(str(tmp_path / "data"), warm_start=False,
+                      prefer_device=True)
+    srv = HttpServer(inst, port=0).start()
+    inst.sql("CREATE TABLE cpu (hostname STRING, region STRING, "
+             "usage_user DOUBLE, ts TIMESTAMP TIME INDEX, "
+             "PRIMARY KEY(hostname, region))")
+    # a fleet's worth of series, so that the stages outweigh the fixed
+    # bookkeeping around them as they do at a deployment's size
+    import numpy as np
+
+    hosts, cells = 2048, 360
+    host = np.repeat(np.arange(hosts), cells)
+    inst._write_columns(inst.catalog.table("public", "cpu"), {
+        "hostname": np.char.add("host_", host.astype(str)).astype(object),
+        "region": np.char.add("r", (host % 3).astype(str)).astype(object),
+        "usage_user": ((host * 7 + np.tile(np.arange(cells), hosts))
+                       % 100).astype(np.float64),
+        "ts": np.tile(np.arange(cells, dtype=np.int64) * 10000, hosts),
+    }, {})
+
+    def query(host: int) -> str:
+        return ("SELECT ts, hostname, max(usage_user) RANGE '60s' FROM "
+                f"cpu WHERE hostname IN ('host_{host}') AND ts >= 0 AND "
+                "ts < 3600000 ALIGN '60s' BY (hostname)")
+
+    for h in range(4):
+        _post_sql(srv.port, query(h))
+    # the statement statistics fold in line at the bound: bring it
+    # down so that one request of the test pays a drain
+    monkeypatch.setattr(stmt_stats.StmtStatsRegistry, "_PENDING_MAX", 1)
+    try:
+        yield srv.port, query
+    finally:
+        srv.stop()
+        inst.close()
+
+
+def _stage_tree(port: int, sql: str, tid: str) -> tuple[float, float]:
+    """One request under trace id `tid`, its tree checked; returns the
+    time its stages cover and its root's."""
+    _post_sql(port, sql, traceparent=f"00-{tid}-{'cd' * 8}-01")
+    spans = _finished_trace(tid)
+    by_id = {s["span_id"]: s for s in spans}
+    root = next(s for s in spans if s["name"] == "http /v1/sql")
+    stages = [s for s in spans if s["name"] in STAGES]
+    assert {s["name"] for s in stages} == set(STAGES)
+    for s in stages:
+        assert s["trace_id"] == tid
+        # a descendant of the request
+        up = s
+        while up["span_id"] != root["span_id"]:
+            up = by_id[up["parent_id"]]
+    # flat: no stage inside another, so their times add up
+    for s in stages:
+        assert by_id[s["parent_id"]]["name"] not in STAGES, s["name"]
+    grid = next(s for s in stages if s["name"] == "query.grid")
+    assert grid["attributes"]["grid_cache"] == "hit"
+    dev = [s for s in stages if s["name"] == "device.execute"]
+    assert len(dev) == 2 and all("execute_ms" in s["attributes"]
+                                 for s in dev)
+    for s in dev:
+        for gone in ("flops", "roofline_bound", "pct_of_peak",
+                     "achieved_gflops"):
+            assert gone not in s["attributes"]
+    return sum(s["duration_ms"] for s in stages), root["duration_ms"]
+
+
+def test_one_request_yields_the_twelve_stages_in_one_tree(panel):
+    port, query = panel
+    trees = [_stage_tree(port, query(100 + i), f"{i:02x}" * 16)
+             for i in range(1, 6)]
+    # what the stages leave is the root's (and the statement's) self
+    # time: under a tenth of the request. A request that a collection
+    # or another test's thread falls into reads lower, so the best of
+    # five is held to it
+    assert max(c / r for c, r in trees) >= 0.9, trees
+
+
+def test_span_time_is_exported_by_name(panel, monkeypatch):
+    port, query = panel
+    # thread CPU is read for one request in `_CPU_EVERY` and scaled up:
+    # read every one here, so that three requests give exact numbers
+    monkeypatch.setattr(tracing, "_CPU_EVERY", 1)
+    m0 = _render()
+    for h in (5, 6, 7):
+        _post_sql(port, query(h))
+    import time
+
+    time.sleep(0.1)     # the roots close after the last byte
+    m1 = _render()
+
+    def moved(family, **labels):
+        return ((_family(m1, family, **labels) or 0.0)
+                - (_family(m0, family, **labels) or 0.0))
+
+    assert moved("gtpu_span_seconds_count", name="sql.parse") == 3
+    assert moved("gtpu_span_seconds_count", name="device.execute") == 6
+    assert moved("gtpu_span_seconds_count", name="http /v1/sql") == 3
+    for name in ("sql.parse", "query.plan", "http /v1/sql"):
+        wall = moved("gtpu_span_seconds_sum", name=name)
+        cpu = moved("gtpu_span_cpu_seconds_total", name=name)
+        # the CPU reading lies inside the wall reading; the two clocks
+        # are not one, so allow their drift
+        assert 0 < cpu <= wall * 1.05 + 1e-4, (name, cpu, wall)
+    # cumulative buckets end at the count
+    assert (_family(m1, "gtpu_span_seconds_bucket", name="sql.parse",
+                    le="+Inf")
+            == _family(m1, "gtpu_span_seconds_count", name="sql.parse"))
+
+
+def test_thread_cpu_is_read_for_one_tree_in_n(monkeypatch):
+    """One local root in `_CPU_EVERY` has its thread CPU read, its
+    children with it, and the reading counts `_CPU_EVERY` times."""
+    monkeypatch.setattr(tracing, "_CPU_EVERY", 4)
+    m0 = _render()
+    flags = []
+    for _ in range(8):
+        with tracing.span("cpu.root") as root:
+            with tracing.child_span("cpu.child") as child:
+                sum(range(20000))
+            assert child.cpu == root.cpu
+            flags.append(root.cpu)
+    assert sum(flags) == 2
+    m1 = _render()
+    wall = _family(m1, "gtpu_span_seconds_sum", name="cpu.child") - (
+        _family(m0, "gtpu_span_seconds_sum", name="cpu.child") or 0.0)
+    cpu = _family(m1, "gtpu_span_cpu_seconds_total", name="cpu.child") - (
+        _family(m0, "gtpu_span_cpu_seconds_total", name="cpu.child")
+        or 0.0)
+    # two of eight equal pieces of work read, each counted four times
+    assert 0.5 * wall < cpu < 1.5 * wall, (cpu, wall)
+
+
+def test_disabled_tracing_moves_no_span_family(tmp_path):
+    import gc
+
+    inst = Standalone(str(tmp_path / "data"), warm_start=False)
+    tracing.configure({"enable": False})
+    try:
+        m0 = _render()
+        inst.sql("CREATE TABLE t (v DOUBLE, ts TIMESTAMP TIME INDEX)")
+        inst.sql("INSERT INTO t (v, ts) VALUES (1.0, 1)")
+        inst.sql("SELECT count(*) FROM t")
+        with tracing.background_span("idle.tick"):
+            pass
+        gc.collect()
+        m1 = _render()
+    finally:
+        tracing.configure({})
+        inst.close()
+    for family in ("gtpu_span_seconds_count", "gtpu_span_seconds_sum",
+                   "gtpu_span_cpu_seconds_total",
+                   "gtpu_background_task_seconds_count",
+                   "gtpu_runtime_gc_pause_seconds_count"):
+        assert _family(m1, family) == _family(m0, family), family
+    assert _family(m1, "gtpu_span_seconds_count",
+                   name="idle.tick") is None
+
+
+def test_background_span_is_counted_and_stays_out_of_the_ring():
+    with tracing.span("a request"):
+        pass
+    ring = len(tracing.global_traces.traces(0))
+    m0 = _render()
+    for _ in range(3):
+        with tracing.background_span("test.tick"):
+            # a loop's inner spans find no request to join
+            with tracing.child_span("region.flush"):
+                pass
+    m1 = _render()
+    for family, label in (("gtpu_span_seconds_count", "name"),
+                          ("gtpu_background_task_seconds_count", "task")):
+        got = (_family(m1, family, **{label: "test.tick"})
+               - (_family(m0, family, **{label: "test.tick"}) or 0.0))
+        assert got == 3, family
+    assert len(tracing.global_traces.traces(0)) == ring
+    # a round slower than slow_ms is an operator's business: kept
+    tracing.configure({"slow_ms": 0.0})
+    try:
+        with tracing.background_span("test.slow_tick"):
+            pass
+    finally:
+        tracing.configure({})
+    kept = tracing.global_traces.traces(1)[0]["spans"]
+    assert [s["name"] for s in kept] == ["test.slow_tick"]
+    assert kept[0]["attributes"] == {"background": True}
+
+
+def test_an_idle_server_adds_no_trace(tmp_path):
+    """Engine maintenance and the flow tick run every few tens of
+    milliseconds here: counted, and not one trace."""
+    import time
+
+    from greptimedb_tpu.storage.engine import EngineConfig
+
+    root = str(tmp_path / "data")
+    inst = Standalone(root, warm_start=False, engine_config=EngineConfig(
+        data_root=root, background_interval_s=0.02))
+    try:
+        inst.enable_flows(tick_interval_s=0.02)
+        # the first region's opening starts the maintenance loop
+        inst.sql("CREATE TABLE t (v DOUBLE, ts TIMESTAMP TIME INDEX)")
+        tracing.global_traces.clear()
+        m0 = _render()
+        time.sleep(0.5)
+        m1 = _render()
+        idle = tracing.global_traces.traces(0)
+    finally:
+        inst.close()
+    assert idle == []
+    for task in ("engine.maintenance", "flow.tick"):
+        got = (_family(m1, "gtpu_background_task_seconds_count", task=task)
+               or 0.0) - (_family(
+                   m0, "gtpu_background_task_seconds_count", task=task)
+                   or 0.0)
+        assert got >= 3, (task, got)
+
+
+def test_forced_collection_is_counted():
+    import gc
+
+    m0 = _render()
+    gc.collect()
+    m1 = _render()
+    assert (_family(m1, "gtpu_runtime_gc_pause_seconds_count",
+                    generation="2")
+            - _family(m0, "gtpu_runtime_gc_pause_seconds_count",
+                      generation="2")) == 1
+    assert (_family(m1, "gtpu_runtime_gc_pause_seconds_sum",
+                    generation="2")
+            > _family(m0, "gtpu_runtime_gc_pause_seconds_sum",
+                      generation="2"))
+
+
+def test_capture_holds_gtpu_events_on_the_profilers_clock(panel, tmp_path):
+    """While a capture runs, spans, ticks and collections lie in the
+    `.xplane.pb` as `gtpu:<name>` events beside the device's."""
+    import gc
+    import glob
+    import threading
+
+    from greptimedb_tpu.telemetry import device_programs as DP
+
+    port, query = panel
+    box: dict = {}
+    cap = threading.Thread(
+        target=lambda: box.update(DP.capture_trace(
+            1.0, str(tmp_path / "traces"))))
+    cap.start()
+    try:
+        import time
+
+        time.sleep(0.4)     # the profiler is up
+        assert tracing._annotating
+        for h in (10, 11):
+            _post_sql(port, query(h))
+        with tracing.background_span("test.tick"):
+            gc.collect()
+    finally:
+        cap.join(timeout=60)
+    assert not tracing._annotating
+    pb = glob.glob(box["trace_dir"] + "/**/*.xplane.pb", recursive=True)
+    assert pb, box
+    from jax.profiler import ProfileData
+
+    names = set()
+    for plane in ProfileData.from_file(pb[0]).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("gtpu:"):
+                    names.add(ev.name)
+    assert {"gtpu:" + s for s in STAGES} <= names, names
+    assert {"gtpu:http /v1/sql", "gtpu:test.tick", "gtpu:gc.gen2"} <= names
+    # off the capture a span opens no annotation
+    with tracing.span("after") as sp:
+        assert sp.trace_id
+
+
+def test_do_put_stream_is_one_trace_with_its_write_stages(tmp_path):
+    import numpy as np
+    import pyarrow as pa
+    import pyarrow.flight as flight
+
+    from greptimedb_tpu.servers.flight import FlightFrontend
+
+    inst = Standalone(str(tmp_path / "data"), warm_start=False)
+    fs = FlightFrontend(inst, port=0).start()
+    try:
+        inst.sql("CREATE TABLE cpu (hostname STRING PRIMARY KEY, "
+                 "usage_user DOUBLE, ts TIMESTAMP TIME INDEX)")
+        schema = pa.schema([("hostname", pa.string()),
+                            ("ts", pa.timestamp("ms")),
+                            ("usage_user", pa.float64())])
+        m0 = _render()
+        client = flight.connect(f"grpc://127.0.0.1:{fs.port}")
+        writer, _ = client.do_put(
+            flight.FlightDescriptor.for_path("cpu"), schema)
+        for chunk in range(2):
+            writer.write_batch(pa.record_batch([
+                pa.array([f"host_{i}" for i in range(8)]),
+                pa.array(np.arange(8, dtype=np.int64) + chunk * 8,
+                         pa.timestamp("ms")),
+                pa.array(np.arange(8, dtype=np.float64)),
+            ], schema=schema))
+        writer.close()      # the acknowledgement
+        client.close()
+        m1 = _render()
+        assert inst.sql("SELECT count(*) FROM cpu").rows()[0][0] == 16
+    finally:
+        fs.close()
+        inst.close()
+    puts = [tr["spans"] for tr in tracing.global_traces.traces(0)
+            if any(s["name"] == "flight.do_put" for s in tr["spans"])]
+    assert len(puts) == 1
+    names = [s["name"] for s in puts[0]]
+    for stage in ("write.decode", "write.tag_columns", "write.intern",
+                  "wal.append", "memtable.append"):
+        assert names.count(stage) == 2, (stage, names)
+        got = (_family(m1, "gtpu_span_seconds_count", name=stage)
+               - (_family(m0, "gtpu_span_seconds_count", name=stage)
+                  or 0.0))
+        assert got == 2, stage
+    root = next(s for s in puts[0] if s["name"] == "flight.do_put")
+    assert root["parent_id"] is None
+    assert root["attributes"]["table"] == "public.cpu"
+
+
+def test_ring_order_is_constant_time_and_newest_first():
+    tracing.configure({"capacity": 3})
+    try:
+        ids = []
+        for i in range(5):
+            with tracing.span(f"t{i}") as sp:
+                ids.append(sp.trace_id)
+        got = [t["trace_id"] for t in tracing.global_traces.traces(0)]
+        assert got == ids[:1:-1]            # the three newest, newest first
+        assert [t["trace_id"] for t in
+                tracing.global_traces.traces(2)] == ids[:2:-1]
+        assert tracing.global_traces.evicted_traces >= 2
+        assert not hasattr(tracing.global_traces, "_order")
+    finally:
+        tracing.configure({})
