@@ -385,8 +385,8 @@ try:
         )
         from greptimedb_tpu.telemetry.metrics import global_registry
 
-        # a ts-bounded twin of the same window re-dispatches the
-        # memoized prelude (same program, new memo key) and a GROUP BY
+        # a ts-bounded twin of the same window dispatches the range
+        # program again (same program, new cell bounds) and a GROUP BY
         # exercises the fused-reduce program, so every site has a
         # steady-state sample behind its %-of-peak
         inst.sql(query.replace("FROM cpu ", "FROM cpu WHERE ts >= 0 "))
@@ -937,7 +937,7 @@ def cold_start_probe(data_dir: str):
     assert entry.rows_scanned == HOSTS * CELLS  # restored, not rebuilt
     nbytes = entry.bytes()
     # first query: what a restart pays AFTER the background
-    # warm — parse/plan, compile-cache load, prelude, execution, result
+    # warm — parse/plan, compile-cache load, execution, result
     t2 = time.perf_counter()
     res = inst.sql(query)
     first_q = time.perf_counter() - t2

@@ -16,6 +16,14 @@ and every query is one XLA program:
     cells (S, NB) --mask--> fold sids->groups --gather--> window combine
     (stride doubling, O(log W) passes) --strided sample--> finalize
 
+One program, one call, one readback: the step window has to be static,
+and the exact one depends on where the selected rows start and end,
+which only the device knows. So the host bounds the window from the
+WHERE's cell bounds before the dispatch, the program computes that
+window and, beside it, the selected rows' exact extent and which series
+hold any, and the host trims to the exact window after the readback (a
+step's value depends on its absolute index alone).
+
 Cache design:
 - one `_Entry` per (table, resolution, phase); holds (S, NB) device arrays
   of per-cell partial aggregate states per field: {s, n, s2, mn, mx, vl/tl,
@@ -49,10 +57,23 @@ from greptimedb_tpu.errors import UnsupportedError
 from greptimedb_tpu.program_cache import ProgramCache
 from greptimedb_tpu.sql import ast as A
 from greptimedb_tpu.telemetry import tracing
+from greptimedb_tpu.telemetry.metrics import global_registry
 
 from greptimedb_tpu import concurrency
 
 _log = logging.getLogger("greptimedb_tpu.query.device_range")
+
+# the program computes the window the host can bound before the
+# dispatch; steps outside the rows' exact extent are trimmed after the
+# readback. A deployment whose WHERE spans (or open sides) run far past
+# its data shows here as trimmed="yes", and by how many steps on the
+# `device.execute` span's `trimmed_steps`.
+_WINDOW_TRIM = global_registry.counter(
+    "gtpu_range_window_trim_total",
+    "device RANGE queries by whether steps of the bound window were "
+    "trimmed to the rows' exact extent after the readback",
+    labels=("trimmed",),
+)
 
 DEVICE_THRESHOLD = 262_144       # min table rows before the cache pays off
 _CELL_CAP = 256 * 1024 * 1024    # max S*NB cells per cached array (1GB f32)
@@ -110,10 +131,11 @@ class _Entry:
     nrow: object = None          # (S, NB) int32 rows per cell (all rows)
     imin: object = None          # (S, NB) int32 intra-cell offset of min ts
     imax: object = None          # (S, NB) int32 intra-cell offset of max ts
-    # memoized prelude results keyed by (matcher_sig, lo, hi)
-    prelude: dict = dc_field(default_factory=dict)
-    # memoized per-query-shape device args + group decode (steady-state
-    # queries re-upload nothing)
+    # the one per-query memo, keyed by the selection (matcher key +
+    # registry version, BY keys): group ids, series mask and group
+    # decode, and under "windows" per (lo, hi) cell bounds what the
+    # program said of them (exact extent, active groups), so a repeated
+    # poll re-derives nothing and a session hit dispatches nothing
     query_memo: dict = dc_field(default_factory=dict)
     # device bytes held; stored (not recomputed) so concurrent readers
     # never iterate `fields` while a grow mutates it
@@ -283,15 +305,14 @@ class DeviceRangeCache:
             total = 0
             for e in self._entries.values():
                 total += e.bytes()
-                # per-query-shape gid/mask device inputs ride the
+                # per-query-shape group-id device inputs ride the
                 # entry (query_memo) but are outside recount_bytes'
                 # grid contract — the watermark must still see them
                 # (the census enumerates the same arrays)
                 for memo in list(e.query_memo.values()):
-                    for k in ("gid", "mask"):
-                        arr = memo.get(k)
-                        if arr is not None:
-                            total += int(getattr(arr, "nbytes", 0))
+                    arr = memo["gid"]
+                    if arr is not None:
+                        total += int(arr.nbytes)
             return {
                 "bytes": total,
                 "entries": len(self._entries),
@@ -330,15 +351,14 @@ class DeviceRangeCache:
                         if id(arr) not in seen:
                             seen.add(id(arr))
                             out.append((arr, f"{tag}:{fname}"))
-                # per-query-shape device inputs (gid/mask uploads) the
+                # per-query-shape device inputs (group-id uploads) the
                 # steady state keeps resident — without owner tags the
                 # census would read them as leaks
                 for memo in list(e.query_memo.values()):
-                    for k in ("gid", "mask"):
-                        arr = memo.get(k)
-                        if arr is not None and id(arr) not in seen:
-                            seen.add(id(arr))
-                            out.append((arr, f"{tag}:query_memo"))
+                    arr = memo["gid"]
+                    if arr is not None and id(arr) not in seen:
+                        seen.add(id(arr))
+                        out.append((arr, f"{tag}:query_memo"))
         return out
 
 
@@ -906,6 +926,13 @@ def _program_specs_path(entry: _Entry, region) -> str:
             f"programs_{entry.res}_{entry.phase}.json")
 
 
+# what a persisted spec list was written for: the fused program's
+# signature and spec meaning (n_steps and g of the BOUND window and the
+# MATCHED series). A file without it predates the fused program: its
+# specs name programs no query would ask for, so it is skipped.
+_SPECS_SIGNATURE = "fused-1"
+
+
 def _persist_program_specs(entry: _Entry, table) -> None:
     """Record the static jit specs this entry has served (capped), so a
     restarted process can precompile them during warm — the first query
@@ -919,11 +946,11 @@ def _persist_program_specs(entry: _Entry, table) -> None:
     # most-RECENT 8 (insertion order): the specs a restart will actually
     # be asked for again
     specs = list(entry.program_specs)[-8:]
-    doc = [
+    doc = {"signature": _SPECS_SIGNATURE, "specs": [
         {"stride": st, "n_steps": ns, "g": g, "fold": fo,
          "nanenc": ne, "items": [list(it) for it in items]}
         for st, ns, g, fo, ne, items in specs
-    ]
+    ]}
     try:
         region.store.write(
             _program_specs_path(entry, region),
@@ -993,40 +1020,36 @@ def precompile_programs(entry: _Entry, table) -> int:
         return 0
     import json as _json
 
-    import jax.numpy as jnp
-
     region = table.regions[0]
     try:
         raw = region.store.read(_program_specs_path(entry, region))
         doc = _json.loads(raw)
     except Exception:  # noqa: BLE001 - no specs file: nothing to do
         return 0
-    # the prelude program runs before every query; compile it too (the
-    # matcher-less variant the flagship shape uses)
-    try:
-        run_prelude(entry, None, -(2**31) + 1, 2**31 - 1)
-    except Exception as e:  # noqa: BLE001
-        # warmup miss: the first real query compiles it instead
-        _log.debug("prelude precompile skipped: %s", e)
+    if (not isinstance(doc, dict)
+            or doc.get("signature") != _SPECS_SIGNATURE):
+        return 0
     entry_mesh = getattr(entry, "mesh", None)
-    _, put1 = _make_put(entry_mesh)
-    # spec inputs shared by every precompile invocation below: pinned
-    # (and owner-tagged) in the warm-scratch pool for the duration
+    # the kind of argument a selection's first query passes
+    # (_selection_gid): a NumPy value that rides each call on one
+    # device, where nothing is pinned; on a mesh a series-sharded device
+    # buffer shared by every precompile invocation, pinned (and
+    # owner-tagged) in the warm-scratch pool for the duration
+    zero_sid = np.zeros(entry.num_series, np.int32)
+    if entry_mesh is None:
+        return _precompile_loop(entry, doc["specs"], entry_mesh, zero_sid)
+    zero_sid = _make_put(entry_mesh)[1](zero_sid)
     label = f"{table.info.database}.{table.info.name}"
-    zero_sid = _WARM_SCRATCH.hold(
-        put1(np.zeros(entry.num_series, np.int32)), label
-    )
-    ones_mask = _WARM_SCRATCH.hold(
-        put1(np.ones(entry.num_series, bool)), label
-    )
+    _WARM_SCRATCH.hold(zero_sid, label)
     try:
-        return _precompile_loop(entry, doc, entry_mesh, zero_sid,
-                                ones_mask, jnp)
+        return _precompile_loop(entry, doc["specs"], entry_mesh, zero_sid)
     finally:
-        _WARM_SCRATCH.drop(zero_sid, ones_mask)
+        _WARM_SCRATCH.drop(zero_sid)
 
 
-def _precompile_loop(entry, doc, entry_mesh, zero_sid, ones_mask, jnp):
+def _precompile_loop(entry, doc, entry_mesh, zero_sid):
+    import jax
+
     done = 0
     for s in doc:
         try:
@@ -1072,14 +1095,12 @@ def _precompile_loop(entry, doc, entry_mesh, zero_sid, ones_mask, jnp):
                     "range", key=("range", prog_tag, spec)) as dcall:
                 out = dcall.run(
                     program,
-                    arrs,
+                    arrs, entry.nrow, entry.imin, entry.imax,
                     zero_sid,
-                    ones_mask,
-                    jnp.int32(0), jnp.int32(-(2**31) + 1),
-                    jnp.int32(2**31 - 1),
+                    np.array([0, -(2**31) + 1, 2**31 - 1], np.int32),
                     spec=spec,
                 )
-                out.block_until_ready()
+                jax.block_until_ready(out)
                 dcall.executed()
             entry.program_specs[spec] = True
             done += 1
@@ -1261,31 +1282,38 @@ def _grow_states_locked(entry, table, missing, cache) -> bool:
 # device programs
 # ----------------------------------------------------------------------
 
-def _prelude_program():
-    import jax
+def _selection_extent(nrow, imin, imax, sid_mask, lo, hi):
+    """Traced inside the range program, ahead of `_range_body`: which
+    selected series hold a row in cells [lo, hi) (`sid_active`, (S,)
+    bool) and the exact extent of those rows as one int32[4]
+    (c_lo, i_lo, c_hi, i_hi): the first and last active cell and the
+    intra-cell ms offsets of the earliest and latest row in them — the
+    host path's `rows.ts.min()/max()`, from cell states. With no active
+    cell c_hi is -1."""
     import jax.numpy as jnp
 
-    @jax.jit
-    def prelude(nrow, imin, imax, sid_mask, lo, hi):
-        nb = nrow.shape[1]
-        cells = jnp.arange(nb, dtype=jnp.int32)
-        cmask = (cells >= lo) & (cells < hi)
-        act = (nrow > 0) & cmask[None, :] & sid_mask[:, None]
-        sid_active = jnp.any(act, axis=1)
-        colact = jnp.any(act, axis=0)
-        big = jnp.int32(_I32_MAX)
-        # global min ts lives in the first active cell (cells are
-        # time-ordered), global max in the last: two exact int32 stages
-        c_lo = jnp.min(jnp.where(colact, cells, big))
-        c_hi = jnp.max(jnp.where(colact, cells, -1))
-        i_lo = jnp.min(jnp.where(act & (cells[None, :] == c_lo), imin, big))
-        i_hi = jnp.max(jnp.where(act & (cells[None, :] == c_hi), imax, -1))
-        return sid_active, c_lo, i_lo, c_hi, i_hi
-
-    return prelude
+    nb = nrow.shape[1]
+    cells = jnp.arange(nb, dtype=jnp.int32)
+    cmask = (cells >= lo) & (cells < hi)
+    act = (nrow > 0) & cmask[None, :] & sid_mask[:, None]
+    sid_active = jnp.any(act, axis=1)
+    colact = jnp.any(act, axis=0)
+    big = jnp.int32(_I32_MAX)
+    # global min ts lives in the first active cell (cells are
+    # time-ordered), global max in the last: two exact int32 stages
+    c_lo = jnp.min(jnp.where(colact, cells, big))
+    c_hi = jnp.max(jnp.where(colact, cells, -1))
+    i_lo = jnp.min(jnp.where(act & (cells[None, :] == c_lo), imin, big))
+    i_hi = jnp.max(jnp.where(act & (cells[None, :] == c_hi), imax, -1))
+    return sid_active, jnp.stack([c_lo, i_lo, c_hi, i_hi])
 
 
-_PRELUDE = None
+def _unpack_inputs(gid, window, spec):
+    """The per-query inputs as the program's body takes them. Every
+    separate host->device argument is a transfer of its own, so a query
+    brings two: `gid` (S,) int32 (unmatched series routed to g: the
+    series mask IS `gid < g`) and `window` int32[3] = (delta, lo, hi)."""
+    return gid < spec[2], window[0], window[1], window[2]
 
 
 def _clamp_i32(v: int) -> int:
@@ -1293,57 +1321,6 @@ def _clamp_i32(v: int) -> int:
     grid; clamping both directions is lossless (comparisons only see
     cells in [0, nb))."""
     return max(-(2**31) + 1, min(int(v), 2**31 - 1))
-
-
-def run_prelude(entry: _Entry, sid_mask: np.ndarray, lo: int, hi: int):
-    """Exact (filtered ts_min, ts_max, active sids) from cell states —
-    mirrors the host path's `rows.ts.min()/max()` window-math inputs.
-    Memoized per (mask signature, bounds) on the entry."""
-    global _PRELUDE
-    key = (sid_mask.tobytes() if sid_mask is not None else None, lo, hi)
-    hit = entry.prelude.get(key)
-    if hit is not None:
-        return hit
-    if len(entry.prelude) >= 32:
-        entry.prelude.pop(next(iter(entry.prelude)))
-    import jax.numpy as jnp
-
-    if _PRELUDE is None:
-        _PRELUDE = _prelude_program()
-    from greptimedb_tpu.telemetry import device_trace
-
-    # the prelude runs before every device RANGE query; it registers
-    # with the program profiler like every other dispatch (shape is
-    # the program identity — one compiled prelude per grid geometry)
-    from greptimedb_tpu.query import readback as _readback
-
-    with device_trace.device_call(
-            "range_prelude",
-            key=("prelude", tuple(entry.nrow.shape))) as dcall:
-        # the mask's upload and the four scalars' readback are this
-        # call's own transfers: inside its span
-        mask = (jnp.asarray(sid_mask) if sid_mask is not None
-                else jnp.ones((entry.num_series,), bool))
-        act_d, c_lo, i_lo, c_hi, i_hi = dcall.run(
-            _PRELUDE, entry.nrow, entry.imin, entry.imax, mask,
-            np.int32(_clamp_i32(lo)), np.int32(_clamp_i32(hi)),
-        )
-        act_d.block_until_ready()
-        dcall.executed()
-        # execute split from readback like every other site; the
-        # active-sid mask crosses at the blessed readback boundary
-        act = _readback.read_full(act_d)
-        dcall.transfer(act.nbytes)
-        if not act.any():
-            out = (act, None, None)
-        else:
-            out = (
-                act,
-                entry.t0c + int(c_lo) * entry.res + int(i_lo),
-                entry.t0c + int(c_hi) * entry.res + int(i_hi),
-            )
-    entry.prelude[key] = out
-    return out
 
 
 # jnp window-combine machinery (device mirror of executor.py's
@@ -1673,9 +1650,17 @@ def _make_range_program():
     from greptimedb_tpu.parallel.dist import LocalFoldCtx
 
     @functools.partial(jax.jit, static_argnames=("spec",))
-    def program(arrs, gid, sid_mask, delta, lo, hi, *, spec):
-        return _range_body(arrs, gid, sid_mask, delta, lo, hi, spec,
-                           LocalFoldCtx())
+    def program(arrs, nrow, imin, imax, gid, window, *, spec):
+        """One device RANGE query, whole: -> (result, sid_active,
+        extent). The result covers the window the host could bound
+        before the dispatch; sid_active and extent let it trim to the
+        exact one after the one readback."""
+        sid_mask, delta, lo, hi = _unpack_inputs(gid, window, spec)
+        sid_active, extent = _selection_extent(nrow, imin, imax,
+                                               sid_mask, lo, hi)
+        out = _range_body(arrs, gid, sid_mask & sid_active, delta, lo,
+                          hi, spec, LocalFoldCtx())
+        return out, sid_active, extent
 
     return program
 
@@ -1683,10 +1668,12 @@ def _make_range_program():
 def _make_sharded_range_program(mesh, kernel: bool = False):
     """shard_map twin of the range program: grids series-sharded over
     AXIS_SHARD, each shard runs _range_body on its slice with the
-    collective fold ctx. fold=True outputs replicate (the post-fold
-    window combine is tiny and runs redundantly per shard); fold=False
-    outputs stay series-sharded. kernel=True threads the Pallas ring
-    fold ctx (parallel/kernels/ring_fold) instead of the gather_blocks
+    collective fold ctx; the selection's extent is computed in the same
+    jit ahead of the shard_map, under auto-SPMD. fold=True outputs
+    replicate (the post-fold window combine is tiny and runs
+    redundantly per shard); fold=False outputs stay series-sharded.
+    kernel=True threads the Pallas ring fold ctx
+    (parallel/kernels/ring_fold) instead of the gather_blocks
     collectives — same fold order, 2(ns-1) accumulator hops."""
     import jax
     from jax import shard_map
@@ -1698,7 +1685,7 @@ def _make_sharded_range_program(mesh, kernel: bool = False):
     ns = mesh.shape[AXIS_SHARD]
 
     @functools.partial(jax.jit, static_argnames=("spec",))
-    def program(arrs, gid, sid_mask, delta, lo, hi, *, spec):
+    def program(arrs, nrow, imin, imax, gid, window, *, spec):
         fold = spec[3]
         arr_specs = jax.tree_util.tree_map(
             lambda _: P(AXIS_SHARD, None), arrs
@@ -1714,13 +1701,17 @@ def _make_sharded_range_program(mesh, kernel: bool = False):
             return _range_body(arrs, gid, sid_mask, delta, lo, hi, spec,
                                ctx)
 
-        return shard_map(
+        sid_mask, delta, lo, hi = _unpack_inputs(gid, window, spec)
+        sid_active, extent = _selection_extent(nrow, imin, imax,
+                                               sid_mask, lo, hi)
+        out = shard_map(
             local, mesh=mesh,
             in_specs=(arr_specs, P(AXIS_SHARD), P(AXIS_SHARD),
                       P(), P(), P()),
             out_specs=P() if fold else P(None, AXIS_SHARD, None),
             check_vma=False,
-        )(arrs, gid, sid_mask, delta, lo, hi)
+        )(arrs, gid, sid_mask & sid_active, delta, lo, hi)
+        return out, sid_active, extent
 
     return program
 
@@ -1768,16 +1759,19 @@ def get_program():
 # orchestration
 # ----------------------------------------------------------------------
 
-def _group_ids_from_sids(plan, registry, active: np.ndarray):
-    """Per-sid group ids over the entry's series space. Returns
-    (gid_full (S,) int32 with inactive sids routed past g, g, key_cols).
-    Mirrors executor.QueryEngine._group_ids but derives groups from sids
+def _group_ids_from_sids(plan, registry, matched: np.ndarray):
+    """Per-sid group ids over the entry's series space, from the series
+    the matchers selected (no device result is needed: which of them
+    hold a row in the query's span is known only after the readback,
+    and groups with none are dropped then). Returns (gid_full (S,)
+    int32 with unmatched sids routed past g, g, key_cols). Mirrors
+    executor.QueryEngine._group_ids but derives groups from sids
     instead of rows (same decoded key values, possibly different group
     order — assembly sorts deterministically)."""
     from greptimedb_tpu.query.expr import Col
 
-    S = len(active)
-    act_idx = np.nonzero(active)[0]
+    S = len(matched)
+    act_idx = np.nonzero(matched)[0]
     if not plan.keys:
         gid_full = np.full(S, 1, np.int32)
         gid_full[act_idx] = 0
@@ -1812,6 +1806,79 @@ def _group_ids_from_sids(plan, registry, active: np.ndarray):
     return gid_full, g, key_cols
 
 
+_MEMO_MAX = 32      # selections an entry remembers; windows a selection does
+
+
+def _memo_put(memo: dict, key, value) -> None:
+    """Insert into a bounded memo, the oldest entry making room. Query
+    threads share an entry's memo unlocked: two of them may pick the
+    same oldest key, so the pop tolerates its absence."""
+    if len(memo) >= _MEMO_MAX:
+        memo.pop(next(iter(memo), None), None)
+    memo[key] = value
+
+
+def _window_steps(plan, ts_min: int, ts_max: int) -> tuple[int, int]:
+    """(j_first, j_last): the absolute step indices whose windows meet
+    rows spanning [ts_min, ts_max] — the host path's window math
+    (executor._execute_range): steps t with t > ts_min - range and
+    t <= ts_max."""
+    align = plan.align_ms
+    align_to = plan.align_to % align if plan.align_to else 0
+    max_range = max(r.range_ms for r in plan.range_items)
+    j_first = -((-(ts_min - max_range + 1 - align_to)) // align)
+    j_last = (ts_max - align_to) // align
+    return j_first, j_last
+
+
+def _selection_gid(memo: dict, mesh):
+    """The group ids of a memoized selection as the program takes them
+    -> (gid, bytes this call uploads). On one device a selection's first
+    dispatch passes them as a NumPy value: the jit's own argument path
+    uploads it with the call, no separate put. From its second dispatch
+    on they stay on the device. On a mesh they are placed series-sharded
+    at once: a NumPy argument would be replicated to every device, and
+    the program would compile a second time beside the one its resident
+    copy uses."""
+    if memo["gid"] is not None:
+        return memo["gid"], 0
+    gid = memo["gid_host"]
+    if mesh is not None or memo["dispatched"]:
+        gid = memo["gid"] = _make_put(mesh)[1](gid)
+    memo["dispatched"] = True
+    return gid, int(gid.nbytes)
+
+
+def _fold_window(entry: _Entry, memo: dict, sid_active, extent) -> dict:
+    """What the program said of one (lo, hi) window of a selection, as
+    the host keeps it: the rows' exact extent in ms (None: no selected
+    row in the span), and the groups that hold such a row — `keep`
+    indexes them among the selection's g groups (None: all of them)."""
+    from greptimedb_tpu.query.expr import Col
+
+    c_lo, i_lo, c_hi, i_hi = (int(v) for v in extent)
+    if c_hi < 0:
+        return {"extent": None, "keep": None, "g": 0, "key_cols": {}}
+    g = memo["g"]
+    grp_active = np.zeros(g + 1, bool)
+    grp_active[memo["gid_host"][sid_active]] = True
+    keep = None
+    key_cols = memo["key_cols"]
+    if not grp_active[:g].all():
+        keep = np.nonzero(grp_active[:g])[0]
+        g = len(keep)
+        key_cols = {
+            k: Col(c.values[keep],
+                   None if c.validity is None else c.validity[keep])
+            for k, c in key_cols.items()
+        }
+    return {
+        "extent": (entry.t0c + c_lo * entry.res + i_lo,
+                   entry.t0c + c_hi * entry.res + i_hi),
+        "keep": keep, "g": g, "key_cols": key_cols,
+    }
+
+
 def execute_range_device(engine, plan, table):
     """Try to run a RANGE plan on the device grid cache. Returns a
     QueryResult, or None to fall back to the host path."""
@@ -1827,8 +1894,6 @@ def execute_range_device(engine, plan, table):
         return None
     if prefer is None and table.row_count() < DEVICE_THRESHOLD:
         return None
-
-    import jax.numpy as jnp
 
     align = plan.align_ms
     if align is None or align <= 0:
@@ -1902,9 +1967,11 @@ def execute_range_device(engine, plan, table):
                 )
             record_mesh_decision(dec, "range")
 
-    # which series and which cells: the WHERE's ts bounds as cell
-    # bounds, its matchers through the tag index as a series mask
-    with tracing.child_span("query.select_series"):
+    # which series, which cells, which steps: the WHERE's ts bounds as
+    # cell bounds, its matchers through the tag index as a series mask,
+    # the window those bounds allow, and (memoized by selection) the
+    # group ids of the matched series
+    with tracing.child_span("query.select_series", memo="hit") as sel_span:
         res = entry.res
         # WHERE ts bounds must land on cell edges or partials can't honor them
         s = plan.scan
@@ -1919,7 +1986,13 @@ def execute_range_device(engine, plan, table):
 
         names = [nm for _, nm in plan.post_items]
         empty = engine._empty_result(names)
-        sid_mask = None
+        # the grid's cells the WHERE admits (the grid's own extent where
+        # it leaves a side open): an outer bound of the rows' extent,
+        # known before any dispatch
+        cell_lo, cell_hi = max(lo, 0), min(hi, entry.nb)
+        if cell_lo >= cell_hi:
+            return empty
+        sids = None
         mask_key = None
         from greptimedb_tpu.query.planner import record_scan_path
 
@@ -1930,8 +2003,6 @@ def execute_range_device(engine, plan, table):
             sids = _index.match_sids(entry.registry, s.matchers)
             if len(sids) == 0:
                 return empty
-            sid_mask = np.zeros(entry.num_series, bool)
-            sid_mask[sids[sids < entry.num_series]] = True
             # memo on the canonical matcher key + registry version instead
             # of hashing an O(num_series) mask per query
             mask_key = (_index.matcher_key(s.matchers),
@@ -1939,46 +2010,44 @@ def execute_range_device(engine, plan, table):
         else:
             record_scan_path(False)
 
-    active, ts_min_f, ts_max_f = run_prelude(entry, sid_mask, lo, hi)
-    # the selected series as the program takes them: the window they
-    # span, then (memoized) the group ids of the active ones and the
-    # device-side mask and bounds
-    with tracing.child_span("query.select_series", memo="hit") as sel_span:
-        if ts_min_f is None:
-            return empty
-        if plan.grid_ts_min is not None:
-            # distributed fill-grid override (see dist/dist_query.py): use
-            # the negotiated global extent so per-datanode grids match
-            ts_min_f = plan.grid_ts_min
-            ts_max_f = plan.grid_ts_max
-
-        # window math — identical to the host path (executor._execute_range)
+        # window math on the bound — identical to the host path's
+        # (executor._execute_range) on the exact extent. A step's value
+        # depends on its absolute index only, so the bound window's
+        # steps are a superset of the exact window's, value for value:
+        # the program computes these, the host trims after the readback
         align_to = plan.align_to % align if plan.align_to else 0
-        max_range = max(r.range_ms for r in plan.range_items)
-        j_first = -((-(ts_min_f - max_range + 1 - align_to)) // align)
-        j_last = (ts_max_f - align_to) // align
-        n_steps = int(j_last - j_first + 1)
-        if n_steps <= 0:
+        if plan.grid_ts_min is not None:
+            # distributed fill-grid override (see dist/dist_query.py): the
+            # negotiated global extent IS the window, bound and exact
+            ts_lo_b, ts_hi_b = plan.grid_ts_min, plan.grid_ts_max
+        else:
+            ts_lo_b = entry.t0c + cell_lo * res
+            ts_hi_b = entry.t0c + cell_hi * res - 1
+        j_first_b, j_last_b = _window_steps(plan, ts_lo_b, ts_hi_b)
+        n_steps_b = int(j_last_b - j_first_b + 1)
+        if n_steps_b <= 0:
             return empty
         stride = align // res
-        t0q = align_to + j_first * align
-        delta = (t0q - entry.t0c) // res
+        delta = (align_to + j_first_b * align - entry.t0c) // res
         if not (-(2**31) < delta < 2**31):
             return None  # query window absurdly far from the data grid
         lo_c = _clamp_i32(lo)
         hi_c = _clamp_i32(hi)
 
-        memo_key = (
-            mask_key,
-            tuple(k.expr.name for k in plan.keys),
-            delta, lo_c, hi_c,
-        )
-        uploaded_bytes = 0
-        memo = entry.query_memo.get(memo_key)
+        sel_key = (mask_key, tuple(k.expr.name for k in plan.keys))
+        memo = entry.query_memo.get(sel_key)
         if memo is None:
             sel_span.attributes["memo"] = "miss"
+            if sids is None:
+                # no matcher: every series the registry knows (the
+                # padded tail of the series axis has no tags)
+                sid_mask = np.arange(entry.num_series) < \
+                    entry.registry.num_series
+            else:
+                sid_mask = np.zeros(entry.num_series, bool)
+                sid_mask[sids[sids < entry.num_series]] = True
             gid_full, g, key_cols = _group_ids_from_sids(
-                plan, entry.registry, active
+                plan, entry.registry, sid_mask
             )
             # identity grouping (each real series is its own group,
             # padded tail routed past g) needs no fold: the per-series
@@ -1988,34 +2057,28 @@ def execute_range_device(engine, plan, table):
             fold = not (g <= entry.num_series
                         and np.array_equal(gid_full[:g], np.arange(g))
                         and (gid_full[g:] == g).all())
-            _, put1 = _make_put(getattr(entry, "mesh", None))
-            dmask = (put1(sid_mask & active) if sid_mask is not None
-                     else put1(active))
             memo = {
-                "gid": put1(gid_full), "mask": dmask, "g": g,
-                "key_cols": key_cols, "fold": fold,
-                "delta": jnp.int32(delta), "lo": jnp.int32(lo_c),
-                "hi": jnp.int32(hi_c),
+                # unmatched series are routed to g: the program reads
+                # the series mask off the group ids
+                "gid_host": gid_full,
+                # the device-resident copy, for the dispatches after
+                # the selection's first
+                "gid": None, "dispatched": False,
+                "g": g, "key_cols": key_cols, "fold": fold,
+                "windows": {},
             }
-            # host-side sizes as the upload proxy (the devices hold the
-            # padded copies): per-query upload bytes for the trace span
-            uploaded_bytes = int(gid_full.nbytes) + int(active.nbytes)
-            if len(entry.query_memo) >= 32:
-                entry.query_memo.pop(next(iter(entry.query_memo)))
-            entry.query_memo[memo_key] = memo
+            _memo_put(entry.query_memo, sel_key, memo)
+        win_key = (lo_c, hi_c)
+        win = memo["windows"].get(win_key)
     # the program for this shape and its inputs: state planes, spec,
     # mesh variant, the session buffer of a repeated poll
     with tracing.child_span("query.plan", phase="program"):
         g = memo["g"]
-        key_cols = memo["key_cols"]
         for item in plan.range_items:
             w_i = item.range_ms // res
-            nb_i = (n_steps - 1) * (align // res) + w_i
+            nb_i = (n_steps_b - 1) * stride + w_i
             if g * nb_i > 256_000_000:
                 return None
-        step_ts = (align_to + (j_first + np.arange(n_steps)) * align).astype(
-            np.int64
-        )
 
         prog_items = tuple(
             (op, it.range_ms // res, fname)
@@ -2059,7 +2122,7 @@ def execute_range_device(engine, plan, table):
 
                     ns_ = shard_count(entry_mesh)
                     for op_i, w_i, _f in prog_items:
-                        nb_i = (n_steps - 1) * stride + w_i
+                        nb_i = (n_steps_b - 1) * stride + w_i
                         planes = 1 + len(_STATE_COMBINE.get(op_i, ()))
                         comm_bytes += fold_comm_bytes(ns_, g, nb_i, planes)
             else:
@@ -2069,22 +2132,25 @@ def execute_range_device(engine, plan, table):
                 # DOCUMENTED bit-identity exception; surface it
                 stats.note("mesh_fold_range", "auto_spmd(oversized_fold)")
                 prog_tag = "auto_spmd"
-        prog_spec = (stride, n_steps, g, memo["fold"], nanenc, prog_items)
+        prog_spec = (stride, n_steps_b, g, memo["fold"], nanenc, prog_items)
         from greptimedb_tpu.query import readback, sessions
         from greptimedb_tpu.telemetry import device_trace
 
-        # delta-poll cursor: j0 = first step whose __ts is past the
-        # client's watermark. With FILL the full grid must assemble first
-        # (PREV/LINEAR carry from pre-cursor steps), so the cursor moves
-        # to cell emission; otherwise only delta steps are read back.
+        # delta-poll cursor: the first step whose __ts is past the
+        # client's watermark, counted in the bound window (its steps'
+        # timestamps are known before the dispatch). With FILL the full
+        # grid must assemble first (PREV/LINEAR carry from pre-cursor
+        # steps), so the cursor moves to cell emission; otherwise only
+        # delta steps are read back.
         since = sessions.current_since()
         has_fill = plan.fill is not None or any(
             r.fill is not None for r in plan.range_items
         )
-        j0 = 0
+        j0_b = 0
         if since is not None and not has_fill:
-            j0 = int(np.searchsorted(step_ts, since, side="right"))
-            if j0 >= n_steps:
+            j0_b = min(max((since - align_to) // align - j_first_b + 1, 0),
+                       n_steps_b)
+            if j0_b >= n_steps_b:
                 return empty  # the client has every step already
 
         # persistent query session: the folded RESULT buffer of this exact
@@ -2102,10 +2168,14 @@ def execute_range_device(engine, plan, table):
         # buffers.
         use_sessions = getattr(table, "session_cacheable", True)
         session_tkey = ("range", id(entry))
-        session_key = (memo_key, prog_spec)
+        session_key = (sel_key, win_key, delta, prog_spec)
         out_dev = (sessions.global_sessions.get(
             session_tkey, session_key, entry.version
         ) if use_sessions else None)
+        if win is None:
+            # what the program said of this window went with the memo:
+            # only a dispatch brings it back
+            out_dev = None
         # device-time attribution: one span per query carrying compile
         # (first-call vs cache-hit), block_until_ready execute time and
         # transfer bytes — the transfer cost becomes a named span on the
@@ -2122,22 +2192,26 @@ def execute_range_device(engine, plan, table):
     with stats.timed("device_exec_ms"), \
             device_trace.device_call(
                 "range", key=("range", prog_tag, prog_spec),
-                groups=g, steps=n_steps,
+                groups=g, steps=n_steps_b,
                 collective=prog_tag == "sharded_pallas",
                 comm_bytes=comm_bytes) as dcall:
+        extras = ()
         if out_dev is not None:
             stats.note("device_session", "hit")
             dcall.executed()
         else:
             stats.note("device_session", "miss")
-            if uploaded_bytes:
-                dcall.transfer(uploaded_bytes, "upload")
-            out_dev = dcall.run(
+            gid_in, uploaded = _selection_gid(memo, entry_mesh)
+            # the three scalars are one NumPy value too: the call
+            # uploads it, in one transfer
+            out_dev, act_dev, extent_dev = dcall.run(
                 program,
-                arrs, memo["gid"], memo["mask"],
-                memo["delta"], memo["lo"], memo["hi"],
+                arrs, entry.nrow, entry.imin, entry.imax,
+                gid_in, np.array([delta, lo_c, hi_c], np.int32),
                 spec=prog_spec,
             )
+            if uploaded:
+                dcall.transfer(uploaded, "upload")
             out_dev.block_until_ready()
             dcall.executed()
             if use_sessions:
@@ -2145,15 +2219,33 @@ def execute_range_device(engine, plan, table):
                     session_tkey, session_key, entry.version, out_dev,
                     int(out_dev.nbytes),
                 )
+            if win is None:
+                extras = (act_dev, extent_dev)
         # fold=False leaves the series axis un-folded: rows [g:] are
         # the padded/inactive tail (fold=True already has exactly g
         # rows). Both slices happen on the DEVICE array, so a delta
-        # poll reads back only the unseen steps
-        # (readback.read_delta feeds
+        # poll reads back only the unseen steps, and every output of
+        # the program crosses in ONE readback
+        # (readback.read_outputs feeds
         # gtpu_readback_bytes_total{mode=full|delta}).
         sliced = out_dev if memo["fold"] else out_dev[:, :g]
-        out = readback.read_delta(sliced, j0, axis=-1)
-        dcall.transfer(out.nbytes, "readback")
+        out, *extras = readback.read_outputs(sliced, j0_b, extras, axis=-1)
+        readback_bytes = out.nbytes + sum(x.nbytes for x in extras)
+        dcall.transfer(readback_bytes, "readback")
+        if win is None:
+            win = _fold_window(entry, memo, *extras)
+            _memo_put(memo["windows"], win_key, win)
+        # the exact window, from the exact extent of the selected rows:
+        # the steps to keep of the bound window's
+        n_steps = 0
+        if win["extent"] is not None:
+            ts_min_f, ts_max_f = win["extent"]
+            if plan.grid_ts_min is not None:
+                ts_min_f, ts_max_f = plan.grid_ts_min, plan.grid_ts_max
+            j_first, j_last = _window_steps(plan, ts_min_f, ts_max_f)
+            n_steps = max(int(j_last - j_first + 1), 0)
+        dcall.annotate(trimmed_steps=n_steps_b - n_steps)
+        _WINDOW_TRIM.labels("yes" if n_steps < n_steps_b else "no").inc()
     # host arrays -> QueryResult, from the values read back on
     with tracing.child_span("query.assemble"):
         if first_spec:
@@ -2162,9 +2254,25 @@ def execute_range_device(engine, plan, table):
                 target=_persist_program_specs, args=(entry, table),
                 daemon=True, name="program-specs-persist",
             ).start()
-        step_ts_eff = step_ts[j0:] if j0 else step_ts
+        # `out` holds bound steps [j0_b, n_steps_b); the exact window is
+        # bound steps [trim_lo, trim_lo + n_steps); emission starts at
+        # the later of the two starts
+        trim_lo = j_first - j_first_b if n_steps else 0
+        j0 = max(j0_b - trim_lo, 0)
+        if j0 >= n_steps:
+            # no selected row in the span, or the client has every step
+            return empty
+        out = out[..., trim_lo + j0 - j0_b:trim_lo + n_steps - j0_b]
+        keep = win["keep"]
+        if keep is not None:
+            # groups none of whose series holds a row in the span: the
+            # host path never sees them, FILL or not
+            out = out[:, keep]
+        g = win["g"]
+        step_ts_eff = (align_to + (j_first + np.arange(j0, n_steps))
+                       * align).astype(np.int64)
         n_steps_eff = n_steps - j0
-        stats.add("device_readback_bytes", out.nbytes)
+        stats.add("device_readback_bytes", readback_bytes)
         stats.add("range_groups", g)
         stats.add("range_steps", n_steps)
         n_items = len(plan.range_items)
@@ -2185,6 +2293,6 @@ def execute_range_device(engine, plan, table):
             item_vals[item.key] = vals[i]
             item_present[item.key] = pres[i]
         return engine._assemble_range_traced(
-            plan, table, item_vals, item_present, key_cols, step_ts_eff,
-            g, n_steps_eff, since if has_fill else None,
+            plan, table, item_vals, item_present, win["key_cols"],
+            step_ts_eff, g, n_steps_eff, since if has_fill else None,
         )

@@ -36,11 +36,22 @@ def _materialize(arr, dtype=None) -> np.ndarray:
     return out
 
 
+def _attribute(mode: str, nbytes: int) -> None:
+    _READBACK_BYTES.labels(mode).inc(nbytes)
+    stmt_stats.add(f"readback_{mode}_bytes", nbytes)
+
+
+def _tail(arr, lo: int, axis: int):
+    """`arr[..., lo:]` along `axis`, sliced on the device."""
+    idx = [slice(None)] * arr.ndim
+    idx[axis] = slice(lo, None)
+    return arr[tuple(idx)]
+
+
 def read_full(arr, dtype=None) -> np.ndarray:
     """Materialize a whole device buffer on host (mode=full)."""
     out = _materialize(arr, dtype)
-    _READBACK_BYTES.labels("full").inc(int(out.nbytes))
-    stmt_stats.add("readback_full_bytes", int(out.nbytes))
+    _attribute("full", int(out.nbytes))
     return out
 
 
@@ -53,12 +64,26 @@ def read_delta(arr, lo: int, *, axis: int = -1, dtype=None) -> np.ndarray:
     reads back only the steps it has not seen)."""
     if lo <= 0:
         return read_full(arr, dtype)
-    idx = [slice(None)] * arr.ndim
-    idx[axis] = slice(lo, None)
-    out = _materialize(arr[tuple(idx)], dtype)
-    _READBACK_BYTES.labels("delta").inc(int(out.nbytes))
-    stmt_stats.add("readback_delta_bytes", int(out.nbytes))
+    out = _materialize(_tail(arr, lo, axis), dtype)
+    _attribute("delta", int(out.nbytes))
     return out
+
+
+def read_outputs(arr, lo: int, extras=(), *, axis: int = -1) -> tuple:
+    """Every output of one program in ONE crossing: `arr[..., lo:]`
+    (sliced on the device, as read_delta; lo <= 0 reads it whole) and
+    the program's small side outputs `extras`. `jax.device_get` of the
+    tuple starts all the copies before it waits for any, where one
+    `np.asarray` per output would block once each. -> (out, *extras)
+    as host arrays; the bytes of all of them count under the mode of
+    `arr` (delta where sliced, else full)."""
+    import jax
+
+    dev = (_tail(arr, lo, axis) if lo > 0 else arr, *extras)
+    host = jax.device_get(dev)
+    _attribute("delta" if lo > 0 else "full",
+               sum(int(x.nbytes) for x in host))
+    return host
 
 
 def readback_bytes(mode: str) -> float:

@@ -166,6 +166,11 @@ class device_call:
             # blessed crossing in query/readback.py; uploads only here
             stmt_stats.add("upload_bytes", int(nbytes))
 
+    def annotate(self, **attrs):
+        """Further attributes of this call's span, known only once its
+        results are back (the range site's `trimmed_steps`)."""
+        self._span.attributes.update(attrs)
+
     def _fold_program(self, sp, rec, *, dispatched: bool):
         """Fold the dispatch into the program registry (when one
         happened) + attach the program id to the span and the

@@ -107,8 +107,8 @@ def test_range_site_folds_one_row_with_calls_accumulating(inst, registry):
     assert row["compile_ms"] > 0          # first call = compile
     assert row["execute_p50_ms"] > 0      # 3 steady-state samples
     assert row["readback_bytes"] > 0
-    # the prelude dispatched once (memoized thereafter)
-    assert sites["range_prelude"][0]["calls"] >= 1
+    # a range query is one call of one program: no other site ran
+    assert set(sites) == {"range"}
 
 
 def test_groupby_and_merge_and_promql_sites_fold(inst, registry):
@@ -372,7 +372,7 @@ def test_three_surface_agreement_across_admin_reset(inst, server,
 
     # ADMIN reset drops every row; all three surfaces zero together
     r = inst.sql("admin reset_device_profiler()")
-    assert r.rows()[0][0] >= 2
+    assert r.rows()[0][0] >= 1
     info2, route2, mets2 = _surface_triple(inst, server)
     assert info2 == {} and route2 == {}
     assert mets2 == {}  # published series zeroed, not frozen

@@ -275,12 +275,12 @@ def test_device_spans_on_range_query(tmp_path):
         spans = tracing.global_traces.trace(root.trace_id)
         dev = [s for s in spans if s["name"] == "device.execute"]
         assert dev, {s["name"] for s in spans}
-        # the prelude dispatch carries its own span now; the range
-        # program's span is the one with site=range
-        sites = {s["attributes"]["site"] for s in dev}
-        assert {"range", "range_prelude"} <= sites
-        attrs = [s for s in dev
-                 if s["attributes"]["site"] == "range"][0]["attributes"]
+        # a range query is ONE call of one program: one device span,
+        # of site=range (the rows' extent is computed inside it)
+        assert [s["attributes"]["site"] for s in dev] == ["range"]
+        attrs = dev[0]["attributes"]
+        # every step of the bound window held a row: nothing trimmed
+        assert attrs["trimmed_steps"] == 0
         assert attrs["compile"] == "first_call"
         assert attrs["readback_bytes"] > 0
         assert "execute_ms" in attrs
@@ -516,7 +516,7 @@ def _stage_tree(port: int, sql: str, tid: str) -> tuple[float, float]:
     grid = next(s for s in stages if s["name"] == "query.grid")
     assert grid["attributes"]["grid_cache"] == "hit"
     dev = [s for s in stages if s["name"] == "device.execute"]
-    assert len(dev) == 2 and all("execute_ms" in s["attributes"]
+    assert len(dev) == 1 and all("execute_ms" in s["attributes"]
                                  for s in dev)
     for s in dev:
         for gone in ("flops", "roofline_bound", "pct_of_peak",
@@ -554,7 +554,7 @@ def test_span_time_is_exported_by_name(panel, monkeypatch):
                 - (_family(m0, family, **labels) or 0.0))
 
     assert moved("gtpu_span_seconds_count", name="sql.parse") == 3
-    assert moved("gtpu_span_seconds_count", name="device.execute") == 6
+    assert moved("gtpu_span_seconds_count", name="device.execute") == 3
     assert moved("gtpu_span_seconds_count", name="http /v1/sql") == 3
     for name in ("sql.parse", "query.plan", "http /v1/sql"):
         wall = moved("gtpu_span_seconds_sum", name=name)
