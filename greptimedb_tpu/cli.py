@@ -245,7 +245,24 @@ def _serve_until_signal(closers):
             except Exception as e:  # noqa: BLE001
                 # keep tearing the rest down, but say what broke
                 print(f"# shutdown: {c} failed: {e}", flush=True)
+    _leave_abandoned_merges()
     return 0
+
+
+def _leave_abandoned_merges():
+    """Everything is closed and flushed, but a compaction merge that
+    sat inside one stage past its scheduler's grace (the device
+    merge's first compile takes minutes) still holds a worker thread,
+    and the interpreter would join it on the way out. It can commit
+    nothing any more and its inputs are intact: leave now."""
+    # a role that never loaded the storage engine has no merges
+    mod = sys.modules.get("greptimedb_tpu.storage.compaction")
+    n = mod.abandoned_merges() if mod is not None else 0
+    if n:
+        print(f"# shutdown: leaving {n} abandoned compaction merge(s) "
+              "behind; their inputs stay live", flush=True)
+        sys.stderr.flush()
+        os._exit(0)
 
 
 def _wire_protocols(inst, opts, closers) -> None:

@@ -222,6 +222,10 @@ def extrapolated_rate(
     valid = (li > lo[None, :]) & (fi <= hi[None, :]) & (fi < li)
     v_last = _take_cells(vals, li_s)
     v_first = _take_cells(vals, fi_s)
+    # ticks from the grid's origin, cast to the values' float: in
+    # float32 (x64 off, as a server runs) a millisecond tick is exact
+    # up to 2^24 ms = 4.66 h from t0; a grid that spans more rounds its
+    # sample times here (GridSpec.build only guards int32)
     t_last = _take_cells(tsg, li_s).astype(dt)
     t_first = _take_cells(tsg, fi_s).astype(dt)
 

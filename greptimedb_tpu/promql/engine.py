@@ -27,6 +27,7 @@ from greptimedb_tpu.errors import (
     UnsupportedError,
 )
 from greptimedb_tpu.promql import parser as P
+from greptimedb_tpu.telemetry import tracing
 from greptimedb_tpu.query.expr import compile_matcher
 from greptimedb_tpu.promql.parser import (
     Agg,
@@ -119,13 +120,15 @@ class PromEngine:
     # ------------------------------------------------------------------
     def query_range(self, promql: str, start_ms: int, end_ms: int,
                     step_ms: int, *, lookback_ms: int = DEFAULT_LOOKBACK_MS):
-        expr = P.parse_promql(promql)
+        with tracing.child_span("promql.parse"):
+            expr = P.parse_promql(promql)
         ev = EvalParams(start_ms, end_ms, max(int(step_ms), 1), lookback_ms)
         return self._eval(expr, ev), ev
 
     def query_instant(self, promql: str, time_ms: int, *,
                       lookback_ms: int = DEFAULT_LOOKBACK_MS):
-        expr = P.parse_promql(promql)
+        with tracing.child_span("promql.parse"):
+            expr = P.parse_promql(promql)
         ev = EvalParams(time_ms, time_ms, 1000, lookback_ms)
         return self._eval(expr, ev), ev
 

@@ -45,6 +45,7 @@ from greptimedb_tpu.promql.parser import (
     VectorSelector,
 )
 from greptimedb_tpu.program_cache import ProgramCache
+from greptimedb_tpu.telemetry import tracing
 from greptimedb_tpu.telemetry.metrics import global_registry
 
 from greptimedb_tpu import concurrency
@@ -73,6 +74,14 @@ _SIMPLE_AGGS = frozenset(
 _FAST_HITS = global_registry.counter(
     "greptime_promql_fast_path_total",
     "PromQL queries served from the selector grid cache", ("event",),
+)
+
+
+_GRID_ENTRIES = global_registry.counter(
+    "gtpu_promql_grid_entries_total",
+    "selector grids built, and tables refused because a series' samples "
+    "do not sit one to a cell or the span outgrows the byte budget",
+    ("outcome",),
 )
 
 
@@ -105,6 +114,9 @@ class _Entry:
     last_used: float = 0.0
     mesh: object = None         # series-axis sharding mesh (None = 1 dev)
     mesh_decision: object = None  # planner MeshDecision (replicate/shard)
+    # why the table has no grid ("irregular", "budget"): kept as an
+    # entry so that a refused table is scanned once a data version
+    refused: str = ""
     # per-entry derived caches (device-resident, so queries move no masks)
     match_cache: dict = field(default_factory=dict)
     group_cache: dict = field(default_factory=dict)
@@ -138,10 +150,13 @@ class SelectorGridCache:
             if e is not None and e.table is table and e.version == version:
                 e.last_used = time.monotonic()
                 self._hits += 1
+                tracing.set_attr(grid_cache="hit")
                 return e
             self._misses += 1
-        e = _build_entry(table, fieldname, version, mesh=mesh,
-                         mesh_opts=mesh_opts)
+        tracing.set_attr(grid_cache="miss")
+        with tracing.child_span("grid.build", site="promql_grid"):
+            e = _build_entry(table, fieldname, version, mesh=mesh,
+                             mesh_opts=mesh_opts)
         if e is None:
             return None
         with self._lock:
@@ -302,9 +317,9 @@ def _series_sharding(mesh, ndim: int):
 def _build_entry(table, fieldname: str, version, mesh=None,
                  mesh_opts=None) -> _Entry | None:
     """Scan the whole table once and gridify every series onto one
-    HBM-resident grid. Resolution is the gcd of observed sample intervals
-    (coarsened if the grid would blow the cell cap, same approximation as
-    ops/window.plan_grid_and_windows)."""
+    HBM-resident grid, one sample a cell. The resolution is the series'
+    own cadence and the origin follows the samples' phase (below); a
+    table that cannot be held exactly comes back as a refused entry."""
     if getattr(table, "remote", False):
         return None  # distributed tables: grids live on the datanodes
     import jax.numpy as jnp
@@ -330,14 +345,31 @@ def _build_entry(table, fieldname: str, version, mesh=None,
     if not np.issubdtype(np.asarray(vals_np).dtype, np.number):
         return None  # string field: no device grid
     ts = np.asarray(rows.ts, np.int64)
-    uniq_ts = np.unique(ts)
-    if len(uniq_ts) > 1:
-        res = int(np.gcd.reduce(np.diff(uniq_ts)))
-    else:
-        res = 1000
-    res = max(res, 1)
-    t_min = int(uniq_ts[0])
-    t_max = int(uniq_ts[-1])
+    sid = np.asarray(rows.sid, np.int32)
+    t_min = int(ts.min())
+    t_max = int(ts.max())
+    # the grid's resolution is the series' own cadence: the median gap
+    # between consecutive samples of one series (rows come in (series,
+    # ts) order), in the millisecond the samples are stamped in. A
+    # Prometheus scrapes each target at its own offset inside the
+    # interval, so the gcd over ALL timestamps is 1 ms where every
+    # series still has exactly one sample a scrape interval
+    step = np.diff(ts)
+    in_series = (np.diff(sid) == 0) & (step > 0)
+    gaps = step[in_series]
+    res = max(int(round(float(np.median(gaps)))), 1) if len(gaps) else 1000
+    # origin: where every sample shares one phase of the cadence, cell
+    # boundaries stay on it (a query that starts on a sample time is
+    # aligned); where the series have phases of their own, boundaries
+    # go on multiples of the cadence since the epoch, where a dashboard
+    # puts its steps. A window that ends on a boundary and is a whole
+    # number of cells long holds the same samples whatever their phase
+    # inside a cell, and the grid keeps every sample's exact tick
+    phase = ts % res
+    p0 = int(phase[0])
+    if not bool((phase == p0).all()):
+        p0 = 0
+    t0 = t_min - 1 - ((t_min - 1 - p0) % res)
     s = registry.num_series
     mesh_decision = None
     if mesh is not None:
@@ -357,26 +389,32 @@ def _build_entry(table, fieldname: str, version, mesh=None,
         # series axis shards over the mesh; pow2 buckets >= 8 divide an
         # 8-way mesh evenly, smaller grids pad up to it
         s_pad = max(s_pad, mesh.shape[AXIS_SHARD])
-    # keep grid bytes within half the cache budget: coarsen res as needed
-    # (sacrifices exact window alignment on pathological intervals; such
-    # queries then fail the alignment check and use the generic path)
-    max_cells = max(_budget_bytes() // 2 // (9 * s_pad), 16)
-    while (t_max - t_min) // res + 2 > max_cells:
-        res *= 2
-    # anchor the grid to the data's phase: samples at t_min + k*res land
-    # exactly on cell boundaries, so query starts on sample times satisfy
-    # the alignment precondition in _plan_windows
-    t0 = t_min - res
     nc = int(-((-(t_max - t0)) // res)) + 1
     spec = G.GridSpec.build(t0, res, nc)
-
     cell = spec.cell_of(ts).astype(np.int32)
+    # exactness: a grid holds one sample a cell. A table whose span
+    # outgrows half the cache budget at its cadence, or a series with
+    # two samples inside one cell (a cadence that is not regular), gets
+    # no grid: the generic engine answers, and the refusal is kept so
+    # that the table is not scanned again until its data changes
+    refused = ""
+    if nc > max(_budget_bytes() // 2 // (9 * s_pad), 16):
+        refused = "budget"
+    elif bool((in_series & (np.diff(cell) == 0)).any()):
+        refused = "irregular"
+    if refused:
+        _GRID_ENTRIES.labels("refused_" + refused).inc()
+        return _Entry(
+            table, fieldname, version, registry, None, None, None, None,
+            s, s_pad, 0, refused=refused,
+        )
+
     tsrel = spec.device_ts(ts)
     mask = np.ones(len(ts), bool)
     if rows.field_valid is not None and fieldname in rows.field_valid:
         mask = np.asarray(rows.field_valid[fieldname], bool)
     gvals, ghas, gtsg = G.gridify(
-        jnp.asarray(np.asarray(rows.sid, np.int32)),
+        jnp.asarray(sid),
         jnp.asarray(cell),
         jnp.asarray(tsrel),
         jnp.asarray(np.asarray(vals_np, np.float32)),
@@ -396,12 +434,11 @@ def _build_entry(table, fieldname: str, version, mesh=None,
     # the grid BUILD is the big host->device transfer of this path:
     # attribute it on the trace (a first query over a cold selector
     # pays it; steady-state queries hit the resident grid)
-    from greptimedb_tpu.telemetry import tracing as _tracing
-
-    with _tracing.child_span("device.upload", site="promql_grid",
-                             upload_bytes=nbytes):
+    with tracing.child_span("device.upload", site="promql_grid",
+                            upload_bytes=nbytes):
         gvals.block_until_ready()
     _FAST_HITS.labels("grid_build").inc()
+    _GRID_ENTRIES.labels("built").inc()
     global_registry.gauge(
         "greptime_promql_grid_build_seconds",
         "wall seconds of the last selector grid build",
@@ -469,7 +506,8 @@ def _plan_windows(entry: _Entry, ev, range_ms: int, offset_ms: int,
     res = spec.res
     start = ev.start_ms - offset_ms
     end = ev.end_ms - offset_ms
-    if ev.step_ms % res or (start - spec.t0) % res:
+    # (an instant query is one step: its step size aligns nothing)
+    if (end > start and ev.step_ms % res) or (start - spec.t0) % res:
         return None
     if align_range and range_ms % res:
         return None
@@ -859,8 +897,8 @@ def _hist_grouping(entry: _Entry, table):
 
 def _resolve_fast_selector(engine, inner, ev):
     """Shared scaffold for the fast paths: match `range_fn(sel)` /
-    bare instant selector, resolve table + grid entry, plan windows.
-    Returns (entry, table, raw_matchers, fname, fargs, win) on success,
+    bare instant selector, resolve table + grid entry. Returns (entry,
+    table, raw_matchers, fname, fargs, range_ms, offset_ms) on success,
     "empty" for a resolvable-but-empty selector, None to fall back."""
     fargs: tuple = ()
     if isinstance(inner, Call) and inner.name in _PREFIX_FNS:
@@ -891,25 +929,42 @@ def _resolve_fast_selector(engine, inner, ev):
         return None
     if sel.at_ms is not None:
         return None
-    table, field_sel, raw_matchers = engine._resolve_table(sel)
-    if table is None:
-        return None
-    try:
-        fieldname = engine._value_field(table, field_sel)
-    except Exception:  # noqa: BLE001 - resolution failure: generic path
-        return None
-    qe = getattr(engine.instance, "query_engine", None)
-    mesh = getattr(qe, "mesh", None)
-    entry = _CACHE.get_entry(table, fieldname, mesh=mesh,
-                             mesh_opts=getattr(qe, "mesh_opts", None))
-    if entry is None:
+    with tracing.child_span("promql.resolve"):
+        table, field_sel, raw_matchers = engine._resolve_table(sel)
+        if table is None:
+            return None
+        try:
+            fieldname = engine._value_field(table, field_sel)
+        except Exception:  # noqa: BLE001 - resolution failure: generic path
+            return None
+        qe = getattr(engine.instance, "query_engine", None)
+        mesh = getattr(qe, "mesh", None)
+        entry = _CACHE.get_entry(table, fieldname, mesh=mesh,
+                                 mesh_opts=getattr(qe, "mesh_opts", None))
+    if entry is None or entry.refused:
         return None
     if entry.num_series == 0:
         return "empty"
-    win = _plan_windows(
-        entry, ev, range_ms, sel.offset_ms,
-        align_range=fname != "__instant__",
-    )
+    return (entry, table, raw_matchers, fname, fargs, range_ms,
+            sel.offset_ms)
+
+
+def _selector_windows(entry, ev, fname, range_ms, offset_ms):
+    """The resolved selector's windows against its grid, or None."""
+    return _plan_windows(entry, ev, range_ms, offset_ms,
+                         align_range=fname != "__instant__")
+
+
+def _resolve_with_windows(engine, inner, ev):
+    """`_resolve_fast_selector` and the selector's windows in one step,
+    for the shapes that plan nothing else before their program: returns
+    (entry, table, raw_matchers, fname, fargs, win), "empty" or None."""
+    resolved = _resolve_fast_selector(engine, inner, ev)
+    if resolved is None or resolved == "empty":
+        return resolved
+    entry, table, raw_matchers, fname, fargs, range_ms, offset_ms = resolved
+    with tracing.child_span("promql.plan"):
+        win = _selector_windows(entry, ev, fname, range_ms, offset_ms)
     if win is None:
         return None
     return entry, table, raw_matchers, fname, fargs, win
@@ -1000,32 +1055,37 @@ def try_fast_histogram(engine, phi: float, inner, ev):
     if resolved == "empty":
         _FAST_HITS.labels("hit").inc()
         return _empty_vector(ev)
-    entry, table, raw_matchers, fname, fargs, win = resolved
+    entry, table, raw_matchers, fname, fargs, range_ms, offset_ms = resolved
     import jax.numpy as jnp
 
-    if agg is not None:
-        agg_labels, d_gid, g_agg = _grouping_dev(
-            entry, table, agg.grouping, agg.without
-        )
-        slots = _hist_slots_from_labels(agg_labels)
-        if slots is None:
+    with tracing.child_span("promql.plan"):
+        win = _selector_windows(entry, ev, fname, range_ms, offset_ms)
+        if win is None:
             _FAST_HITS.labels("fallback").inc()
             return None
-        labels, slot_np, uniq_le, g, b = slots
-        d_slot = jnp.asarray(slot_np)
-        agg_op = "sum"
-    else:
-        grouping = _hist_grouping(entry, table)
-        if grouping is None:
-            _FAST_HITS.labels("fallback").inc()
-            return None
-        labels, d_slot, uniq_le, g, b = grouping
-        d_gid = jnp.zeros(entry.s_pad, jnp.int32)
-        g_agg = 1
-        agg_op = ""
-    lo, hi, t_end, range_ticks, range_seconds, l_cells = win
-    matchers = engine._to_registry_matchers(raw_matchers, table)
-    smask, any_match = _matcher_mask_dev(entry, matchers)
+        if agg is not None:
+            agg_labels, d_gid, g_agg = _grouping_dev(
+                entry, table, agg.grouping, agg.without
+            )
+            slots = _hist_slots_from_labels(agg_labels)
+            if slots is None:
+                _FAST_HITS.labels("fallback").inc()
+                return None
+            labels, slot_np, uniq_le, g, b = slots
+            d_slot = jnp.asarray(slot_np)
+            agg_op = "sum"
+        else:
+            grouping = _hist_grouping(entry, table)
+            if grouping is None:
+                _FAST_HITS.labels("fallback").inc()
+                return None
+            labels, d_slot, uniq_le, g, b = grouping
+            d_gid = jnp.zeros(entry.s_pad, jnp.int32)
+            g_agg = 1
+            agg_op = ""
+        lo, hi, t_end, range_ticks, range_seconds, l_cells = win
+        matchers = engine._to_registry_matchers(raw_matchers, table)
+        smask, any_match = _matcher_mask_dev(entry, matchers)
     if not any_match:
         _FAST_HITS.labels("hit").inc()
         return _empty_vector(ev)
@@ -1059,16 +1119,17 @@ def try_fast_histogram(engine, phi: float, inner, ev):
         dcall.executed()
         packed_np = _readback.read_full(packed, np.float64)
         dcall.transfer(packed_np.nbytes, "readback")
-    vals_np = packed_np[:g]
-    pres_np = packed_np[g:] != 0.0
-    keep = pres_np.any(axis=1)
     _FAST_HITS.labels("hit").inc()
-    if not keep.all():
-        idx = np.nonzero(keep)[0]
-        return VectorValue(
-            [labels[i] for i in idx], vals_np[idx], pres_np[idx]
-        )
-    return VectorValue(list(labels), vals_np, pres_np)
+    with tracing.child_span("promql.assemble"):
+        vals_np = packed_np[:g]
+        pres_np = packed_np[g:] != 0.0
+        keep = pres_np.any(axis=1)
+        if not keep.all():
+            idx = np.nonzero(keep)[0]
+            return VectorValue(
+                [labels[i] for i in idx], vals_np[idx], pres_np[idx]
+            )
+        return VectorValue(list(labels), vals_np, pres_np)
 
 
 def try_fast(engine, e, ev):
@@ -1086,14 +1147,20 @@ def try_fast(engine, e, ev):
     if resolved == "empty":
         _FAST_HITS.labels("hit").inc()
         return _empty_vector(ev)
-    entry, table, raw_matchers, fname, fargs, win = resolved
-    lo, hi, t_end, range_ticks, range_seconds, l_cells = win
-    matchers = engine._to_registry_matchers(raw_matchers, table)
-    smask, any_match = _matcher_mask_dev(entry, matchers)
-    if not any_match:
-        _FAST_HITS.labels("hit").inc()
-        return _empty_vector(ev)
-    labels, gid, g = _grouping_dev(entry, table, e.grouping, e.without)
+    entry, table, raw_matchers, fname, fargs, range_ms, offset_ms = resolved
+    with tracing.child_span("promql.plan"):
+        win = _selector_windows(entry, ev, fname, range_ms, offset_ms)
+        if win is None:
+            _FAST_HITS.labels("fallback").inc()
+            return None
+        lo, hi, t_end, range_ticks, range_seconds, l_cells = win
+        matchers = engine._to_registry_matchers(raw_matchers, table)
+        smask, any_match = _matcher_mask_dev(entry, matchers)
+        if not any_match:
+            _FAST_HITS.labels("hit").inc()
+            return _empty_vector(ev)
+        labels, gid, g = _grouping_dev(entry, table, e.grouping,
+                                       e.without)
     lookback_ticks = max(int(ev.lookback_ms // entry.spec.unit), 1)
     program = (_fused_query if entry.mesh is None
                else _get_sharded_query(entry.mesh))
@@ -1123,16 +1190,17 @@ def try_fast(engine, e, ev):
         dcall.executed()
         packed_np = _readback.read_full(packed, np.float64)
         dcall.transfer(packed_np.nbytes, "readback")
-    vals_np = packed_np[:g]
-    pres_np = packed_np[g:] != 0.0
-    keep = pres_np.any(axis=1)
     _FAST_HITS.labels("hit").inc()
-    if not keep.all():
-        idx = np.nonzero(keep)[0]
-        return VectorValue(
-            [labels[i] for i in idx], vals_np[idx], pres_np[idx]
-        )
-    return VectorValue(list(labels), vals_np, pres_np)
+    with tracing.child_span("promql.assemble"):
+        vals_np = packed_np[:g]
+        pres_np = packed_np[g:] != 0.0
+        keep = pres_np.any(axis=1)
+        if not keep.all():
+            idx = np.nonzero(keep)[0]
+            return VectorValue(
+                [labels[i] for i in idx], vals_np[idx], pres_np[idx]
+            )
+        return VectorValue(list(labels), vals_np, pres_np)
 
 
 # ----------------------------------------------------------------------
@@ -1418,7 +1486,7 @@ def try_fast_topk(engine, e, ev):
     k = int(e.param.value)
     if k <= 0:
         return _empty_vector(ev)
-    resolved = _resolve_fast_selector(engine, e.expr, ev)
+    resolved = _resolve_with_windows(engine, e.expr, ev)
     if resolved is None:
         _FAST_HITS.labels("fallback").inc()
         return None
@@ -1618,10 +1686,10 @@ def _resolve_binary(engine, e, ev):
         return None  # only default one-to-one matching rides sid codes
     if not (_operand_shape_fast(e.lhs) and _operand_shape_fast(e.rhs)):
         return None
-    left = _resolve_fast_selector(engine, e.lhs, ev)
+    left = _resolve_with_windows(engine, e.lhs, ev)
     if left is None:
         return None
-    right = _resolve_fast_selector(engine, e.rhs, ev)
+    right = _resolve_with_windows(engine, e.rhs, ev)
     if right is None:
         return None
     if left == "empty" or right == "empty":

@@ -527,7 +527,9 @@ def _make_handler(instance, user_provider=None, *, enable_scripts=False,
             if path == "/v1/sql":
                 return self._handle_sql()
             if path == "/v1/promql":
-                return self._handle_promql_range(self._form())
+                with tracing.child_span("http.read"):
+                    params = self._form()
+                return self._handle_promql_range(params)
             _local_only = (
                 path.startswith("/v1/prometheus/")
                 or path.startswith(("/v1/influxdb/", "/influxdb/"))
@@ -706,7 +708,8 @@ def _make_handler(instance, user_provider=None, *, enable_scripts=False,
 
         # ------------------------------------------------------------------
         def _handle_prom_api(self, endpoint: str):
-            params = self._form()
+            with tracing.child_span("http.read"):
+                params = self._form()
             db = params.get("db", "public")
             ctx = QueryContext(database=db)
             engine = PromEngine(instance, ctx)
@@ -833,7 +836,9 @@ def _make_handler(instance, user_provider=None, *, enable_scripts=False,
                 val, ev = engine.query_range(q, start, end, step_ms)
             except GreptimeError as e:
                 return self._prom_error(str(e))
-            self._json(200, _prom_matrix_json(val, ev))
+            with tracing.child_span("http.encode"):
+                body = json.dumps(_prom_matrix_json(val, ev)).encode()
+            self._send(200, body)
 
         def _prom_error(self, msg: str):
             self._json(400, {
@@ -842,9 +847,10 @@ def _make_handler(instance, user_provider=None, *, enable_scripts=False,
 
         # ------------------------------------------------------------------
         def _handle_remote_write(self):
-            params = self._params()
+            with tracing.child_span("http.read"):
+                params = self._params()
+                body = self._body()
             db = params.get("db", "public")
-            body = self._body()
             compressed = "snappy" in (
                 self.headers.get("Content-Encoding") or "snappy"
             )

@@ -18,6 +18,7 @@ from greptimedb_tpu.datatypes.types import ConcreteDataType
 from greptimedb_tpu.errors import InvalidArgumentError
 from greptimedb_tpu.servers import snappy
 from greptimedb_tpu.servers.influx import ensure_table
+from greptimedb_tpu.telemetry import tracing
 
 VALUE_FIELD = "greptime_value"
 
@@ -126,9 +127,10 @@ def parse_write_request(data: bytes):
 def remote_write(instance, body: bytes, *, db: str = "public",
                  compressed: bool = True) -> tuple[int, int]:
     """Apply a remote-write payload. Returns (series, samples)."""
-    if compressed:
-        body = snappy.decompress(body)
-    serieses = parse_write_request(body)
+    with tracing.child_span("prom_write.decode", body_bytes=len(body)):
+        if compressed:
+            body = snappy.decompress(body)
+        serieses = parse_write_request(body)
     return len(serieses), apply_series(instance, serieses, db=db)
 
 

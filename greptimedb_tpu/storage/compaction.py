@@ -41,6 +41,7 @@ import time
 import uuid
 
 from collections import defaultdict
+from concurrent import futures as _futures
 from dataclasses import dataclass
 
 from greptimedb_tpu import concurrency
@@ -65,6 +66,19 @@ MAX_LEVEL = 2
 # cascade bound per compact_once call: L0->L1->L2->tier is 4 picks;
 # anything deeper indicates a picker bug, not more work
 _MAX_ROUNDS = 8
+# how long a scheduler's close() waits for the merge it interrupted
+CLOSE_GRACE_S = 10.0
+# merges that outlived their scheduler's close(): they can no longer
+# commit, and their threads still run
+_abandoned: list = []
+
+
+def abandoned_merges() -> int:
+    """Merges still running after their scheduler closed. Their worker
+    threads are not daemons, so a process that wants to exit now has
+    to leave without joining them (cli.py)."""
+    return sum(not f.done() for f in _abandoned)
+
 
 # ----------------------------------------------------------------------
 # telemetry
@@ -305,23 +319,28 @@ def _read_inputs(region, task: CompactionTask,
 
 
 def run_task(region, task: CompactionTask,
-             opts: CompactionOptions) -> bool:
+             opts: CompactionOptions, *, stop=None) -> bool:
     """Run one merge task end to end: pipelined read, (device) merge,
     write, validated manifest swap, input deletion. Returns True if
     the swap committed; False when a concurrent truncate/compaction
-    removed an input first (the new output is deleted, nothing else
-    changed)."""
+    removed an input first, or when ``stop()`` reads true at a stage
+    boundary (the scheduler closed under the merge): the new output
+    is deleted, nothing else changed."""
     from greptimedb_tpu.telemetry import tracing
 
     with tracing.span("region.compact", region=region.meta.region_id,
                       kind=task.kind, files=len(task.files),
                       level=task.output_level, tier=task.output_tier,
                       drop_deletes=task.drop_deletes):
-        return _run_task_traced(region, task, opts)
+        return _run_task_traced(region, task, opts, stop)
+
+
+def _stopped(stop) -> bool:
+    return stop is not None and stop()
 
 
 def _run_task_traced(region, task: CompactionTask,
-                     opts: CompactionOptions) -> bool:
+                     opts: CompactionOptions, stop=None) -> bool:
     from greptimedb_tpu.errors import SstRestoreError
 
     t0 = time.perf_counter()
@@ -337,7 +356,7 @@ def _run_task_traced(region, task: CompactionTask,
         raise
     t1 = time.perf_counter()
     _stage_ms.labels("read").inc((t1 - t0) * 1000.0)
-    if not chunks:
+    if not chunks or _stopped(stop):
         return False
     rows = (_concat_rows(chunks, region.meta.field_names)
             if len(chunks) > 1 else chunks[0])
@@ -355,6 +374,8 @@ def _run_task_traced(region, task: CompactionTask,
             _tombstones_dropped.inc(deletes_in)
     t2 = time.perf_counter()
     _stage_ms.labels("merge").inc((t2 - t1) * 1000.0)
+    if _stopped(stop):
+        return False
 
     if len(rows) == 0:
         # every surviving row was a GC'd tombstone: commit a pure
@@ -391,9 +412,11 @@ def _run_task_traced(region, task: CompactionTask,
 
     with region._lock:
         live = {m.file_id for m in region.manifest.state.ssts}
-        if not all(m.file_id in live for m in task.files):
-            # lost a race with truncate/TTL purge/another compaction:
-            # abort without touching the manifest
+        if _stopped(stop) or not all(m.file_id in live
+                                     for m in task.files):
+            # lost a race with truncate/TTL purge/another compaction,
+            # or the scheduler closed: abort without touching the
+            # manifest
             out_store.delete(new_path)
             if new_meta.fulltext:
                 out_store.delete(sidecar_path(new_path))
@@ -428,10 +451,11 @@ def _region_opts(region) -> CompactionOptions:
 
 def compact_once(region, opts: CompactionOptions | None = None, *,
                  force: bool = False,
-                 now_ms: int | None = None) -> bool:
+                 now_ms: int | None = None, stop=None) -> bool:
     """Run triggered compactions for this region until the picker is
     satisfied (bounded cascade: an L0 merge may arm the L1 trigger and
-    so on). Returns True if any merge committed."""
+    so on) or ``stop()`` reads true. Returns True if any merge
+    committed."""
     if opts is None:
         opts = _region_opts(region)
     did = False
@@ -447,8 +471,10 @@ def compact_once(region, opts: CompactionOptions | None = None, *,
             break
         progressed = False
         for task in tasks:
+            if _stopped(stop):
+                break
             try:
-                if run_task(region, task, opts):
+                if run_task(region, task, opts, stop=stop):
                     progressed = did = True
             except Exception as e:  # noqa: BLE001 - re-raised below
                 # one bad window (corrupt input, device divergence
@@ -614,10 +640,18 @@ class CompactionScheduler:
         with self._lock:
             self._closed = True
             pool, self._pool = self._pool, None
-        if pool is not None:
-            # let the running merge finish (its commit is atomic);
-            # queued work is dropped — the picker re-finds it
-            pool.shutdown(wait=True, cancel_futures=True)
+            running = list(self._inflight.values())
+        if pool is None:
+            return
+        # queued work is dropped — the picker re-finds it. The running
+        # merge sees `_closed` at its next stage boundary and gives up
+        # with its inputs intact. One that sits inside a stage past
+        # CLOSE_GRACE_S (the device merge's first compile takes two
+        # minutes) is left behind: it can no longer commit, and
+        # `abandoned_merges()` tells the process not to wait for it
+        pool.shutdown(wait=False, cancel_futures=True)
+        _, late = _futures.wait(running, timeout=CLOSE_GRACE_S)
+        _abandoned.extend(late)
 
     def _in_worker(self) -> bool:
         import threading
@@ -687,7 +721,8 @@ class CompactionScheduler:
             with tracing.child_span("compaction.job",
                                     _parent=_trace_parent,
                                     region=region.meta.region_id):
-                return compact_once(region, self.opts, force=force)
+                return compact_once(region, self.opts, force=force,
+                                    stop=lambda: self._closed)
         except Exception:
             # the background path has no caller to observe the Future:
             # a failing merge must surface in the log (the errors

@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from greptimedb_tpu.errors import TableNotFoundError
+from greptimedb_tpu.storage import compaction
 from greptimedb_tpu.storage.compaction import (
     CompactionOptions,
     CompactionScheduler,
@@ -505,6 +506,96 @@ def test_scheduler_dedupes_inflight_region(tmp_path):
         assert sched.maybe_schedule(r) is False  # nothing triggered
     finally:
         sched.close()
+    r.close()
+
+
+def test_close_interrupts_the_running_merge(tmp_path):
+    """close() does not wait a merge out: the merge sees the closed
+    scheduler at its next stage boundary and gives up, its inputs
+    live and nothing committed."""
+    store = _GatedStore(str(tmp_path / "data"))
+    r = make_region(tmp_path, trigger=2, store=store)
+    write_flush(r, ["a"], [100], [1.0])
+    write_flush(r, ["a"], [200], [2.0])
+    before = [m.file_id for m in r.manifest.state.ssts]
+    sched = CompactionScheduler(CompactionOptions())
+    fut = sched.schedule(r)
+    assert store.reading.wait(10)
+    threading.Timer(0.2, store.release.set).start()
+    t0 = time.perf_counter()
+    sched.close()                           # the read stage ends in 0.2 s
+    assert time.perf_counter() - t0 < 5
+    assert fut.result(timeout=10) is False
+    assert [m.file_id for m in r.manifest.state.ssts] == before
+    assert compaction.abandoned_merges() == 0
+    r.close()
+
+
+def test_close_leaves_a_merge_stuck_in_a_stage(tmp_path, monkeypatch):
+    """A merge that sits inside one stage past the grace (a device
+    compile takes minutes) is left behind and counted, so the process
+    knows not to join its thread; once it moves on it commits nothing
+    and the count falls back."""
+    monkeypatch.setattr(compaction, "CLOSE_GRACE_S", 0.2)
+    store = _GatedStore(str(tmp_path / "data"))
+    r = make_region(tmp_path, trigger=2, store=store)
+    write_flush(r, ["a"], [100], [1.0])
+    write_flush(r, ["a"], [200], [2.0])
+    before = [m.file_id for m in r.manifest.state.ssts]
+    sched = CompactionScheduler(CompactionOptions())
+    fut = sched.schedule(r)
+    assert store.reading.wait(10)
+    t0 = time.perf_counter()
+    sched.close()
+    assert time.perf_counter() - t0 < 5
+    assert compaction.abandoned_merges() == 1
+    store.release.set()
+    assert fut.result(timeout=10) is False
+    assert [m.file_id for m in r.manifest.state.ssts] == before
+    assert compaction.abandoned_merges() == 0
+    r.close()
+
+
+def test_the_process_does_not_join_an_abandoned_merge(monkeypatch, capsys):
+    """After every closer ran, the server leaves at once (exit 0) if a
+    merge was left behind, and returns normally if none was."""
+    from concurrent.futures import Future
+
+    from greptimedb_tpu import cli
+
+    left = []
+    monkeypatch.setattr(cli.os, "_exit", left.append)
+    cli._leave_abandoned_merges()
+    assert left == []
+    stuck = Future()
+    monkeypatch.setattr(compaction, "_abandoned", [stuck])
+    cli._leave_abandoned_merges()
+    assert left == [0]
+    assert "1 abandoned compaction merge" in capsys.readouterr().out
+    stuck.set_result(False)
+    cli._leave_abandoned_merges()
+    assert left == [0]
+
+
+@pytest.mark.parametrize("stage", ["read", "merge", "write"])
+def test_a_stopped_merge_commits_nothing(tmp_path, stage):
+    """`stop` read at each stage boundary: the inputs stay the live
+    files and no output is left in the store."""
+    r = make_region(tmp_path, trigger=2)
+    write_flush(r, ["a"], [100], [1.0])
+    write_flush(r, ["a"], [200], [2.0])
+    before = [m.file_id for m in r.manifest.state.ssts]
+    stages = ["read", "merge", "write"]
+    asked = []
+
+    def stop():
+        asked.append(1)     # the first ask is compact_once's own
+        return len(asked) > stages.index(stage) + 1
+
+    assert compact_once(r, stop=stop) is False
+    assert [m.file_id for m in r.manifest.state.ssts] == before
+    assert len(r.store.list(r.prefix + "/sst/")) == len(before)
+    assert compact_once(r) is True          # nothing was harmed
     r.close()
 
 

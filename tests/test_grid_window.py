@@ -2,6 +2,7 @@
 
 import numpy as np
 import jax.numpy as jnp
+import pyarrow as pa
 import pytest
 
 from greptimedb_tpu.ops import grid as G
@@ -363,3 +364,29 @@ def test_interval_duration_wire_normalization():
         "d", pa.array([7], pa.duration("ms"))
     )
     assert hc2.values.dtype == np.int64 and hc2.values[0] == 7
+
+
+@pytest.mark.parametrize("arr", [
+    pa.array(["a", "bb", None, "a"]),
+    pa.array(["a", None], pa.large_string()),
+    pa.array([b"x", None, b""], pa.binary()),
+    pa.array([], pa.string()),
+    pa.array(["a", "b", "c", "d"]).slice(1, 2),
+    pa.chunked_array([["a"], ["b", None]]),
+    pa.array(["a", "b", "a"]).dictionary_encode(),
+], ids=["string", "large_string", "binary", "empty", "slice", "chunked",
+        "dictionary"])
+def test_string_columns_land_as_object_arrays(arr):
+    """A string or binary Arrow column comes out as a 1-D object array of
+    str (bytes), a null as None and marked invalid: what the Flight write
+    path hands to `_write_columns`."""
+    from greptimedb_tpu.datatypes.batch import HostColumn
+
+    flat = arr.combine_chunks() if isinstance(arr, pa.ChunkedArray) else arr
+    if pa.types.is_dictionary(flat.type):
+        flat = flat.cast(flat.type.value_type)
+    want = flat.to_pylist()
+    hc = HostColumn.from_arrow("c", arr)
+    assert hc.values.dtype == object and hc.values.shape == (len(want),)
+    assert hc.values.tolist() == want
+    assert list(hc.valid_mask) == [v is not None for v in want]
