@@ -5,9 +5,16 @@ Replaces the reference's per-series streaming window materialization
 RangeArray ragged view, /root/reference/src/promql/src/range_array.rs) with
 two TPU-friendly formulations:
 
-- prefix path: window aggregates as differences of per-series prefix sums
-  (O(S*T) memory, no per-window gather). Used for sum/count/avg, the
-  extrapolated rate family, changes/resets, first/last/idelta/irate.
+- prefix path: scans along the cell axis, read at the steps' columns
+  (O(S*T) memory, no per-window gather, and no gather whose index
+  differs by series). A window's sum or count is a difference of
+  per-series prefix sums at the columns hi + 1 and lo + 1. Its last
+  (first) sample is, above 128 cells, the sample carried along the cell
+  axis by `_carry` and read at the column hi (lo + 1): the columns are
+  the same for every series, so every fetch is `arr[:, idx]` with a (J,)
+  index; up to 128 cells the sample's cell is compared with every cell
+  (`_take_cells`). Used for sum/count/avg, the extrapolated rate family,
+  changes/resets, first/last/idelta/irate and the instant selector.
 - gather path: materialize (S, J, L) window tensors by gathering L cells per
   output step. Used for order statistics and sequential folds (min/max/
   quantile/stddev/holt_winters/deriv/predict_linear).
@@ -97,6 +104,33 @@ def _prefix(x: jax.Array) -> jax.Array:
     return jnp.pad(c, ((0, 0), (1, 0)))
 
 
+def _gather_steps(arr: jax.Array, idx: jax.Array) -> jax.Array:
+    """(S, T') array at the steps' (J,) columns -> (S, J): one index for
+    every series (out-of-range columns clamp)."""
+    return arr[:, idx]
+
+
+def _before(x: jax.Array, fill) -> jax.Array:
+    """x one cell earlier: out[:, i] = x[:, i - 1], fill at cell 0."""
+    return jnp.pad(x[:, :-1], ((0, 0), (1, 0)), constant_values=fill)
+
+
+# A window's last (first) sample sits at a cell that differs by series.
+# Fetching it with take_along_axis serialises on the TPU: 0.3 to 1.1 s a
+# plane of 131,072 x 243 cells (ledger, PR 29). Two forms replace it, by
+# the number of cells T, a shape (chip readings in PERF.md, PR 30):
+# - T > 128: carry the sample along the cell axis (`_carry`) and read the
+#   carried plane at the steps' columns, the same for every series. Its
+#   scans are passes over the grid: rate() over 131,072 x 243 cells reads
+#   33 ms where the gathers read 1,720.
+# - T <= 128: scan for the sample's cell and compare it with every cell
+#   (`_take_cells`): work grows as S x J x T, 6.3 ms at 1M series x 12
+#   cells and 38.7 at 131,072 x 121, where the scans read 14.6 and 59.8:
+#   XLA splits a scan into blocks of 128 cells and runs a shorter one
+#   whole, at a cost that grows with T squared.
+_ONE_HOT_MAX_T = 128
+
+
 def _last_present_idx(has: jax.Array) -> jax.Array:
     """lastidx[:, i] = greatest cell j <= i with a sample, else -1."""
     t = has.shape[1]
@@ -104,46 +138,104 @@ def _last_present_idx(has: jax.Array) -> jax.Array:
     return jax.lax.cummax(jnp.where(has, i, jnp.int32(-1)), axis=1)
 
 
-def _first_present_idx(has: jax.Array) -> jax.Array:
-    """firstidx[:, i] = least cell j >= i with a sample, else T."""
-    t = has.shape[1]
-    rev = jnp.flip(has, axis=1)
-    i = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), has.shape)
-    lp = jax.lax.cummax(jnp.where(rev, i, jnp.int32(-1)), axis=1)
-    return jnp.flip(jnp.int32(t - 1) - lp, axis=1)
-
-
-def _prev_present_idx(lastidx: jax.Array) -> jax.Array:
-    """prev[:, i] = greatest cell j < i with a sample, else -1."""
-    return jnp.pad(lastidx[:, :-1], ((0, 0), (1, 0)), constant_values=-1)
-
-
-def _gather_steps(arr: jax.Array, idx: jax.Array) -> jax.Array:
-    """Gather (S, T') array at per-step indices. idx is (J,) -> (S, J) or
-    (S, J) -> (S, J)."""
-    if idx.ndim == 1:
-        return arr[:, idx]
-    return _take_cells(arr, idx)
-
-
-# per-row gathers (take_along_axis with (S, J) indices) lower to
-# scatter-like HLO that serializes on TPU (~110ms at 1M series x 12 cells);
-# when the cell axis is small, a broadcast-compare + masked reduction is
-# pure fused VPU work (~10x faster). Above the threshold the (S, J, T)
-# virtual intermediate stops fusing profitably and take_along_axis wins.
-_TAKE_CELLS_MAX_T = 128
-
-
 def _take_cells(arr: jax.Array, idx: jax.Array) -> jax.Array:
-    """take_along_axis(arr, idx, axis=1) for (S, T) arr and (S, J) idx,
-    TPU-reformulated for small T."""
+    """take_along_axis(arr, idx, axis=1) for (S, T) arr and (S, J) idx
+    over few cells: a broadcast-compare and masked sum, fused VPU work."""
     t = arr.shape[1]
-    if t > _TAKE_CELLS_MAX_T:
-        return jnp.take_along_axis(arr, idx, axis=1)
     oh = idx[:, :, None] == jnp.arange(t, dtype=jnp.int32)[None, None, :]
     return jnp.sum(
         jnp.where(oh, arr[:, None, :], jnp.zeros((), arr.dtype)), axis=2
     )
+
+
+def _carry(planes, has: jax.Array, rising=(), falling=()):
+    """Carry each plane's present cells forward along the cell axis.
+    Returns ([filled], idx), planes first: idx[:, i] is the last present
+    cell at or before i, -1 where there is none, and filled[:, i] is
+    x[:, idx[:, i]], the same bits, unspecified where there is none.
+
+    A cummax selects by its key's high bits: the key of a present cell is
+    (cell << p) | piece for a p-bit piece of x's bit pattern, -1 for an
+    absent one, so the running maximum is the key of the last present
+    cell and its low bits are that cell's piece. A plane takes
+    ceil(bits / p) scans, p being what the cell index leaves of a key:
+    two for 32-bit planes under 32,768 cells and for every 64-bit plane,
+    four at 4M cells. An int32 plane that never falls (never rises) from
+    one present cell of a series to the next, as sample ticks and cell
+    indices do, is its own key: one scan."""
+    t = has.shape[1]
+    out = []
+    for x in planes:
+        nbits = 8 * x.dtype.itemsize
+        # a key as wide as the plane: 64 bits only for a 64-bit plane,
+        # which exists only under x64
+        kt, ut = jnp.dtype(f"int{nbits}"), jnp.dtype(f"uint{nbits}")
+        pbits = nbits - 1 - max((t - 1).bit_length(), 1)
+        mask = jnp.asarray((1 << pbits) - 1, ut)
+        rank = (jnp.arange(t, dtype=kt) << pbits)[None, :]
+        bits = jax.lax.bitcast_convert_type(x, ut)
+        filled = jnp.zeros(x.shape, ut)
+        for sh in range(0, nbits, pbits):
+            piece = ((bits >> sh) & mask).astype(kt)
+            top = jax.lax.cummax(
+                jnp.where(has, rank | piece, jnp.asarray(-1, kt)), axis=1)
+            filled = filled | ((top.astype(ut) & mask) << sh)
+        out.append(jax.lax.bitcast_convert_type(filled, x.dtype))
+    i32 = jnp.iinfo(jnp.int32)
+    out += [jax.lax.cummax(jnp.where(has, x, i32.min), axis=1)
+            for x in rising]
+    out += [jax.lax.cummin(jnp.where(has, x, i32.max), axis=1)
+            for x in falling]
+    # arithmetic shift: an absent key stays -1
+    return out, (top >> pbits).astype(jnp.int32)
+
+
+def _last_at(planes, has: jax.Array, cols: jax.Array, rising=(), falling=()):
+    """Each plane at the last present cell at or before each of the (J,)
+    columns, planes first, then `rising` and `falling` (int32 planes that
+    never fall, never rise, from one present cell of a series to the
+    next), and that cell (-1 where there is none): ([(S, J)], (S, J)).
+    The values are unspecified where there is none."""
+    if has.shape[1] <= _ONE_HOT_MAX_T:
+        li = _gather_steps(_last_present_idx(has), cols)
+        safe = jnp.maximum(li, 0)
+        return [_take_cells(x, safe) for x in (*planes, *rising, *falling)], li
+    filled, lastidx = _carry(planes, has, rising, falling)
+    return ([_gather_steps(f, cols) for f in filled],
+            _gather_steps(lastidx, cols))
+
+
+def _first_at(planes, has: jax.Array, cols: jax.Array, rising=()):
+    """Each plane at the first present cell at or after each column, and
+    that cell (T where there is none). The scans run over the mirrored
+    grid, read at the mirrored columns: the TPU takes 5.8 ms for a scan
+    against the cell order where it takes 1.5 along it (131,072 x 243
+    cells; PERF.md, PR 30)."""
+    t = has.shape[1]
+    mirrored = jnp.maximum((t - 1) - cols, 0)
+    back = jnp.flip(has, axis=1)
+    if t <= _ONE_HOT_MAX_T:
+        fi = (t - 1) - _gather_steps(_last_present_idx(back), mirrored)
+        safe = jnp.minimum(fi, t - 1)
+        return [_take_cells(x, safe) for x in (*planes, *rising)], fi
+    vals, li = _last_at(
+        [jnp.flip(x, axis=1) for x in planes], back, mirrored,
+        falling=[jnp.flip(x, axis=1) for x in rising])
+    return vals, (t - 1) - li
+
+
+def _sample_before(planes, has: jax.Array, rising=()):
+    """Per cell, each plane at the last present cell strictly before it,
+    and that cell (-1 where there is none): ([(S, T)], (S, T))."""
+    if has.shape[1] <= _ONE_HOT_MAX_T:
+        pl = _before(_last_present_idx(has), -1)
+        safe = jnp.maximum(pl, 0)
+        return [_take_cells(x, safe) for x in (*planes, *rising)], pl
+    filled, lastidx = _carry(planes, has, rising)
+    # a rising plane stays rising: nothing precedes a series' first sample
+    fills = [0] * len(planes) + [jnp.iinfo(jnp.int32).min] * len(rising)
+    return ([_before(f, fill) for f, fill in zip(filled, fills)],
+            _before(lastidx, -1))
 
 
 # ----------------------------------------------------------------------
@@ -174,33 +266,14 @@ def window_avg(vals, has, lo, hi):
 @jax.jit
 def window_last(vals, has, tsg, lo, hi):
     """Most recent sample in each window: (value, ts, present)."""
-    li = _gather_steps(_last_present_idx(has), hi)
-    present = li > lo[None, :]
-    safe = jnp.maximum(li, 0)
-    v = _take_cells(vals, safe)
-    t = _take_cells(tsg, safe)
-    return v, t, present
+    (v, t), li = _last_at((vals,), has, hi, rising=(tsg,))
+    return v, t, li > lo[None, :]
 
 
 @jax.jit
 def window_first(vals, has, tsg, lo, hi):
-    fi = _gather_steps(_first_present_idx(has), lo + 1)
-    present = fi <= hi[None, :]
-    t_max = vals.shape[1] - 1
-    safe = jnp.minimum(fi, t_max)
-    v = _take_cells(vals, safe)
-    t = _take_cells(tsg, safe)
-    return v, t, present
-
-
-def _pair_indicator(vals, has, pred):
-    """Per-cell indicator over (prev_sample, sample) pairs; pred(prev, cur)."""
-    lastidx = _last_present_idx(has)
-    pl = _prev_present_idx(lastidx)
-    safe = jnp.maximum(pl, 0)
-    prev_val = _take_cells(vals, safe)
-    pair = has & (pl >= 0)
-    return pair, prev_val
+    (v, t), fi = _first_at((vals,), has, lo + 1, rising=(tsg,))
+    return v, t, fi <= hi[None, :]
 
 
 @functools.partial(jax.jit, static_argnames=("is_counter", "is_rate"))
@@ -212,30 +285,29 @@ def extrapolated_rate(
     functions.go (semantics per /root/reference/src/promql/src/functions/
     extrapolate_rate.rs:120-205). Returns (value, present) shaped (S, J)."""
     dt = vals.dtype
-    lastidx = _last_present_idx(has)
-    firstidx = _first_present_idx(has)
-    li = _gather_steps(lastidx, hi)          # (S, J)
-    fi = _gather_steps(firstidx, lo + 1)     # (S, J)
-    t_max = vals.shape[1] - 1
-    li_s = jnp.maximum(li, 0)
-    fi_s = jnp.minimum(fi, t_max)
+    (v_last, t_last), li = _last_at((vals,), has, hi, rising=(tsg,))
+    first = (vals,)
+    if is_counter:
+        (prev_val,), pl = _sample_before((vals,), has)
+        pair = has & (pl >= 0)
+        drop = jnp.where(pair & (vals < prev_val), prev_val, jnp.zeros((), dt))
+        d = _prefix(drop)
+        # the drops up to and with the window's first sample: the prefix
+        # just past that sample
+        first += (d[:, 1:],)
+    (v_first, *d_first, t_first), fi = _first_at(
+        first, has, lo + 1, rising=(tsg,))
     valid = (li > lo[None, :]) & (fi <= hi[None, :]) & (fi < li)
-    v_last = _take_cells(vals, li_s)
-    v_first = _take_cells(vals, fi_s)
     # ticks from the grid's origin, cast to the values' float: in
     # float32 (x64 off, as a server runs) a millisecond tick is exact
     # up to 2^24 ms = 4.66 h from t0; a grid that spans more rounds its
     # sample times here (GridSpec.build only guards int32)
-    t_last = _take_cells(tsg, li_s).astype(dt)
-    t_first = _take_cells(tsg, fi_s).astype(dt)
+    t_last = t_last.astype(dt)
+    t_first = t_first.astype(dt)
 
     delta = v_last - v_first
     if is_counter:
-        pair, prev_val = _pair_indicator(vals, has, None)
-        drop = jnp.where(pair & (vals < prev_val), prev_val, jnp.zeros((), dt))
-        d = _prefix(drop)
-        corr = _gather_steps(d, hi + 1) - _take_cells(d, fi_s + 1)
-        delta = delta + corr
+        delta = delta + (_gather_steps(d, hi + 1) - d_first[0])
 
     cnt = window_count(has, lo, hi).astype(dt)
     t_end_f = t_end[None, :].astype(dt)
@@ -271,19 +343,18 @@ def window_pair_count(vals, has, lo, hi, *, count_changes: bool):
     over each window. Pairs are (prev sample, sample) with both inside the
     window. Returns (count_float, present)."""
     dt = vals.dtype
-    pair, prev_val = _pair_indicator(vals, has, None)
+    (prev_val,), pl = _sample_before((vals,), has)
+    pair = has & (pl >= 0)
     if count_changes:
         ind = pair & (vals != prev_val)
     else:
         ind = pair & (vals < prev_val)
     p = _prefix(ind.astype(jnp.int32))
-    firstidx = _first_present_idx(has)
-    fi = _gather_steps(firstidx, lo + 1)
-    t_max = vals.shape[1] - 1
-    fi_s = jnp.minimum(fi, t_max)
+    # pairs up to and with the window's first sample (whose own pair
+    # reaches back out of the window), as in extrapolated_rate
+    (p_first,), fi = _first_at((p[:, 1:],), has, lo + 1)
     in_w = fi <= hi[None, :]
-    cnt = _gather_steps(p, hi + 1) - _take_cells(p, fi_s + 1)
-    cnt = jnp.where(in_w, cnt, 0)
+    cnt = jnp.where(in_w, _gather_steps(p, hi + 1) - p_first, 0)
     return cnt.astype(dt), in_w
 
 
@@ -292,19 +363,14 @@ def instant_delta(vals, has, tsg, lo, hi, tps, *, is_rate: bool):
     """idelta (last two samples' value difference) / irate (per-second,
     with counter-reset handling)."""
     dt = vals.dtype
-    lastidx = _last_present_idx(has)
-    pl = _prev_present_idx(lastidx)
-    li = _gather_steps(lastidx, hi)
-    t_max = vals.shape[1] - 1
-    li_s = jnp.maximum(li, 0)
-    # previous present cell strictly before li
-    pi = _take_cells(pl, li_s)
-    pi_s = jnp.maximum(pi, 0)
+    # the sample before each sample, fetched at a window's last sample:
+    # the sample before the last
+    (pv, pt), pl = _sample_before((vals,), has, rising=(tsg,))
+    (v2, v1, t2, t1, pi), li = _last_at(
+        (vals, pv), has, hi, rising=(tsg, pt, pl))
     valid = (li > lo[None, :]) & (pi > lo[None, :]) & (pi >= 0)
-    v1 = _take_cells(vals, pi_s)
-    v2 = _take_cells(vals, li_s)
-    t1 = _take_cells(tsg, pi_s).astype(dt)
-    t2 = _take_cells(tsg, li_s).astype(dt)
+    t1 = t1.astype(dt)
+    t2 = t2.astype(dt)
     if is_rate:
         dv = jnp.where(v2 < v1, v2, v2 - v1)  # counter reset: use raw value
         dtm = jnp.maximum(t2 - t1, 1) / jnp.asarray(tps, dt)
@@ -383,6 +449,11 @@ def _small_sort_lanes(x, length: int):
     return jnp.stack(cols, axis=2)
 
 
+# the rank's lane differs by series AND step, so no shared column serves
+# it: up to this many cells a window the lanes are compared one by one
+_QUANTILE_ONE_HOT_MAX_CELLS = 128
+
+
 @functools.partial(jax.jit, static_argnames=("num_cells",))
 def window_quantile(vals, has, tsg, lo, hi, num_cells: int, q):
     """phi-quantile with linear interpolation (Prometheus
@@ -401,7 +472,7 @@ def window_quantile(vals, has, tsg, lo, hi, num_cells: int, q):
     rank = q * jnp.maximum(n - 1, 0).astype(dt)
     lo_i = jnp.clip(jnp.floor(rank).astype(jnp.int32), 0, num_cells - 1)
     hi_i = jnp.clip(jnp.ceil(rank).astype(jnp.int32), 0, num_cells - 1)
-    if num_cells <= _TAKE_CELLS_MAX_T:
+    if num_cells <= _QUANTILE_ONE_HOT_MAX_CELLS:
         # data-dependent take_along_axis lowers to a serializing
         # scatter on TPU (~250ms at 1M series); a one-hot masked
         # reduction over the tiny lane axis is fused VPU work
@@ -501,11 +572,7 @@ def instant_lookback(vals, has, tsg, hi, t_end, lookback_ticks):
     lookback delta — PromQL instant-vector selection (reference:
     /root/reference/src/promql/src/extension_plan/instant_manipulate.rs)."""
     dt = vals.dtype
-    lastidx = _last_present_idx(has)
-    li = _gather_steps(lastidx, hi)
-    safe = jnp.maximum(li, 0)
-    v = _take_cells(vals, safe)
-    t = _take_cells(tsg, safe)
+    (v, t), li = _last_at((vals,), has, hi, rising=(tsg,))
     # int32-safe freshness test: ts is <= t_end by construction, so the
     # difference is small and non-positive.
     age = t_end[None, :] - t
