@@ -18,8 +18,8 @@ Exit code 0 and, as the LAST stdout line, exactly
 `{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}`
 (the device as jax reports it in the server process) only when every
 phase passed on a TPU. The line before it, `report: {...}`, carries the
-rows, the per-phase wall times, the exec path and kernel decision of
-every step and the compile-cache counts; the same document is written
+rows, the per-phase wall times, the exec path of every step and the
+compile-cache counts; the same document is written
 to chiprun_out/chip_smoke_report.json. No accelerator, a query that
 lands on a `host:*` path, a dead server child, a wrong answer or any
 phase that raised: non-zero exit and no result line. `--cpu-rehearsal`
@@ -250,10 +250,6 @@ class Server:
         return out
 
 
-def labelled(metrics: dict, name: str) -> list:
-    return [(dict(lb), v) for (n, lb), v in metrics.items() if n == name]
-
-
 # ----------------------------------------------------------------------
 # data: TSBS cpu-only from --seed, and the plain reference over it
 # ----------------------------------------------------------------------
@@ -373,7 +369,7 @@ def compare(np, name: str, got: dict, want: dict, rtol: float) -> float:
 
 
 class Workload:
-    """The query shapes (bench.py's TSBS set) with their references.
+    """The TSBS query shapes with their references.
     Each step yields (sql-or-promql, reference dict, rtol) for a cold
     variant and a fresh-literal warm variant: same program shapes, a
     different literal, so the session registry cannot answer it."""
@@ -474,11 +470,11 @@ class Workload:
                 want[(k * 3600, self.hostnames[h])] = (m[h],)
         return q, want, EXACT
 
-    # -- ring legs (folds that cross shards; chips > 1) ----------------
+    # -- cross-shard legs (folds that cross shards; chips > 1) ---------
     def fold_all(self, where: str = ""):
         """BY (): every series folds into one group — the cross-shard
-        blocked sum (ring_fold), extreme (ring_pext) and last-value
-        winner extraction (ring_psum_onehot)."""
+        blocked sum (gather_blocks + left fold), extreme (pmax) and
+        last-value winner extraction (staged pext + psum)."""
         np = self.np
         sql = ("SELECT ts, count(usage_user) RANGE '1h', "
                "avg(usage_user) RANGE '1h', max(usage_user) RANGE '1h', "
@@ -616,9 +612,7 @@ def run(args) -> dict:
         with open(config, "w") as f:
             f.write("[mesh]\nenabled = true\n")
             if args.cpu_rehearsal:
-                # what `auto` selects on a TPU, through the interpreter
-                f.write(f"force_host_device_count = {chips}\n"
-                        'pallas_kernels = "on"\n')
+                f.write(f"force_host_device_count = {chips}\n")
         mesh_off_config = os.path.join(work, "mesh_off.toml")
         with open(mesh_off_config, "w") as f:
             f.write("[mesh]\nenabled = false\n")
@@ -724,9 +718,9 @@ def _drive(np, args, srv: Server, report, tag, say, *, mesh_off_config,
     ]
     if chips > 1:
         steps += [
-            ("ring-fold-all", "range", "sql", 1,
+            ("fold-all", "range", "sql", 1,
              wl.fold_all(), wl.fold_all("WHERE ts >= 0")),
-            ("ring-topk", "promql", "promql", 2,
+            ("topk", "promql", "promql", 2,
              wl.promql_topk(),
              wl.promql_topk(',hostname!="no_such_host"')),
         ]
@@ -745,16 +739,12 @@ def _drive(np, args, srv: Server, report, tag, say, *, mesh_off_config,
             r[0] for r in srv.sql("EXPLAIN ANALYZE " + wl.fold_all()[0]))
         notes = _notes(plan)
         report["mesh"] = {k: notes.get(k) for k in (
-            "mesh_decision_range", "mesh_devices", "mesh_kernel_range",
-            "exec_path_range")}
+            "mesh_decision_range", "mesh_devices", "exec_path_range")}
         say(f"mesh: {report['mesh']}")
         check(notes.get("mesh_decision_range") == "shard(large_grid)",
               f"range not sharded: {notes.get('mesh_decision_range')}")
         check(notes.get("mesh_devices") == str(chips),
               f"mesh_devices {notes.get('mesh_devices')} != {chips}")
-        check(notes.get("mesh_kernel_range") == "pallas(ring_fold)",
-              "the ring kernels were not selected: "
-              f"{notes.get('mesh_kernel_range')}")
         in_use = srv.device()["bytes_in_use"]
         report["mesh"]["bytes_in_use"] = in_use
         if all(b is not None for b in in_use):
@@ -812,7 +802,7 @@ def _drive(np, args, srv: Server, report, tag, say, *, mesh_off_config,
           f"the second life of the server added {cache2 - cache1} "
           "compile cache entries")
 
-    # ---- mesh off: the ring legs against one device --------------------
+    # ---- mesh off: the cross-shard legs against one device -------------
     if chips > 1:
         srv.config = mesh_off_config
         phases["reopen_mesh_off"] = round(srv.start(), 3)
@@ -821,10 +811,10 @@ def _drive(np, args, srv: Server, report, tag, say, *, mesh_off_config,
         # required: XLA's own f32 reductions pick their order from the
         # shard-local shape on a TPU (PR 21 saw the unfolded flagship
         # differ in the last bits between 4 chips and 1, while every
-        # ring leg was bit-identical)
+        # cross-shard leg was bit-identical)
         report["mesh"]["mesh_off"] = {}
         for name in ("double-groupby-all", "groupby-hostname",
-                     "ring-fold-all", "ring-topk"):
+                     "fold-all", "topk"):
             wire, query, n_keys, on_mesh, rtol = answers[name]
             t0 = time.perf_counter()
             got = _ask(srv, wire, query, hours, n_keys)
@@ -911,15 +901,8 @@ def _run_step(np, srv, name, kind, wire, n_keys, cold, warm, hours, say,
             else f"host:{off_dev}"
         check(on_dev >= 2 and not off_dev,
               f"{name}: exec paths device={on_dev} host={off_dev}")
-    kern = sorted(
-        f"{lb.get('kind')}={lb.get('mode')}({lb.get('reason')})"
-        for lb, v in labelled(m1, "gtpu_mesh_queries_total")
-        if lb.get("kind", "").endswith("_kernel")
-        and v - m0.get(("gtpu_mesh_queries_total",
-                        tuple(sorted(lb.items()))), 0.0) > 0)
-    rec["kernel"] = kern or ["xla(single_device)"]
     say(f"{name}: cold {rec['cold_s']}s warm {rec['warm_s']}s "
-        f"exec_path={rec['exec_path']} kernel={rec['kernel']} "
+        f"exec_path={rec['exec_path']} "
         f"rows={len(cold[1])} worst_rel_err="
         f"{max(rec['cold_worst_rel_err'], rec['warm_worst_rel_err']):.2g}")
     return rec

@@ -157,13 +157,6 @@ DEFAULTS: dict = {
         "force_host_device_count": 0,   # CPU simulation (virtual devices)
         "shard_min_series": 4096,       # grids below this replicate
         "shard_min_rows": 262144,       # row reductions below this replicate
-        # Pallas kernel paths (parallel/kernels): auto|on|off — auto
-        # enables them on the native TPU backend only; on forces them
-        # everywhere (interpret mode off-TPU); off keeps the XLA paths.
-        "pallas_kernels": "auto",
-        "pallas_min_series": 4096,      # kernel grid floor (stay XLA below)
-        "pallas_min_rows": 262144,      # kernel row paths below this stay XLA
-        "pallas_max_k": 128,            # topk merge kernel O(k^2) cap
     },
     # secondary tag-index dataplane (index/): per-region inverted
     # tag-value -> sid postings over the dictionary-coded label plane,
@@ -227,8 +220,7 @@ DEFAULTS: dict = {
     "tracing": {
         "enable": True,
         "sample_ratio": 1.0,    # head probability for unremarkable traces
-        "capacity": 256,        # trace ring size (0 = unbounded; bench
-                                # refuses to run like that)
+        "capacity": 256,        # trace ring size (0 = unbounded)
         "slow_ms": 5000.0,      # always-keep threshold for slow traces
     },
     # query execution device preference (None = row-count heuristic);

@@ -339,7 +339,7 @@ _clients: dict[str, object] = {}
 
 def _peer_client(inst, addr: str):
     """Addr-keyed DatanodeClient: DistInstance already keeps one
-    (its flow-mirror client map); other instances (bench/tests) share a
+    (its flow-mirror client map); other instances (tests) share a
     bounded module cache. Eviction is LRU and DROPS the reference
     without close() — another fan-out thread may be mid-call on the
     evicted client, and its channel is released when the last user

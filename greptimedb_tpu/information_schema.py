@@ -411,7 +411,6 @@ def _device_programs_doc(inst) -> dict[str, list]:
         "site", "program", "key", "calls", "errors", "compile_ms",
         "execute_ms_total", "execute_p50_ms", "execute_p99_ms",
         "device_ms_total", "upload_bytes", "readback_bytes",
-        "collective", "comm_bytes",
         "dispatch_only", "analysis", "analysis_error", "flops",
         "bytes_accessed", "temp_bytes", "output_bytes",
         "argument_bytes", "aot_compile_ms", "achieved_gflops",

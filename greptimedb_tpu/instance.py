@@ -1084,7 +1084,7 @@ class Standalone:
                 qstats.note("result_cache", "hit")
                 stmt_stats.add("result_cache_hits")
                 # truthful path attribution: the cached payload came
-                # from this execution path (bench/EXPLAIN assertions)
+                # from this execution path (EXPLAIN assertions)
                 self.query_engine.last_exec_path = entry.exec_path
                 res = entry.result
                 if since is not None:

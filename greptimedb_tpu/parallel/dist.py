@@ -83,21 +83,9 @@ def halo_exchange_prev(x: jax.Array, halo: int, axis_name: str = AXIS_TIME):
     return _halo_prev(x, halo, axis_name, axis=1, fill=0.0)
 
 
-def dist_topk(mesh: Mesh, k: int, *, largest: bool = True,
-              kernel: bool = False, interpret: bool | None = None):
+def dist_topk(mesh: Mesh, k: int, *, largest: bool = True):
     """Distributed top-k over a sharded 1-D value array: local top-k,
-    all_gather the candidates, re-select. Returns (values, global_indices).
-
-    With kernel=True the all-gather reselect is replaced by the Pallas
-    merge-path ring (parallel/kernels/topk_merge): each shard still
-    selects its local candidates, but only the accumulated (k,) winner
-    planes walk the ring. Winner values and indices are identical to
-    the all-gather path (the merge tie-break reproduces top_k's
-    lower-concat-index rule); the one exception is slots beyond the
-    real candidate count when a shard holds fewer than k rows, where
-    the kernel path reports its -inf padding sentinel index."""
-
-    ns = mesh.devices.size
+    all_gather the candidates, re-select. Returns (values, global_indices)."""
 
     def local(values, mask):
         n_local = values.shape[0]
@@ -107,21 +95,6 @@ def dist_topk(mesh: Mesh, k: int, *, largest: bool = True,
         loc_v, loc_i = jax.lax.top_k(vv, min(k, n_local))
         shard = jax.lax.axis_index(AXIS_SHARD)
         glob_i = loc_i + shard * n_local
-        if kernel:
-            from greptimedb_tpu.parallel.kernels import (
-                interpret_mode, ring_topk_merge,
-            )
-
-            interp = interpret_mode() if interpret is None else interpret
-            top_v, _, top_i, _ = ring_topk_merge(
-                loc_v[None, :], loc_v[None, :], glob_i[None, :],
-                jnp.isfinite(loc_v)[None, :], k=k, ns=ns,
-                interpret=interp,
-            )
-            top_v, top_i = top_v[0], top_i[0]
-            if not largest:
-                top_v = -top_v
-            return top_v, top_i
         all_v = jax.lax.all_gather(loc_v, AXIS_SHARD).reshape(-1)
         all_i = jax.lax.all_gather(glob_i, AXIS_SHARD).reshape(-1)
         top_v, sel = jax.lax.top_k(all_v, k)
@@ -196,9 +169,7 @@ class LocalFoldCtx:
 
     def fold_blocks(self, partial):
         """Gather the per-shard partial blocks and run the canonical
-        unrolled left fold — THE cross-shard sum seam. The kernel path
-        (parallel/kernels/ring_fold.RingFoldCtx) overrides this with
-        the sequential ring, preserving the same fold order."""
+        unrolled left fold — THE cross-shard sum seam."""
         return left_fold_sum(self.gather(partial))
 
     def pext(self, x, take_max: bool):

@@ -51,13 +51,6 @@ class MeshOptions:
     # replicate-vs-shard planner thresholds (query/planner.py)
     shard_min_series: int = 4096    # grid paths: series below this replicate
     shard_min_rows: int = 262144    # row paths: rows below this replicate
-    # Pallas kernel paths (parallel/kernels): auto = native TPU backend
-    # only; on = everywhere via interpret mode (tests/fuzz/CPU bench);
-    # off = never. Shape floors keep small programs on the XLA paths.
-    pallas_kernels: str = "auto"
-    pallas_min_series: int = 4096   # kernel grid paths below this stay XLA
-    pallas_min_rows: int = 262144   # kernel row paths below this stay XLA
-    pallas_max_k: int = 128         # topk merge kernel is O(k^2) per hop
 
 
 def mesh_options_from(section: dict) -> MeshOptions:
@@ -74,16 +67,6 @@ def mesh_options_from(section: dict) -> MeshOptions:
             section.get("shard_min_series", d.shard_min_series)
         ),
         shard_min_rows=int(section.get("shard_min_rows", d.shard_min_rows)),
-        pallas_kernels=str(
-            section.get("pallas_kernels", d.pallas_kernels)
-        ),
-        pallas_min_series=int(
-            section.get("pallas_min_series", d.pallas_min_series)
-        ),
-        pallas_min_rows=int(
-            section.get("pallas_min_rows", d.pallas_min_rows)
-        ),
-        pallas_max_k=int(section.get("pallas_max_k", d.pallas_max_k)),
     )
 
 

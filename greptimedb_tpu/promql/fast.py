@@ -1312,8 +1312,8 @@ def _topk_key(out, pres, largest: bool):
     base = jnp.clip(out, -big, big)
     k_dir = base if largest else -base
     # canonicalize -0.0 -> +0.0: lax.top_k's total order ranks +0.0
-    # above -0.0 while the ring-merge kernel compares them equal; a
-    # single key representation keeps both paths bit-identical
+    # above -0.0 where a comparison holds them equal; with one key
+    # representation the tie goes to the lower series index
     k_dir = k_dir + jnp.asarray(0.0, out.dtype)
     return jnp.where(
         pres, jnp.where(jnp.isnan(out), nan_key, k_dir), -jnp.inf
@@ -1390,85 +1390,10 @@ def _make_sharded_fused_topk(mesh):
     return program
 
 
-def _make_sharded_fused_topk_pallas(mesh):
-    """Pallas-kernel twin of _make_sharded_fused_topk: identical local
-    candidate extraction, but the ns*k-candidate reselect is a ring of
-    pairwise merge-path kernels (parallel/kernels/topk_merge.py) moving
-    only the (J, k) winner planes hop-by-hop instead of all-gathering
-    every shard's candidates everywhere. The sequential ring combines
-    candidates in shard order with acc-wins tie-breaks — the same
-    lower-index-wins order lax.top_k applies over the shard-ordered
-    concat — so winners, values and indices stay bit-identical to the
-    XLA twin (interpret-mode fuzz pins this)."""
-    from jax import shard_map
-    from jax.sharding import PartitionSpec as P
-
-    from greptimedb_tpu.parallel.kernels import (
-        interpret_mode, ring_topk_merge,
-    )
-    from greptimedb_tpu.parallel.mesh import AXIS_SHARD
-
-    ns = int(mesh.devices.size)
-    interp = interpret_mode()
-
-    @functools.partial(
-        jax.jit,
-        static_argnames=("fname", "k", "largest", "range_ticks",
-                         "range_seconds", "l_cells", "tps", "fargs",
-                         "lookback_ticks"),
-    )
-    def program(
-        vals, has, tsg, smask, lo, hi, t_end, *,
-        fname: str, k: int, largest: bool, range_ticks: int,
-        range_seconds: float, l_cells: int, tps: float, fargs: tuple,
-        lookback_ticks: int,
-    ):
-        import jax.numpy as jnp
-
-        def local(vals, has, tsg, smask, lo, hi, t_end):
-            out, pres = _eval_side(
-                vals, has, tsg, smask, lo, hi, t_end, fname=fname,
-                range_ticks=range_ticks, range_seconds=range_seconds,
-                l_cells=l_cells, tps=tps, fargs=fargs,
-                lookback_ticks=lookback_ticks,
-            )
-            s_loc = out.shape[0]
-            key = _topk_key(out, pres, largest)
-            kl = min(k, s_loc)
-            l_key, l_idx = jax.lax.top_k(key.T, kl)    # (J, kl)
-            base = jax.lax.axis_index(AXIS_SHARD) * jnp.int32(s_loc)
-            l_gidx = base + l_idx.astype(jnp.int32)
-            l_pres = jnp.take_along_axis(pres.T, l_idx, axis=1)
-            l_vals = jnp.take_along_axis(out.T, l_idx, axis=1)
-            f_key, f_vals, f_idx, f_pres = ring_topk_merge(
-                l_key, l_vals.astype(jnp.float32), l_gidx, l_pres,
-                k=k, ns=ns, interpret=interp,
-            )
-            f_pres = f_pres & jnp.isfinite(f_key)
-            return jnp.concatenate([
-                f_vals.astype(jnp.float32),
-                f_idx.astype(jnp.float32),
-                f_pres.astype(jnp.float32),
-            ])
-
-        return shard_map(
-            local, mesh=mesh,
-            in_specs=(P(AXIS_SHARD, None), P(AXIS_SHARD, None),
-                      P(AXIS_SHARD, None), P(AXIS_SHARD),
-                      P(), P(), P()),
-            out_specs=P(), check_vma=False,
-        )(vals, has, tsg, smask, lo, hi, t_end)
-
-    return program
-
-
 _SHARDED_TOPK = ProgramCache(_make_sharded_fused_topk)
-_SHARDED_TOPK_PALLAS = ProgramCache(_make_sharded_fused_topk_pallas)
 
 
-def _get_sharded_topk(mesh, kernel: bool = False):
-    if kernel:
-        return _SHARDED_TOPK_PALLAS.get(mesh)
+def _get_sharded_topk(mesh):
     return _SHARDED_TOPK.get(mesh)
 
 
@@ -1506,42 +1431,21 @@ def try_fast_topk(engine, e, ev):
         return None
     lookback_ticks = max(int(ev.lookback_ms // entry.spec.unit), 1)
     kk = min(k, entry.num_series)
-    use_kernel = False
-    comm_bytes = 0
-    if entry.mesh is not None:
-        from greptimedb_tpu.query import planner as qplanner
-
-        qe = getattr(engine.instance, "query_engine", None)
-        kdec, kreason = qplanner.decide_kernel(
-            "topk", series=entry.num_series, k=kk,
-            opts=getattr(qe, "mesh_opts", None),
-        )
-        use_kernel = kdec == "pallas"
-        qplanner.record_kernel_decision("topk", kdec, kreason)
-        if use_kernel:
-            from greptimedb_tpu.parallel.kernels import topk_comm_bytes
-
-            comm_bytes = topk_comm_bytes(
-                int(entry.mesh.devices.size), int(lo.shape[0]), kk
-            )
     topk_prog = (_fused_topk if entry.mesh is None
-                 else _get_sharded_topk(entry.mesh, kernel=use_kernel))
+                 else _get_sharded_topk(entry.mesh))
     _note_mesh_decision(entry)
     from greptimedb_tpu.telemetry import device_trace
 
     from greptimedb_tpu.query import readback as _readback
 
-    skey = ("topk", entry.mesh is None, use_kernel, fname, kk,
-            e.op == "topk",
+    skey = ("topk", entry.mesh is None, fname, kk, e.op == "topk",
             range_ticks, range_seconds, l_cells, entry.spec.tps, fargs,
             lookback_ticks, id(smask), id(lo), id(hi), id(t_end))
     with device_trace.device_call(
-            "topk", key=("topk", entry.mesh is None, use_kernel, fname,
-                         kk,
+            "topk", key=("topk", entry.mesh is None, fname, kk,
                          e.op == "topk", range_ticks, range_seconds,
                          l_cells, entry.spec.tps, fargs,
-                         lookback_ticks),
-            collective=use_kernel, comm_bytes=comm_bytes) as dcall:
+                         lookback_ticks)) as dcall:
         packed_dev = _session_exec(entry, skey, lambda: dcall.run(
             topk_prog,
             entry.vals, entry.has, entry.tsg, smask, lo, hi, t_end,

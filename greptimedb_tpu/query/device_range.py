@@ -1665,16 +1665,13 @@ def _make_range_program():
     return program
 
 
-def _make_sharded_range_program(mesh, kernel: bool = False):
+def _make_sharded_range_program(mesh):
     """shard_map twin of the range program: grids series-sharded over
     AXIS_SHARD, each shard runs _range_body on its slice with the
     collective fold ctx; the selection's extent is computed in the same
     jit ahead of the shard_map, under auto-SPMD. fold=True outputs
     replicate (the post-fold window combine is tiny and runs
-    redundantly per shard); fold=False outputs stay series-sharded.
-    kernel=True threads the Pallas ring fold ctx
-    (parallel/kernels/ring_fold) instead of the gather_blocks
-    collectives — same fold order, 2(ns-1) accumulator hops."""
+    redundantly per shard); fold=False outputs stay series-sharded."""
     import jax
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
@@ -1690,12 +1687,7 @@ def _make_sharded_range_program(mesh, kernel: bool = False):
         arr_specs = jax.tree_util.tree_map(
             lambda _: P(AXIS_SHARD, None), arrs
         )
-        if kernel:
-            from greptimedb_tpu.parallel.kernels import RingFoldCtx
-
-            ctx = RingFoldCtx(ns)
-        else:
-            ctx = ShardFoldCtx(ns)
+        ctx = ShardFoldCtx(ns)
 
         def local(arrs, gid, sid_mask, delta, lo, hi):
             return _range_body(arrs, gid, sid_mask, delta, lo, hi, spec,
@@ -1717,14 +1709,9 @@ def _make_sharded_range_program(mesh, kernel: bool = False):
 
 
 _SHARDED_RANGE = ProgramCache(_make_sharded_range_program)
-_SHARDED_RANGE_PALLAS = ProgramCache(
-    lambda mesh: _make_sharded_range_program(mesh, kernel=True)
-)
 
 
-def get_sharded_program(mesh, kernel: bool = False):
-    if kernel:
-        return _SHARDED_RANGE_PALLAS.get(mesh)
+def get_sharded_program(mesh):
     return _SHARDED_RANGE.get(mesh)
 
 
@@ -2094,7 +2081,6 @@ def execute_range_device(engine, plan, table):
         )
         program = get_program()
         prog_tag = "single"
-        comm_bytes = 0
         entry_mesh = getattr(entry, "mesh", None)
         if entry_mesh is not None:
             if (not memo["fold"]
@@ -2103,28 +2089,6 @@ def execute_range_device(engine, plan, table):
                 # exact fold (bit-identical across mesh sizes)
                 program = get_sharded_program(entry_mesh)
                 prog_tag = "sharded"
-                # kernel variant: same decision decide_mesh_execution
-                # recorded at plan time (deterministic in the same inputs,
-                # so no double count here)
-                from greptimedb_tpu.query.planner import decide_kernel
-
-                kern, _ = decide_kernel(
-                    "range", series=entry.num_series,
-                    opts=getattr(engine, "mesh_opts", None),
-                )
-                if kern == "pallas":
-                    program = get_sharded_program(entry_mesh, kernel=True)
-                    prog_tag = "sharded_pallas"
-                    from greptimedb_tpu.parallel.kernels.ring_fold import (
-                        fold_comm_bytes,
-                    )
-                    from greptimedb_tpu.parallel.mesh import shard_count
-
-                    ns_ = shard_count(entry_mesh)
-                    for op_i, w_i, _f in prog_items:
-                        nb_i = (n_steps_b - 1) * stride + w_i
-                        planes = 1 + len(_STATE_COMBINE.get(op_i, ()))
-                        comm_bytes += fold_comm_bytes(ns_, g, nb_i, planes)
             else:
                 # oversized blocked fold (FOLD_BLOCKS*g*nb past the partial
                 # budget): stays on the auto-SPMD jit program — still
@@ -2192,9 +2156,7 @@ def execute_range_device(engine, plan, table):
     with stats.timed("device_exec_ms"), \
             device_trace.device_call(
                 "range", key=("range", prog_tag, prog_spec),
-                groups=g, steps=n_steps_b,
-                collective=prog_tag == "sharded_pallas",
-                comm_bytes=comm_bytes) as dcall:
+                groups=g, steps=n_steps_b) as dcall:
         extras = ()
         if out_dev is not None:
             stats.note("device_session", "hit")

@@ -166,13 +166,6 @@ class MeshDecision:
     mode: str            # "shard" | "replicate"
     reason: str          # why (threshold, op shape, mesh geometry, ...)
     devices: int = 1     # shard-axis devices the query will use
-    # program-variant dimension for sharded executions: "pallas" when
-    # the ring/merge kernels (parallel/kernels) carry the cross-shard
-    # combine, "xla" for the gather_blocks collective path. Recorded
-    # additively (kernel_label) — label() is unchanged so the existing
-    # mode/reason surfaces stay stable.
-    kernel: str = "xla"
-    kernel_reason: str = ""
 
     @property
     def shard(self) -> bool:
@@ -180,9 +173,6 @@ class MeshDecision:
 
     def label(self) -> str:
         return f"{self.mode}({self.reason})"
-
-    def kernel_label(self) -> str:
-        return f"{self.kernel}({self.kernel_reason})"
 
 
 def decide_mesh_execution(
@@ -220,64 +210,8 @@ def decide_mesh_execution(
     else:
         if rows is not None and rows < max(opts.shard_min_rows, 1):
             return MeshDecision("replicate", "small_rowset", devices=n_dev)
-    kernel, kreason = decide_kernel(kind, series=series, rows=rows,
-                                    opts=opts)
     return MeshDecision("shard", "large_grid" if kind in ("range", "promql")
-                        else "large_rowset", devices=n_dev,
-                        kernel=kernel, kernel_reason=kreason)
-
-
-def decide_kernel(
-    kind: str, *, series: int | None = None, rows: int | None = None,
-    k: int | None = None, opts=None,
-) -> tuple[str, str]:
-    """Choose the program variant for one already-sharded execution
-    site: "pallas" runs the parallel/kernels ring kernels, "xla" the
-    collective gather paths. Deterministic in its inputs, so execution
-    sites may re-ask with the same arguments without a planner
-    round-trip. `k` caps the topk merge kernel (O(k^2) ranks per
-    hop)."""
-    from greptimedb_tpu.parallel import kernels as pk
-    from greptimedb_tpu.parallel.mesh import MeshOptions
-
-    opts = opts or MeshOptions()
-    mode = pk.kernel_mode(opts)
-    if mode == "off":
-        return "xla", "kernels_off"
-    if mode == "auto" and not pk.native_available():
-        return "xla", "no_tpu"
-    if k is not None and k > max(getattr(opts, "pallas_max_k", 128), 1):
-        return "xla", "k_too_large"
-    if kind in ("range", "promql", "topk"):
-        if series is not None and \
-                series < max(getattr(opts, "pallas_min_series", 4096), 1):
-            return "xla", "small_grid"
-        return "pallas", ("ring_topk" if kind == "topk" or k is not None
-                          else "ring_fold")
-    if rows is not None and \
-            rows < max(getattr(opts, "pallas_min_rows", 262144), 1):
-        return "xla", "small_rowset"
-    return "pallas", "ring_fold"
-
-
-def record_kernel_decision(kind: str, kernel: str, reason: str) -> None:
-    """Surface one kernel-variant choice in EXPLAIN ANALYZE + metrics.
-    Rides the existing gtpu_mesh_queries_total counter under the
-    "<kind>_kernel" site label so the established mode/reason series
-    are untouched."""
-    from greptimedb_tpu.query import stats
-    from greptimedb_tpu.telemetry import stmt_stats, tracing
-    from greptimedb_tpu.telemetry.metrics import global_registry
-
-    label = f"{kernel}({reason})"
-    stats.note(f"mesh_kernel_{kind}", label)
-    tracing.set_attr(**{f"mesh_kernel_{kind}": label})
-    stmt_stats.note("mesh_kernel", label)
-    global_registry.counter(
-        "gtpu_mesh_queries_total",
-        "Mesh execution decisions by mode/reason/site",
-        labels=("kind", "mode", "reason"),
-    ).labels(f"{kind}_kernel", kernel, reason).inc()
+                        else "large_rowset", devices=n_dev)
 
 
 def record_scan_path(pruned: bool) -> None:
@@ -328,9 +262,6 @@ def record_mesh_decision(decision: MeshDecision, kind: str) -> None:
         "Mesh execution decisions by mode/reason/site",
         labels=("kind", "mode", "reason"),
     ).labels(kind, decision.mode, decision.reason).inc()
-    if decision.shard and decision.kernel_reason:
-        record_kernel_decision(kind, decision.kernel,
-                               decision.kernel_reason)
 
 
 _NORMALIZE_AGG = {

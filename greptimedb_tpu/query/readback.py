@@ -87,5 +87,5 @@ def read_outputs(arr, lo: int, extras=(), *, axis: int = -1) -> tuple:
 
 
 def readback_bytes(mode: str) -> float:
-    """Current counter value (tests, bench)."""
+    """Current counter value (tests)."""
     return _READBACK_BYTES.labels(mode).value

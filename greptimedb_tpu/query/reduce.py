@@ -312,9 +312,6 @@ def _fused_program():
 
 _2_31M = 2**31 - 1
 _FUSED = None
-# keyed (mesh, kernel): the Pallas ring variant is a distinct compiled
-# program and must never cross-serve the XLA collective twin
-_SHARDED_FUSED = ProgramCache(lambda key: _sharded_fused_program(*key))
 
 
 def _pow2_floor(n: int) -> int:
@@ -332,18 +329,14 @@ def _pick_blocks(nb: int, gb: int) -> int:
     return max(1, min(nb, _pow2_floor(max(8, (1 << 20) // max(gb, 1)))))
 
 
-def _sharded_fused_program(mesh, kernel: bool = False):
+def _sharded_fused_program(mesh):
     """shard_map twin of _fused_program: rows sharded over AXIS_SHARD,
     each shard computes its aligned slice of the per-(group, block)
     partials locally (identical rows, identical scatter order), blocked
     sections concatenate by output sharding, extremes recombine with
     pmin/pmax and first/last winners with staged exact selection +
     psum value extraction (the dist_segment_agg pattern from
-    parallel/dist.py generalized to the fused multi-aggregate layout).
-    kernel=True swaps the cross-shard pext/psum collectives for the
-    Pallas sequential-ring twins (parallel/kernels/ring_fold) — exact
-    for these payloads: extremes are associative, and the psum only
-    ever extracts masked one-nonzero winner values."""
+    parallel/dist.py generalized to the fused multi-aggregate layout)."""
     import jax
     import jax.numpy as jnp
     from jax import shard_map
@@ -353,12 +346,7 @@ def _sharded_fused_program(mesh, kernel: bool = False):
     from greptimedb_tpu.parallel.mesh import AXIS_SHARD
 
     ns = mesh.shape[AXIS_SHARD]
-    if kernel:
-        from greptimedb_tpu.parallel.kernels import RingFoldCtx
-
-        ctx = RingFoldCtx(ns)
-    else:
-        ctx = ShardFoldCtx(ns)
+    ctx = ShardFoldCtx(ns)
 
     @functools.partial(jax.jit, static_argnames=("spec",))
     def program(vals, masks, gid, tshi, tslo, *, spec):
@@ -447,6 +435,9 @@ def _sharded_fused_program(mesh, kernel: bool = False):
     return program
 
 
+_SHARDED_FUSED = ProgramCache(_sharded_fused_program)
+
+
 def _make_row_put(mesh):
     """Host->device placement for row-axis arrays: single-device
     jnp.asarray, or row-axis sharding over the mesh (SURVEY.md §2.7 #2 —
@@ -474,10 +465,9 @@ def _make_row_put(mesh):
 
 
 def _device_reduce_fused(specs, values: dict, gid, valid_map, g: int, ts,
-                         mesh=None, kernel: bool = False):
+                         mesh=None):
     """Single-program GROUP BY. specs: (name, op, vkey|None, q). Returns
-    {name: (np values, np valid|None)}. kernel=True dispatches the
-    Pallas ring variant of the sharded program (planner-decided)."""
+    {name: (np values, np valid|None)}."""
     global _FUSED
     import jax.numpy as jnp
 
@@ -547,27 +537,10 @@ def _device_reduce_fused(specs, values: dict, gid, valid_map, g: int, ts,
         d_vals, d_masks, d_gid, d_tshi, d_tslo
     ) if hasattr(a, "nbytes"))
     if mesh is not None:
-        prog = _SHARDED_FUSED.get((mesh, kernel))
-        prog_tag = "groupby-sharded-pallas" if kernel else "groupby-sharded"
-        comm_bytes = 0
-        if kernel:
-            # declared ring traffic: one (gb,) f32 ring pass per
-            # cross-shard extreme stage (min/max: 1; first/last: 3
-            # staged pext + 1 psum extraction)
-            from greptimedb_tpu.parallel.kernels import ring_comm_bytes
-            from greptimedb_tpu.parallel.mesh import AXIS_SHARD as _AX
-
-            ns_ = mesh.shape[_AX]
-            passes = sum(
-                1 if op2 in ("min", "max") else 4
-                for op2, _vi, _mi in items
-                if op2 in ("min", "max", "first_value", "last_value")
-            )
-            comm_bytes = ring_comm_bytes(ns_, 4 * gb) * passes
+        prog = _SHARDED_FUSED.get(mesh)
         with device_trace.device_call(
-                "groupby", key=(prog_tag, spec),
-                groups=g, collective=kernel,
-                comm_bytes=comm_bytes) as dcall:
+                "groupby", key=("groupby-sharded", spec),
+                groups=g) as dcall:
             dcall.transfer(upload, "upload")
             out_b, out_s = dcall.run(prog, d_vals, d_masks, d_gid,
                                      d_tshi, d_tslo, spec=spec)
@@ -678,7 +651,6 @@ def grouped_reduce(
         path = "host:dtype"
     if path == "device":
         use_mesh = None
-        kernel = False
         if mesh is not None:
             from greptimedb_tpu.query import planner as qplanner
 
@@ -689,10 +661,8 @@ def grouped_reduce(
             qplanner.record_mesh_decision(dec, "aggregate")
             if dec.shard:
                 use_mesh = mesh
-                kernel = dec.kernel == "pallas"
         return _device_reduce_fused(
             specs, values, gid, valid_map, g, ts, mesh=use_mesh,
-            kernel=kernel,
         ), path
     out = {}
     for name, op, vk, q in specs:

@@ -11,7 +11,7 @@ under one registry lock.
 Three pieces live here:
 
 - ``RecoveryOptions`` — the ``[recovery]`` knob surface shared by the
-  engine, the CLI config loader, and the bench probe.
+  engine and the CLI config loader.
 - ``restore_region_ssts`` — the pipelined fetch/verify/decode of a
   region's manifest SSTs with a bounded readahead window. Fetches are
   ranged gets of exactly the manifest's ``size_bytes``; a short read is
@@ -86,7 +86,7 @@ def record_region() -> None:
 
 
 def stage_totals() -> dict[str, float]:
-    """Current aggregate per-stage ms (bench/probe snapshots)."""
+    """Current aggregate per-stage ms (snapshots before and after)."""
     return {key[0]: child.value for key, child in _stage_ms._snapshot()}
 
 

@@ -136,12 +136,6 @@ _M_READBACK = global_registry.counter(
     "device->host bytes read back by dispatches of (site, program)",
     labels=("site", "program"),
 )
-_M_COMM = global_registry.counter(
-    "gtpu_device_program_comm_bytes_total",
-    "declared inter-chip bytes moved by collective kernel dispatches "
-    "of (site, program)",
-    labels=("site", "program"),
-)
 _M_COMPILE = global_registry.gauge(
     "gtpu_device_program_compile_ms",
     "wall time of the first execution (includes XLA compilation)",
@@ -264,7 +258,6 @@ class _Program:
         "analysis", "analysis_error", "flops", "bytes_accessed",
         "temp_bytes", "output_bytes", "argument_bytes",
         "aot_compile_ms", "_spec", "_compile_done", "metric_prog",
-        "collective", "comm_bytes",
     )
 
     def __init__(self, site: str, prog_id: str, key_text: str):
@@ -292,11 +285,6 @@ class _Program:
         self.output_bytes = 0
         self.argument_bytes = 0
         self.aot_compile_ms = 0.0
-        # collective kernel programs (Pallas ring/merge paths) declare
-        # their inter-chip copy sizes per dispatch; cumulative here so
-        # communication share per program is computable from the row
-        self.collective = False
-        self.comm_bytes = 0
         self._spec = None              # (fn, arg specs, kw specs)
         # monotonic instant the compile call finished: dispatches that
         # STARTED before it blocked on the shared XLA compile and are
@@ -310,15 +298,11 @@ class _Program:
     # -- folding -------------------------------------------------------
     def fold_call(self, execute_ms: float | None, upload: int,
                   readback: int, *, dispatch_only: bool,
-                  run_start: float | None = None,
-                  collective: bool = False, comm_bytes: int = 0):
+                  run_start: float | None = None):
         self.calls += 1
         self.last_seen_ms = int(time.time() * 1000)
         self.upload_bytes += upload
         self.readback_bytes += readback
-        if collective:
-            self.collective = True
-        self.comm_bytes += int(comm_bytes)
         if execute_ms is None:
             # failed dispatch: if it was the compile attempt,
             # compile_ms stays None and the NEXT successful call (which
@@ -352,8 +336,6 @@ class _Program:
             self.exec_buckets[i] += other.exec_buckets[i]
         self.upload_bytes += other.upload_bytes
         self.readback_bytes += other.readback_bytes
-        self.collective = self.collective or other.collective
-        self.comm_bytes += other.comm_bytes
         self.dispatch_only = self.dispatch_only or other.dispatch_only
         if other.compile_ms:
             self.compile_ms = (self.compile_ms or 0.0) + other.compile_ms
@@ -416,8 +398,6 @@ class _Program:
             "device_ms_total": round(self.device_ms(), 3),
             "upload_bytes": int(self.upload_bytes),
             "readback_bytes": int(self.readback_bytes),
-            "collective": self.collective,
-            "comm_bytes": int(self.comm_bytes),
             "dispatch_only": self.dispatch_only,
             "analysis": self.analysis,
             "analysis_error": self.analysis_error,
@@ -539,14 +519,12 @@ class DeviceProgramRegistry:
     def finish(self, row: _Program, *,
                execute_ms: float | None, upload: int, readback: int,
                dispatch_only: bool = False,
-               run_start: float | None = None,
-               collective: bool = False, comm_bytes: int = 0):
+               run_start: float | None = None):
         with self._lock:
             cold = row.compile_ms is None
             row.fold_call(execute_ms, upload, readback,
                           dispatch_only=dispatch_only,
-                          run_start=run_start,
-                          collective=collective, comm_bytes=comm_bytes)
+                          run_start=run_start)
             compiled = cold and row.compile_ms is not None
         if compiled:
             # rare (once a program row): the one push-model family here
@@ -728,12 +706,11 @@ class DeviceProgramRegistry:
             a = agg.get(lab)
             if a is None:
                 a = agg[lab] = {"calls": 0, "exec": 0.0, "up": 0,
-                                "rb": 0, "comm": 0, "doc": None}
+                                "rb": 0, "doc": None}
             a["calls"] += d["calls"]
             a["exec"] += d["execute_ms_total"]
             a["up"] += d["upload_bytes"]
             a["rb"] += d["readback_bytes"]
-            a["comm"] += d["comm_bytes"]
             if mp == d["program"]:
                 a["doc"] = d
         live: set[tuple[str, str]] = set()
@@ -743,7 +720,6 @@ class DeviceProgramRegistry:
             _set_value(_M_EXEC.labels(*lab), a["exec"])
             _set_value(_M_UPLOAD.labels(*lab), a["up"])
             _set_value(_M_READBACK.labels(*lab), a["rb"])
-            _set_value(_M_COMM.labels(*lab), a["comm"])
             d = a["doc"]
             if d is None:
                 # an over-cap aggregate label: per-program gauges are
@@ -763,8 +739,7 @@ class DeviceProgramRegistry:
         for lab in self._published - live:
             # vanished rows (ADMIN reset / LRU collapse): zero, don't
             # freeze — the surfaces must agree at every scrape
-            for fam in (_M_CALLS, _M_EXEC, _M_UPLOAD, _M_READBACK,
-                        _M_COMM):
+            for fam in (_M_CALLS, _M_EXEC, _M_UPLOAD, _M_READBACK):
                 _set_value(fam.labels(*lab), 0)
             for fam in (_M_COMPILE, _M_P50, _M_P99, _M_FLOPS, _M_BYTES,
                         _M_GFLOPS, _M_GBPS, _M_PCT):

@@ -74,21 +74,11 @@ class device_call:
 
     __slots__ = ("_cm", "_span", "_mono0", "site", "_stmt", "key",
                  "_rec", "_first", "_run_t0", "_exec_ms", "_up", "_rb",
-                 "_dispatch_only", "collective", "comm_bytes")
+                 "_dispatch_only")
 
-    def __init__(self, site: str, *, key=None, collective: bool = False,
-                 comm_bytes: int = 0, **attrs):
+    def __init__(self, site: str, *, key=None, **attrs):
         self.site = site
         self.key = key
-        # collective-time attribution (kernel programs with declared
-        # inter-chip copies): rides the span AND the program row, so
-        # bench multichip can report communication share per mesh size
-        self.collective = bool(collective)
-        self.comm_bytes = int(comm_bytes)
-        if self.collective:
-            attrs = dict(attrs)
-            attrs["collective"] = True
-            attrs["comm_bytes"] = self.comm_bytes
         self._rec = None
         self._first = False
         self._run_t0 = 0.0
@@ -185,9 +175,7 @@ class device_call:
             reg.finish(rec, execute_ms=self._exec_ms,
                        upload=self._up, readback=self._rb,
                        dispatch_only=self._dispatch_only,
-                       run_start=self._run_t0 or None,
-                       collective=self.collective,
-                       comm_bytes=self.comm_bytes)
+                       run_start=self._run_t0 or None)
         if self._stmt:
             # program-registry link: the statement_statistics row lists
             # the program ids its executions used (dispatched, or

@@ -307,7 +307,7 @@ class MetricsRegistry:
 
     def get(self, name) -> _Metric:
         """Look up an existing metric WITHOUT declaring its schema
-        (bench/test readers that only consume values). KeyError when
+        (readers that only consume values: renderers, tests). KeyError when
         the metric has not been registered by its owning module yet."""
         with self._lock:
             m = self._metrics.get(name)

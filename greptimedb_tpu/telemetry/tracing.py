@@ -23,7 +23,8 @@ keep/drop decision happens when the process-local root span finishes —
 error traces, slow traces (>= slow_ms) and explicitly marked traces
 (`mark_keep`) are ALWAYS kept; the rest keep with probability
 `sample_ratio`. `[tracing]` TOML knobs: enable, sample_ratio, capacity
-(trace ring size, 0 = unbounded — bench.py refuses that), slow_ms.
+(trace ring size; 0 = unbounded: the ring then grows with every kept
+trace), slow_ms.
 
 Timestamps: `start_ms` is epoch milliseconds (display/correlation);
 durations are computed on the MONOTONIC clock (an NTP slew must never
@@ -114,7 +115,7 @@ def enabled() -> bool:
 
 def ring_unbounded() -> bool:
     """True when the trace ring has no capacity bound (capacity <= 0):
-    a misconfiguration bench.py refuses to measure under."""
+    a misconfiguration, since memory then grows with every kept trace."""
     return global_traces.cap <= 0
 
 
@@ -347,7 +348,7 @@ class _TraceStore:
 
     def _evict_locked(self):
         if self.cap <= 0:
-            return  # unbounded (bench.py refuses to run like this)
+            return  # unbounded: nothing is ever evicted
         while len(self._spans) > self.cap:
             victim, _ = self._spans.popitem(last=False)
             self._kept.discard(victim)

@@ -1000,7 +1000,7 @@ class MetricFamilyContract(ContractRule):
     name = "metric-family-contract"
     description = (
         "A `gtpu_*`/`greptime_*` metric family name referenced by a "
-        "renderer, bench probe, or test (registry.get(), or a string "
+        "renderer or test (registry.get(), or a string "
         "literal carrying a conventional family suffix: _total, "
         "_seconds, _ms, _bytes, _bucket, _sum, _count) must be "
         "registered somewhere in the program — an unregistered "

@@ -126,18 +126,15 @@ def changed_files(ref: str) -> set[str] | None:
 
 def _aux_paths(done: set[str]) -> list[str]:
     """Harvest-only files for the whole-program contracts pass: the
-    rest of the package plus the repo's reference surfaces (tests and
-    bench.py hold metric-name references and action dispatches the
-    contract model must see). Returns paths not already in `done`."""
+    rest of the package plus the repo's reference surfaces (tests
+    hold metric-name references and action dispatches the contract
+    model must see). Returns paths not already in `done`."""
     out: list[str] = []
     roots = [os.path.join(_REPO_ROOT, "greptimedb_tpu"),
              os.path.join(_REPO_ROOT, "tests")]
     for root in roots:
         if os.path.isdir(root):
             out.extend(iter_py_files([root]))
-    bench = os.path.join(_REPO_ROOT, "bench.py")
-    if os.path.isfile(bench):
-        out.append(bench)
     return [p for p in out if _norm_path(p) not in done]
 
 
@@ -209,8 +206,8 @@ def lint_paths(paths: list[str], *, baseline: Baseline | None = None,
     Each file is parsed exactly ONCE: the tree feeds both the per-file
     walk and the whole-program contracts pass (GT028-GT032). The
     contracts pass is whole-program by construction — besides the
-    scanned files it harvests the rest of the package, tests/, bench.py
-    and README.md, so a subdirectory run still checks against the full
+    scanned files it harvests the rest of the package, tests/ and
+    README.md, so a subdirectory run still checks against the full
     contract surfaces. `--changed` runs skip it (a partial forest
     cannot decide cross-file contracts; the full gate run catches the
     drift)."""

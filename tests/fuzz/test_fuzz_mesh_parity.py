@@ -33,12 +33,6 @@ PER_BATCH = 20
 # tiny test grids: force the replicate-vs-shard planner to shard so the
 # shard_map programs actually execute (prod defaults gate on 4096 series)
 FORCE_SHARD = M.MeshOptions(shard_min_series=1, shard_min_rows=1)
-# kernel leg (ISSUE 17): same sharding, plus the Pallas ring/merge
-# kernel programs forced on (interpret mode on this CPU platform, so
-# the real kernel bodies execute) with thresholds dropped to the floor
-FORCE_KERNEL = M.MeshOptions(shard_min_series=1, shard_min_rows=1,
-                             pallas_kernels="on", pallas_min_series=1,
-                             pallas_min_rows=1)
 
 ROW_AGGS = ["count", "sum", "min", "max", "avg",
             "first_value", "last_value"]
@@ -68,9 +62,7 @@ def sql_setup(tmp_path_factory):
     e1 = QueryEngine(prefer_device=True)
     em = QueryEngine(prefer_device=True, mesh=M.make_mesh(),
                      mesh_opts=FORCE_SHARD)
-    ek = QueryEngine(prefer_device=True, mesh=M.make_mesh(),
-                     mesh_opts=FORCE_KERNEL)
-    yield inst, e1, em, ek
+    yield inst, e1, em
     inst.close()
 
 
@@ -141,7 +133,7 @@ def _random_sql(rng) -> str:
 
 @pytest.mark.parametrize("batch", range(BATCHES))
 def test_mesh_parity_fuzz_sql(sql_setup, batch):
-    inst, e1, em, _ek = sql_setup
+    inst, e1, em = sql_setup
     rng = np.random.default_rng(SEED + batch * 104729)
     sharded = 0
     for _ in range(PER_BATCH):
@@ -157,28 +149,6 @@ def test_mesh_parity_fuzz_sql(sql_setup, batch):
     assert sharded >= PER_BATCH * 2 // 3, sharded
 
 
-@pytest.mark.parametrize("batch", range(BATCHES))
-def test_mesh_parity_fuzz_sql_kernels(sql_setup, batch):
-    """Kernel-path leg (ISSUE 17 satellite): the Pallas ring programs
-    (interpret mode — real kernel bodies on the forced 8-device CPU
-    mesh) stay bit-identical to the single-device engine on the same
-    random query stream, and actually take the kernel path."""
-    inst, e1, _em, ek = sql_setup
-    rng = np.random.default_rng(SEED + batch * 104729)
-    kernel_hits = 0
-    for _ in range(PER_BATCH):
-        q = _random_sql(rng)
-        r1 = _run(e1, inst, q)
-        with qstats.collect() as collected:
-            rk = _run(ek, inst, q)
-        _exact(r1, rk, q)
-        if any(k.startswith("mesh_kernel_") and v.startswith("pallas(")
-               for k, v in collected.notes.items()):
-            kernel_hits += 1
-    # the leg must exercise the Pallas programs, not the XLA fallback
-    assert kernel_hits >= PER_BATCH * 2 // 3, kernel_hits
-
-
 # ----------------------------------------------------------------------
 # PromQL: rate/aggregate + topk over the selector-grid fast path
 # ----------------------------------------------------------------------
@@ -186,10 +156,10 @@ def test_mesh_parity_fuzz_sql_kernels(sql_setup, batch):
 
 @pytest.fixture(scope="module")
 def prom_setup(tmp_path_factory):
-    def build(home, mesh, opts=FORCE_SHARD):
+    def build(home, mesh):
         rng = np.random.default_rng(SEED)  # identical data all builds
         inst = Standalone(str(home), prefer_device=True, mesh=mesh,
-                          mesh_opts=None if mesh is None else opts,
+                          mesh_opts=None if mesh is None else FORCE_SHARD,
                           warm_start=False)
         inst.execute_sql(
             "create table http_requests (ts timestamp time index, "
@@ -213,14 +183,12 @@ def prom_setup(tmp_path_factory):
     tmp = tmp_path_factory.mktemp("mesh_parity_prom")
     i1 = build(tmp / "single", None)
     im = build(tmp / "mesh", M.make_mesh())
-    ik = build(tmp / "kern", M.make_mesh(), FORCE_KERNEL)
-    yield i1, im, ik
+    yield i1, im
     from greptimedb_tpu.promql import fast as F
 
     F.invalidate_cache()
     i1.close()
     im.close()
-    ik.close()
 
 
 def _random_promql(rng) -> str:
@@ -236,17 +204,17 @@ def _random_promql(rng) -> str:
     return f"{op} {by}({fn}({sel}))"
 
 
-def _prom_exact(queries, rs1, rs2, tag=""):
+def _prom_exact(queries, rs1, rs2):
     for q, r1, rm in zip(queries, rs1, rs2):
         l1 = [frozenset(lb.items()) for lb in r1.labels]
         lm = [frozenset(lb.items()) for lb in rm.labels]
-        assert l1 == lm, f"labels differ for{tag}: {q}"
+        assert l1 == lm, f"labels differ for: {q}"
         assert (r1.present == rm.present).all(), \
-            f"presence differs{tag}: {q}"
+            f"presence differs: {q}"
         a = np.where(r1.present, r1.values, 0.0)
         b = np.where(rm.present, rm.values, 0.0)
         assert np.array_equal(a, b, equal_nan=True), (
-            f"values not bit-identical for{tag}: {q}\n{a}\nvs\n{b}"
+            f"values not bit-identical for: {q}\n{a}\nvs\n{b}"
         )
 
 
@@ -254,9 +222,8 @@ def _prom_exact(queries, rs1, rs2, tag=""):
 def test_mesh_parity_fuzz_promql(prom_setup):
     from greptimedb_tpu.promql import fast as F
     from greptimedb_tpu.promql.engine import PromEngine
-    from greptimedb_tpu.telemetry.metrics import global_registry
 
-    i1, im, ik = prom_setup
+    i1, im = prom_setup
     rng = np.random.default_rng(SEED + 7919)
     queries = [_random_promql(rng) for _ in range(PER_BATCH)]
     t0, t1, step = 0, 119 * 10_000, 60_000
@@ -277,17 +244,3 @@ def test_mesh_parity_fuzz_promql(prom_setup):
     assert entry.mesh is not None
     assert len(entry.vals.devices()) == 8
     _prom_exact(queries, rs1, rsm)
-    # kernel leg (ISSUE 17 satellite): the ring topk merge + ring fold
-    # programs, interpret mode, same stream — still bit-identical, and
-    # the topk queries really took the Pallas path
-    ctr = global_registry.counter(
-        "gtpu_mesh_queries_total",
-        "Mesh execution decisions by mode/reason/site",
-        labels=("kind", "mode", "reason"),
-    ).labels("topk_kernel", "pallas", "ring_topk")
-    before = ctr.value
-    rsk = run_all(ik)
-    _prom_exact(queries, rs1, rsk, tag=" (kernel)")
-    n_topk = sum(1 for q in queries if q.startswith(("topk", "bottomk")))
-    assert n_topk > 0
-    assert ctr.value - before >= n_topk

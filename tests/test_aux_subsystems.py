@@ -344,10 +344,12 @@ def test_http_log_ingest(tmp_path):
 
 def test_explain_analyze_stage_metrics(tmp_path):
     """EXPLAIN ANALYZE reports per-stage metrics (VERDICT r2 task #9):
-    rows scanned, exec path, cache state, reduce/device timings."""
+    rows scanned, exec path, cache state, reduce/device timings, and
+    whether the scan rode the tag index."""
     import numpy as np
 
     from greptimedb_tpu.instance import Standalone
+    from greptimedb_tpu.telemetry.metrics import global_registry
 
     inst = Standalone(str(tmp_path / "data"))
     inst.sql(
@@ -366,6 +368,18 @@ def test_explain_analyze_stage_metrics(tmp_path):
     assert "agg_groups: 2" in text
     assert "exec_path_aggregate:" in text
     assert "reduce_ms:" in text
+    assert "scan_path: full_scan" in text
+    # a tag matcher rides the index: stamped on the plan and counted
+    scans = global_registry.counter(
+        "gtpu_index_scans_total", labels=("path",)
+    ).labels("index_pruned")
+    before = scans.value
+    r = inst.sql("EXPLAIN ANALYZE SELECT host, count(*) FROM ea "
+                 "WHERE host = 'b' GROUP BY host")
+    text = "\n".join(row[0] for row in r.rows())
+    assert "scan_path: index_pruned" in text
+    assert "rows_scanned: 10" in text
+    assert scans.value == before + 1
     # joins report their stage too
     r = inst.sql(
         "EXPLAIN ANALYZE SELECT a.host FROM ea a JOIN ea b ON a.host = b.host"

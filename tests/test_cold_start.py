@@ -123,54 +123,3 @@ def test_program_specs_persist_and_precompile(tmp_path, rng):
     )
     assert r.num_rows > 0
     inst2.close()
-
-
-def test_bench_emit_ordering():
-    """Every auditable metric must sit in the FINAL output block, in
-    tail-priority order, with the headline last (VERDICT r3 weak #5)."""
-    import importlib.util
-    import json
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(__file__), "..",
-                              "bench.py")
-    )
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    lines = [
-        json.dumps({"metric": "tsbs_ingest_skip_wal_rows_per_s",
-                    "value": 1}),
-        json.dumps({"metric": "tsbs_ingest_wal_rows_per_s", "value": 2}),
-        json.dumps({"metric": "tsbs_lastpoint_sql_ms", "value": 3}),
-        json.dumps({"metric": "tsbs_single_groupby_1_1_1_sql_ms",
-                    "value": 4}),
-        json.dumps({"metric": "tsbs_groupby_orderby_limit_sql_ms",
-                    "value": 5}),
-        json.dumps({"metric": "promql_1m_series_range_p50_ms",
-                    "value": 6}),
-        json.dumps({"metric": "tsbs_double_groupby_all_sql_ms",
-                    "value": 7}),
-    ]
-    import io
-    from contextlib import redirect_stdout
-
-    buf = io.StringIO()
-    with redirect_stdout(buf):
-        bench._emit_ordered(
-            lines, json.dumps({"metric": "cold_start_first_query_ms",
-                               "value": 8})
-        )
-    out = [json.loads(x) for x in buf.getvalue().splitlines()]
-    metrics = [d["metric"] for d in out]
-    assert metrics[-1] == "tsbs_double_groupby_all_sql_ms"
-    assert metrics[-2] == "cold_start_first_query_ms"
-    # every audit-critical metric present in the test input sits in the
-    # final block, directly before cold-start + headline
-    present = [m for m in bench._TAIL_PRIORITY if m in metrics]
-    tail = set(metrics[-(len(present) + 2):])
-    for m in present:
-        assert m in tail, m
-    # shape metrics precede them
-    assert metrics[0] == "tsbs_single_groupby_1_1_1_sql_ms"

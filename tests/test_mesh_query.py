@@ -70,8 +70,11 @@ def _compare(ra, rb):
             )
 
 
-def test_sql_on_8device_mesh_matches_single(inst, devices):
-    mesh = M.make_mesh(devices)  # 8-way series sharding
+@pytest.mark.parametrize("n_dev", [2, 4, 8])
+def test_sql_on_mesh_matches_single(inst, devices, n_dev):
+    """Series sharding over 2, 4 and 8 devices answers the flagship
+    shape bit for bit as one device does."""
+    mesh = M.make_mesh(devices[:n_dev])
     e1 = QueryEngine(prefer_device=True)
     em = QueryEngine(prefer_device=True, mesh=mesh, mesh_opts=FORCE_SHARD)
     r1 = _run(e1, inst, FLAGSHIP)
@@ -82,8 +85,14 @@ def test_sql_on_8device_mesh_matches_single(inst, devices):
     entry = next(iter(em.range_cache._entries.values()))
     sharding = entry.nrow.sharding
     assert getattr(sharding, "mesh", None) is not None
-    assert len(entry.nrow.devices()) == 8
-    _compare(r1, rm)
+    assert len(entry.nrow.devices()) == n_dev
+    assert r1.num_rows == rm.num_rows
+    for name, c1, cm in zip(r1.names, r1.cols, rm.cols):
+        a, b = np.asarray(c1.values), np.asarray(cm.values)
+        if a.dtype == object:
+            assert (a == b).all(), name
+        else:
+            assert np.array_equal(a, b, equal_nan=True), name
 
 
 def test_sql_on_mesh_global_group(inst, devices):
@@ -285,7 +294,10 @@ def test_planner_decision_through_query_path(inst, devices):
 def test_mesh_metrics_and_explain_analyze(tmp_path, rng):
     """gtpu_mesh_* must render in /metrics AND runtime_metrics, and
     EXPLAIN ANALYZE must carry the replicate-vs-shard decision. Uses the
-    full [mesh]-config lifecycle (configure() from TOML-shaped knobs)."""
+    full [mesh]-config lifecycle (configure() from TOML-shaped knobs).
+    A sharded query runs its one sharded program: a `pallas_*` key
+    left in an old TOML selects nothing, and no kernel variant shows in
+    the plan's notes or in the counter's kinds."""
     import urllib.request
 
     from greptimedb_tpu.servers.http import HttpServer
@@ -294,7 +306,10 @@ def test_mesh_metrics_and_explain_analyze(tmp_path, rng):
     try:
         opts = M.mesh_options_from({
             "enabled": True, "shard_min_series": 1, "shard_min_rows": 1,
+            "pallas_kernels": "on", "pallas_min_series": 1,
         })
+        assert opts == M.MeshOptions(enabled=True, shard_min_series=1,
+                                     shard_min_rows=1)
         mesh = M.configure(opts)
         assert mesh is not None and M.shard_count(mesh) == 8
         inst = Standalone(str(tmp_path), mesh=mesh, mesh_opts=opts,
@@ -317,6 +332,7 @@ def test_mesh_metrics_and_explain_analyze(tmp_path, rng):
         text = "\n".join(row[0] for row in r.rows())
         assert "mesh_decision_range: shard(large_grid)" in text
         assert "mesh_devices: 8" in text
+        assert "mesh_kernel_" not in text
         srv = HttpServer(inst, port=0).start()
         try:
             with urllib.request.urlopen(
@@ -326,6 +342,7 @@ def test_mesh_metrics_and_explain_analyze(tmp_path, rng):
             assert "gtpu_mesh_devices 8" in body
             assert ('gtpu_mesh_queries_total{kind="range",mode="shard",'
                     'reason="large_grid"}') in body
+            assert '_kernel"' not in body
         finally:
             srv.stop()
         res = inst.sql("select metric_name from "

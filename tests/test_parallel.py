@@ -73,19 +73,66 @@ def test_halo_exchange_window_sum(mesh8, rng):
     np.testing.assert_allclose(got, want, rtol=1e-5)
 
 
-def test_dist_topk(mesh8, rng):
-    n, k = 256, 7
-    vals = rng.normal(size=n).astype(np.float32)
-    mask = rng.random(n) > 0.1
+@pytest.mark.parametrize("k", [1, 8, 130])
+@pytest.mark.parametrize("largest", [True, False])
+def test_dist_topk(mesh8, rng, largest, k):
+    """Values AND indices equal single-device lax.top_k: few distinct
+    values (ties everywhere, the lower index wins), 60% of the rows
+    masked (at k=130 the fill slots are reached), and at k=130 a shard
+    holds 64 rows, fewer than k."""
+    n = 256
+    vals = rng.integers(-4, 5, n).astype(np.float32)
+    mask = rng.random(n) > 0.6
     sharding = dist.shard_rows_sharding(mesh8)
     dv = jax.device_put(jnp.array(vals), sharding)
     dm = jax.device_put(jnp.array(mask), sharding)
-    top_v, top_i = dist.dist_topk(mesh8, k)(dv, dm)
-    masked = np.where(mask, vals, -np.inf)
-    want = np.sort(masked)[::-1][:k]
-    np.testing.assert_allclose(np.asarray(top_v), want, rtol=1e-6)
-    np.testing.assert_array_equal(np.sort(vals[np.asarray(top_i)]),
-                                  np.sort(want))
+    top_v, top_i = dist.dist_topk(mesh8, k, largest=largest)(dv, dm)
+    sign = 1.0 if largest else -1.0
+    want_v, want_i = jax.lax.top_k(
+        jnp.where(jnp.array(mask), sign * jnp.array(vals), -jnp.inf), k)
+    np.testing.assert_array_equal(np.asarray(top_v),
+                                  sign * np.asarray(want_v))
+    np.testing.assert_array_equal(np.asarray(top_i), np.asarray(want_i))
+    assert int(mask.sum()) < 130 <= n  # k=130 does reach the fill
+
+
+def _bits(a) -> np.ndarray:
+    """Through the unsigned twin: -0.0 against +0.0 and NaN payloads
+    compare by bit pattern."""
+    return np.asarray(a).view(np.uint32)
+
+
+@pytest.mark.parametrize("ns", [1, 2, 4, 8])
+def test_blocked_fold_is_bit_identical_across_mesh_sizes(rng, ns):
+    """The cross-shard sum seam (gather_blocks + the unrolled left
+    fold) over FOLD_BLOCKS partial blocks split over ns shards equals
+    the single-device fold bit for bit, on planes holding NaNs with
+    payloads, -0.0, +0.0 and both infinities, and every shard holds
+    the same answer."""
+    g, nb = 5, 16
+    x = rng.standard_normal((M.FOLD_BLOCKS, g, nb)).astype(np.float32)
+    x[rng.random(x.shape) < 0.05] = -0.0
+    x[rng.random(x.shape) < 0.05] = 0.0
+    x[rng.random(x.shape) < 0.02] = np.inf
+    x[rng.random(x.shape) < 0.02] = -np.inf
+    nans = rng.random(x.shape) < 0.03
+    x.view(np.uint32)[nans] = (
+        0x7FC00000 | rng.integers(1, 1 << 22, int(nans.sum()))
+    ).astype(np.uint32)
+    x[:, 0, 0] = -0.0  # a sum of nothing but -0.0 stays -0.0
+    want = np.asarray(jax.jit(dist.LocalFoldCtx().fold_blocks)(
+        jnp.asarray(x)))
+    mesh = M.make_mesh(jax.devices()[:ns])
+    spec = P(M.AXIS_SHARD, None, None)
+    got = np.asarray(shard_map(
+        lambda parts: dist.ShardFoldCtx(ns).fold_blocks(parts)[None],
+        mesh=mesh, in_specs=(spec,), out_specs=spec, check_vma=False,
+    )(jax.device_put(jnp.asarray(x), NamedSharding(mesh, spec))))
+    assert got.shape == (ns, g, nb)
+    assert np.signbit(want[0, 0]) and want[0, 0] == 0.0
+    assert np.isnan(want).any() and np.isinf(want).any()
+    for shard in range(ns):
+        assert np.array_equal(_bits(got[shard]), _bits(want)), shard
 
 
 def test_distributed_double_groupby_matches_single(mesh8, rng):

@@ -315,16 +315,11 @@ MESH_QUERIES = [
 ]
 
 
-@pytest.mark.parametrize("kernel", ["xla", "pallas"])
 @pytest.mark.parametrize("q", MESH_QUERIES)
-def test_the_mesh_twin_is_one_call_too(inst, devices, q, kernel):
+def test_the_mesh_twin_is_one_call_too(inst, devices, q):
     from greptimedb_tpu.parallel import mesh as M
 
     opts = M.MeshOptions(shard_min_series=1, shard_min_rows=1)
-    if kernel == "pallas":
-        opts = M.MeshOptions(shard_min_series=1, shard_min_rows=1,
-                             pallas_kernels="on", pallas_min_series=1,
-                             pallas_min_rows=1)
     inst.query_engine = QueryEngine(prefer_device=False)
     rh = inst.sql(q)
     em = QueryEngine(prefer_device=True, mesh=M.make_mesh(devices),

@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from greptimedb_tpu.instance import Standalone
+from greptimedb_tpu.query.readback import readback_bytes
 from greptimedb_tpu.query.result_cache import ResultCache
 from greptimedb_tpu.query import sessions as sessions_mod
 from greptimedb_tpu.session import QueryContext
@@ -243,16 +244,19 @@ def test_since_range_device_delta_readback(dev_inst):
     _seed(inst, rows=60)
     q = ("select ts, host, avg(v) range '10s' from t "
          "align '10s' by (host) order by ts, host")
+    f0 = readback_bytes("full")
     full = inst.sql(q).rows()
     assert inst.query_engine.last_exec_path == "device"
+    full_bytes = readback_bytes("full") - f0
     cut = sorted({r[0] for r in full})[len({r[0] for r in full}) // 2]
-    d0 = _counter("gtpu_readback_bytes_total", "delta")
+    d0 = readback_bytes("delta")
     s0 = _counter("gtpu_session_hits_total")
     ctx = QueryContext()
     ctx.extensions["since_ms"] = cut
     delta = inst.sql(q, ctx).rows()
     assert delta == [r for r in full if r[0] > cut]
-    assert _counter("gtpu_readback_bytes_total", "delta") > d0
+    # only the steps past the watermark crossed back to the host
+    assert 0 < readback_bytes("delta") - d0 < full_bytes
     # the repeated shape reused the session-resident result buffer
     assert _counter("gtpu_session_hits_total") > s0
 
