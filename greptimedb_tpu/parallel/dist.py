@@ -164,6 +164,11 @@ class LocalFoldCtx:
     def sid_base(self, s_local: int):
         return jnp.int32(0)
 
+    def sids(self, s_local: int):
+        """Absolute series id of each local row: the (ts, sid) tie-break
+        of first/last folds must not depend on how the rows got here."""
+        return self.sid_base(s_local) + jnp.arange(s_local, dtype=jnp.int32)
+
     def gather(self, partial):
         return partial
 
@@ -177,6 +182,17 @@ class LocalFoldCtx:
 
     def psum(self, x):
         return x
+
+
+class RowsFoldCtx(LocalFoldCtx):
+    """LocalFoldCtx over rows gathered from the plane: row i is series
+    `row_sids[i]`, not series i."""
+
+    def __init__(self, row_sids):
+        self.row_sids = row_sids
+
+    def sids(self, s_local: int):
+        return self.row_sids
 
 
 class ShardFoldCtx(LocalFoldCtx):

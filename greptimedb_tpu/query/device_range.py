@@ -24,6 +24,30 @@ window and, beside it, the selected rows' exact extent and which series
 hold any, and the host trims to the exact window after the readback (a
 step's value depends on its absolute index alone).
 
+Two programs share that body (`_selection_extent`, `_range_body`), and
+one test chooses between them before the dispatch, on a number the
+host already holds: `len(sids) <= _ROWS_MAX` of the series the tag
+index matched.
+
+- the rows program (`program_rows`): a selective query. One host
+  argument, int32[3 + 2*Kb]: (delta, lo, hi), the K matched sids padded
+  to the bucket Kb, their group ids. The program reads those rows of
+  every plane ([Kb, NB] each; a dynamic slice a row up to
+  `_ROW_SLICES_MAX` rows on one device, a gather beyond and on a mesh)
+  and runs the body on them; a fresh window
+  reads back one packed buffer (the result, the extent, Kb activity
+  flags). Nothing is placed on the device ahead of a call. On a mesh
+  the gathered rows are replicated and every device does one device's
+  arithmetic.
+- the plane programs (`program`, and on a mesh its shard_map twin): no
+  matcher, or more than `_ROWS_MAX` matched series. Two host arguments,
+  the (S,) group ids (unmatched series routed past g; device-resident
+  from a selection's second dispatch) and int32[3]; the body masks all
+  S series; three outputs in one readback.
+
+No knob chooses: `_ROW_BUCKETS` are module constants, the bucket is the
+last member of the static program spec.
+
 Cache design:
 - one `_Entry` per (table, resolution, phase); holds (S, NB) device arrays
   of per-cell partial aggregate states per field: {s, n, s2, mn, mx, vl/tl,
@@ -74,6 +98,17 @@ _WINDOW_TRIM = global_registry.counter(
     "trimmed to the rows' exact extent after the readback",
     labels=("trimmed",),
 )
+
+# which program a query's selection took: the rows the tag index
+# matched, gathered (at or under _ROWS_MAX matched series), or the whole
+# plane, masked
+_SELECTION = global_registry.counter(
+    "gtpu_range_selection_total",
+    "device RANGE queries by what the range program was called on: the "
+    "rows the tag index matched, or the whole series plane",
+    labels=("path",),
+)
+_TOOK_ROWS, _TOOK_PLANE = _SELECTION.labels("rows"), _SELECTION.labels("plane")
 
 DEVICE_THRESHOLD = 262_144       # min table rows before the cache pays off
 _CELL_CAP = 256 * 1024 * 1024    # max S*NB cells per cached array (1GB f32)
@@ -926,11 +961,12 @@ def _program_specs_path(entry: _Entry, region) -> str:
             f"programs_{entry.res}_{entry.phase}.json")
 
 
-# what a persisted spec list was written for: the fused program's
-# signature and spec meaning (n_steps and g of the BOUND window and the
-# MATCHED series). A file without it predates the fused program: its
-# specs name programs no query would ask for, so it is skipped.
-_SPECS_SIGNATURE = "fused-1"
+# what a persisted spec list was written for: the fused programs'
+# signatures and spec meaning (n_steps and g of the BOUND window and the
+# MATCHED series, and the rows program's bucket: "fused-2"). A file of
+# another signature names programs no query would ask for, so it is
+# skipped.
+_SPECS_SIGNATURE = "fused-2"
 
 
 def _persist_program_specs(entry: _Entry, table) -> None:
@@ -948,8 +984,8 @@ def _persist_program_specs(entry: _Entry, table) -> None:
     specs = list(entry.program_specs)[-8:]
     doc = {"signature": _SPECS_SIGNATURE, "specs": [
         {"stride": st, "n_steps": ns, "g": g, "fold": fo,
-         "nanenc": ne, "items": [list(it) for it in items]}
-        for st, ns, g, fo, ne, items in specs
+         "nanenc": ne, "items": [list(it) for it in items], "rows": kb}
+        for st, ns, g, fo, ne, items, kb in specs
     ]}
     try:
         region.store.write(
@@ -1070,15 +1106,23 @@ def _precompile_loop(entry, doc, entry_mesh, zero_sid):
                     d[bk] = entry.fields[fname][bk]
             if not usable:
                 continue
+            kb = int(s["rows"])
             spec = (int(s["stride"]), int(s["n_steps"]), int(s["g"]),
-                    bool(s["fold"]), bool(s["nanenc"]), items)
+                    bool(s["fold"]), bool(s["nanenc"]), items, kb)
             # select the program the way execute_range_device will, so
             # the warm compile is the one that actually serves queries
             # (sharded entries use the shard_map twin except for
-            # affordable blocked folds)
+            # affordable blocked folds), and pass the kind of argument
+            # a query passes
+            window = np.array([0, -(2**31) + 1, 2**31 - 1], np.int32)
+            inputs = (zero_sid, window)
             program = get_program()
             prog_tag = "single" if entry_mesh is None else "auto_spmd"
-            if entry_mesh is not None and (
+            if kb:
+                program, prog_tag = get_rows_program(entry_mesh)
+                inputs = (np.concatenate(
+                    [window, np.zeros(2 * kb, np.int32)]),)
+            elif entry_mesh is not None and (
                 not spec[3]
                 or _fold_blocks(spec[2], entry.nb,
                                 entry.num_series) != 1
@@ -1095,9 +1139,7 @@ def _precompile_loop(entry, doc, entry_mesh, zero_sid):
                     "range", key=("range", prog_tag, spec)) as dcall:
                 out = dcall.run(
                     program,
-                    arrs, entry.nrow, entry.imin, entry.imax,
-                    zero_sid,
-                    np.array([0, -(2**31) + 1, 2**31 - 1], np.int32),
+                    arrs, entry.nrow, entry.imin, entry.imax, *inputs,
                     spec=spec,
                 )
                 jax.block_until_ready(out)
@@ -1501,8 +1543,7 @@ def _fold_groups(op, state, gid, g, jnp, ctx):
     # segment_sum — exact for any float value incl. ±inf/NaN.
     def fold_extreme(v_arr, t_arr, pick_max):
         has = state["n"] > 0
-        sid = (ctx.sid_base(s_local)
-               + jnp.arange(s_local, dtype=jnp.int32))[:, None]
+        sid = ctx.sids(s_local)[:, None]
         seg_ext = jax.ops.segment_max if pick_max else jax.ops.segment_min
         t_id = -1 if pick_max else _I32_MAX
         t = jnp.where(has, t_arr, t_id)
@@ -1563,14 +1604,16 @@ def _disjoint_reduce(op, state, n_steps, w, jnp):
 
 def _range_body(arrs, gid, sid_mask, delta, lo, hi, spec, ctx):
     """One RANGE query over (local) cell-state grids. spec =
-    (stride, n_steps, g, fold, nanenc, items), items (op, w, field_key)
-    — everything shape-determining static. Shared verbatim by the
-    single-device program and each shard_map shard (the fold ctx is the
-    only difference), so sharded == unsharded bit-for-bit."""
+    (stride, n_steps, g, fold, nanenc, items, rows), items (op, w,
+    field_key) — everything shape-determining static; `rows` is the
+    bucket of the rows program, 0 for the plane programs. Shared
+    verbatim by the single-device program, each shard_map shard and the
+    rows program (the fold ctx is the only difference), so sharded ==
+    unsharded bit-for-bit."""
     import jax
     import jax.numpy as jnp
 
-    stride, n_steps, g, fold, nanenc, items = spec
+    stride, n_steps, g, fold, nanenc, items = spec[:6]
     vals_out = []
     pres_out = []
     nb = next(iter(next(iter(arrs.values())).values())).shape[1]
@@ -1583,7 +1626,9 @@ def _range_body(arrs, gid, sid_mask, delta, lo, hi, spec, ctx):
         state["n"] = jnp.where(
             cmask[None, :] & sid_mask[:, None], raw["n"], 0
         )
-        for bk, ck in (("s", "s"), ("s2", "s2"), ("mn", "m"), ("mx", "m"),
+        # a field's planes hold what every item on it needs: min reads
+        # "mn" also where a max beside it brought "mx"
+        for bk, ck in (("s", "s"), ("s2", "s2"), (_ck_to_bk("m", op), "m"),
                        ("vl", "vl"), ("il", "il"), ("vf", "vf"),
                        ("if", "if")):
             if bk in raw and ck in _STATE_COMBINE.get(op, ()):
@@ -1715,6 +1760,102 @@ def get_sharded_program(mesh):
     return _SHARDED_RANGE.get(mesh)
 
 
+# A selective query works on the rows the tag index matched: at or
+# under _ROWS_MAX matched series the rows program gathers them and runs
+# the plane programs' body on [bucket, NB] rows; past it, or with no
+# matcher, a plane program masks all S. len(sids) alone chooses, before
+# the dispatch; one compile a bucket. Where the crossover lies on the
+# chip: PERF.md section 6, PR 32.
+_ROW_BUCKETS = (8, 64)
+_ROWS_MAX = _ROW_BUCKETS[-1]
+# up to this many rows one device reads each with a dynamic slice: the
+# chip's compiler then moves K rows, where for a gather of rows it first
+# prefetches every plane whole (0.15 of the program's 0.18 ms at 4,000
+# series: PERF.md section 6, PR 32), as it does from 64 slices on too
+_ROW_SLICES_MAX = 8
+
+
+def _make_rows_program(mesh):
+    """The range program over K gathered rows. One host argument: `vec`
+    int32[3 + 2*Kb] = (delta, lo, hi), the matched sids ascending and
+    padded to the bucket Kb, their group ids (padding routed to g). Two
+    outputs: `out` (fold=False: cut to the g matched rows), which stays
+    on the device for the session and for `since` polls, and `packed`,
+    one flat int32 of `out`'s bits, the extent and the Kb activity
+    flags: all a query of an unknown window reads back. int32, not
+    float32: an extent's small numbers are denormals and its -1 a NaN
+    as float bits, which no integer copy can touch.
+
+    On a mesh the gathered rows are replicated and the body runs on
+    every device with the local fold ctx: the same arithmetic as on one
+    device, whatever the mesh size."""
+    import jax
+    import jax.numpy as jnp
+
+    from greptimedb_tpu.parallel.dist import RowsFoldCtx
+
+    replicated = None
+    if mesh is not None:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        replicated = NamedSharding(mesh, P())
+
+    @functools.partial(jax.jit, static_argnames=("spec",))
+    def program_rows(arrs, nrow, imin, imax, vec, *, spec):
+        g, fold, kb = spec[2], spec[3], spec[6]
+        delta, lo, hi = vec[0], vec[1], vec[2]
+        sids = vec[3:3 + kb]
+        gid = vec[3 + kb:3 + 2 * kb]
+
+        def take(plane):
+            if replicated is None and kb <= _ROW_SLICES_MAX:
+                # a dynamic slice clamps its start as mode="clip" does
+                return jnp.concatenate([
+                    jax.lax.dynamic_slice_in_dim(plane, sids[i], 1)
+                    for i in range(kb)])
+            rows = jnp.take(plane, sids, axis=0, mode="clip")
+            if replicated is None:
+                return rows
+            # a gather it is on a mesh at any K: auto-SPMD makes it
+            # masked local gathers and an all-reduce, where it would
+            # all-gather every plane for the slices
+            return jax.lax.with_sharding_constraint(rows, replicated)
+
+        arrs, nrow, imin, imax = jax.tree_util.tree_map(
+            take, (arrs, nrow, imin, imax))
+        sid_mask = gid < g
+        sid_active, extent = _selection_extent(nrow, imin, imax,
+                                               sid_mask, lo, hi)
+        out = _range_body(arrs, gid, sid_mask & sid_active, delta, lo,
+                          hi, spec, RowsFoldCtx(sids))
+        if not fold:
+            out = out[:, :g]
+        packed = jnp.concatenate([
+            jax.lax.bitcast_convert_type(out, jnp.int32).ravel(),
+            extent, sid_active.astype(jnp.int32),
+        ])
+        return out, packed
+
+    return program_rows
+
+
+_ROWS_RANGE = ProgramCache(_make_rows_program)
+
+
+def get_rows_program(mesh=None):
+    """-> (program, its tag in the registry key: mesh twins never share
+    a registry row)."""
+    return _ROWS_RANGE.get(mesh), "rows" if mesh is None else "rows_mesh"
+
+
+def _unpack_rows(packed: np.ndarray, shape: tuple, k: int):
+    """`packed` of the rows program on the host -> (out, sid_active of
+    the K matched rows, extent)."""
+    n = math.prod(shape)
+    out = packed[:n].view(np.float32).reshape(shape)
+    return out, packed[n + 4:n + 4 + k] != 0, packed[n:n + 4]
+
+
 _STATE_COMBINE = {
     "count": (),
     "sum": ("s",), "mean": ("s",),
@@ -1746,51 +1887,92 @@ def get_program():
 # orchestration
 # ----------------------------------------------------------------------
 
-def _group_ids_from_sids(plan, registry, matched: np.ndarray):
-    """Per-sid group ids over the entry's series space, from the series
-    the matchers selected (no device result is needed: which of them
-    hold a row in the query's span is known only after the readback,
-    and groups with none are dropped then). Returns (gid_full (S,)
-    int32 with unmatched sids routed past g, g, key_cols). Mirrors
-    executor.QueryEngine._group_ids but derives groups from sids
-    instead of rows (same decoded key values, possibly different group
-    order — assembly sorts deterministically)."""
+def _group_ids(plan, registry, sids: np.ndarray):
+    """Group ids of the matched series `sids` (ascending) by the BY
+    keys' tag codes (no device result is needed: which of them hold a
+    row in the query's span is known only after the readback, and
+    groups with none are dropped then). Returns (gid (K,) int32, g,
+    key_cols). Mirrors executor.QueryEngine._group_ids but derives
+    groups from sids instead of rows (same decoded key values, possibly
+    different group order — assembly sorts deterministically)."""
     from greptimedb_tpu.query.expr import Col
 
-    S = len(matched)
-    act_idx = np.nonzero(matched)[0]
     if not plan.keys:
-        gid_full = np.full(S, 1, np.int32)
-        gid_full[act_idx] = 0
-        return gid_full, 1, {}
+        return np.zeros(len(sids), np.int32), 1, {}
     code_cols = []
     vocabs = []
     cards = []
     for k in plan.keys:
         name = k.expr.name
-        codes = registry.tag_codes(name).astype(np.int64)
-        vocab = np.asarray(
-            registry.dicts[registry.tag_names.index(name)].values,
-            dtype=object,
-        )
-        code_cols.append(codes)
+        code_cols.append(registry.tag_codes(name)[sids].astype(np.int64))
+        vocab = registry.dicts[registry.tag_names.index(name)].values
         vocabs.append(vocab)
         cards.append(max(len(vocab), 1))
     combined = code_cols[0].copy()
     for codes, card in zip(code_cols[1:], cards[1:]):
         combined = combined * card + codes
-    uniq, inv = np.unique(combined[act_idx], return_inverse=True)
+    uniq, inv = np.unique(combined, return_inverse=True)
     g = len(uniq)
-    gid_full = np.full(S, g, np.int32)
-    gid_full[act_idx] = inv.astype(np.int32)
     key_cols = {}
     rem = uniq
     for i in range(len(code_cols) - 1, -1, -1):
         card = cards[i]
         code_i = rem % card
         rem = rem // card
-        key_cols[plan.keys[i].key] = Col(vocabs[i][code_i])
-    return gid_full, g, key_cols
+        # decode the g groups' values alone: the dictionary holds every
+        # value of the tag, a selective query a few of them
+        col = np.empty(g, dtype=object)
+        col[:] = [vocabs[i][c] for c in code_i.tolist()]
+        key_cols[plan.keys[i].key] = Col(col)
+    return inv.astype(np.int32), g, key_cols
+
+
+def _plane_selection(plan, entry: _Entry, sids) -> dict:
+    """A selection as a plane program takes it: group ids over the
+    entry's whole series axis, unmatched series routed to g (the
+    program reads the series mask off them). `sids` None: no matcher,
+    every series the registry knows (the padded tail of the series axis
+    has no tags)."""
+    if sids is None:
+        sids = np.arange(min(entry.registry.num_series, entry.num_series))
+    gid, g, key_cols = _group_ids(plan, entry.registry, sids)
+    gid_full = np.full(entry.num_series, g, np.int32)
+    gid_full[sids] = gid
+    # identity grouping (each real series is its own group, padded tail
+    # routed past g) needs no fold: the per-series state IS the group
+    # state. num_series is FOLD_BLOCKS-padded, so compare the real
+    # prefix, not the whole axis.
+    fold = not (g <= entry.num_series
+                and np.array_equal(gid_full[:g], np.arange(g))
+                and (gid_full[g:] == g).all())
+    return {
+        "rows": 0, "gid_host": gid_full,
+        # the device-resident copy, for the dispatches after the
+        # selection's first
+        "gid": None, "dispatched": False,
+        "g": g, "key_cols": key_cols, "fold": fold, "windows": {},
+    }
+
+
+def _rows_selection(plan, entry: _Entry, sids) -> dict:
+    """A selection as the rows program takes it: the K matched sids and
+    their group ids, padded to the bucket (padding reads row 0, routed
+    to g), ready to follow (delta, lo, hi) in the call's one vector.
+    Nothing of it is ever placed on the device ahead of a call."""
+    k = len(sids)
+    kb = next(b for b in _ROW_BUCKETS if k <= b)
+    gid, g, key_cols = _group_ids(plan, entry.registry, sids)
+    tail = np.zeros(2 * kb, np.int32)
+    tail[:k] = sids
+    tail[kb:] = g
+    tail[kb:kb + k] = gid
+    return {
+        "rows": kb, "tail": tail, "gid_host": gid, "gid": None,
+        "g": g, "key_cols": key_cols,
+        # each matched row its own group, in order: no fold
+        "fold": not (g == k and np.array_equal(gid, np.arange(k))),
+        "windows": {},
+    }
 
 
 _MEMO_MAX = 32      # selections an entry remembers; windows a selection does
@@ -2025,36 +2207,15 @@ def execute_range_device(engine, plan, table):
         memo = entry.query_memo.get(sel_key)
         if memo is None:
             sel_span.attributes["memo"] = "miss"
-            if sids is None:
-                # no matcher: every series the registry knows (the
-                # padded tail of the series axis has no tags)
-                sid_mask = np.arange(entry.num_series) < \
-                    entry.registry.num_series
+            if sids is not None:
+                sids = sids[sids < entry.num_series]
+            if sids is not None and len(sids) <= _ROWS_MAX:
+                memo = _rows_selection(plan, entry, sids)
             else:
-                sid_mask = np.zeros(entry.num_series, bool)
-                sid_mask[sids[sids < entry.num_series]] = True
-            gid_full, g, key_cols = _group_ids_from_sids(
-                plan, entry.registry, sid_mask
-            )
-            # identity grouping (each real series is its own group,
-            # padded tail routed past g) needs no fold: the per-series
-            # state IS the group state. num_series is
-            # FOLD_BLOCKS-padded, so compare the real prefix, not the
-            # whole axis.
-            fold = not (g <= entry.num_series
-                        and np.array_equal(gid_full[:g], np.arange(g))
-                        and (gid_full[g:] == g).all())
-            memo = {
-                # unmatched series are routed to g: the program reads
-                # the series mask off the group ids
-                "gid_host": gid_full,
-                # the device-resident copy, for the dispatches after
-                # the selection's first
-                "gid": None, "dispatched": False,
-                "g": g, "key_cols": key_cols, "fold": fold,
-                "windows": {},
-            }
+                memo = _plane_selection(plan, entry, sids)
             _memo_put(entry.query_memo, sel_key, memo)
+        kb = memo["rows"]
+        (_TOOK_ROWS if kb else _TOOK_PLANE).inc()
         win_key = (lo_c, hi_c)
         win = memo["windows"].get(win_key)
     # the program for this shape and its inputs: state planes, spec,
@@ -2082,7 +2243,11 @@ def execute_range_device(engine, plan, table):
         program = get_program()
         prog_tag = "single"
         entry_mesh = getattr(entry, "mesh", None)
-        if entry_mesh is not None:
+        if kb:
+            # chosen by K alone, on a mesh too: the gathered rows are
+            # replicated, so every mesh size does one device's arithmetic
+            program, prog_tag = get_rows_program(entry_mesh)
+        elif entry_mesh is not None:
             if (not memo["fold"]
                     or _fold_blocks(g, entry.nb, entry.num_series) != 1):
                 # explicit-collective shard_map program with the blocked
@@ -2096,7 +2261,8 @@ def execute_range_device(engine, plan, table):
                 # DOCUMENTED bit-identity exception; surface it
                 stats.note("mesh_fold_range", "auto_spmd(oversized_fold)")
                 prog_tag = "auto_spmd"
-        prog_spec = (stride, n_steps_b, g, memo["fold"], nanenc, prog_items)
+        prog_spec = (stride, n_steps_b, g, memo["fold"], nanenc, prog_items,
+                     kb)
         from greptimedb_tpu.query import readback, sessions
         from greptimedb_tpu.telemetry import device_trace
 
@@ -2156,43 +2322,64 @@ def execute_range_device(engine, plan, table):
     with stats.timed("device_exec_ms"), \
             device_trace.device_call(
                 "range", key=("range", prog_tag, prog_spec),
-                groups=g, steps=n_steps_b) as dcall:
+                groups=g, steps=n_steps_b, rows=kb) as dcall:
         extras = ()
-        if out_dev is not None:
-            stats.note("device_session", "hit")
-            dcall.executed()
-        else:
-            stats.note("device_session", "miss")
-            gid_in, uploaded = _selection_gid(memo, entry_mesh)
-            # the three scalars are one NumPy value too: the call
-            # uploads it, in one transfer
-            out_dev, act_dev, extent_dev = dcall.run(
-                program,
-                arrs, entry.nrow, entry.imin, entry.imax,
-                gid_in, np.array([delta, lo_c, hi_c], np.int32),
-                spec=prog_spec,
-            )
-            if uploaded:
-                dcall.transfer(uploaded, "upload")
-            out_dev.block_until_ready()
-            dcall.executed()
-            if use_sessions:
-                sessions.global_sessions.put(
-                    session_tkey, session_key, entry.version, out_dev,
-                    int(out_dev.nbytes),
+        dispatch = out_dev is None
+        stats.note("device_session", "miss" if dispatch else "hit")
+        if dispatch:
+            window = np.array([delta, lo_c, hi_c], np.int32)
+            if kb:
+                # the call's one host argument, uploaded with it
+                vec = np.concatenate([window, memo["tail"]])
+                out_dev, packed_dev = dcall.run(
+                    program,
+                    arrs, entry.nrow, entry.imin, entry.imax, vec,
+                    spec=prog_spec,
                 )
-            if win is None:
-                extras = (act_dev, extent_dev)
-        # fold=False leaves the series axis un-folded: rows [g:] are
-        # the padded/inactive tail (fold=True already has exactly g
-        # rows). Both slices happen on the DEVICE array, so a delta
-        # poll reads back only the unseen steps, and every output of
-        # the program crosses in ONE readback
-        # (readback.read_outputs feeds
-        # gtpu_readback_bytes_total{mode=full|delta}).
-        sliced = out_dev if memo["fold"] else out_dev[:, :g]
-        out, *extras = readback.read_outputs(sliced, j0_b, extras, axis=-1)
-        readback_bytes = out.nbytes + sum(x.nbytes for x in extras)
+                dcall.transfer(vec.nbytes, "upload")
+            else:
+                gid_in, uploaded = _selection_gid(memo, entry_mesh)
+                # the three scalars are one NumPy value too: the call
+                # uploads it, in one transfer
+                out_dev, act_dev, extent_dev = dcall.run(
+                    program,
+                    arrs, entry.nrow, entry.imin, entry.imax,
+                    gid_in, window,
+                    spec=prog_spec,
+                )
+                if uploaded:
+                    dcall.transfer(uploaded, "upload")
+                if win is None:
+                    extras = (act_dev, extent_dev)
+            out_dev.block_until_ready()
+        dcall.executed()
+        if dispatch and use_sessions:
+            sessions.global_sessions.put(
+                session_tkey, session_key, entry.version, out_dev,
+                int(out_dev.nbytes),
+            )
+        if kb and win is None:
+            # a window the memo does not know (so this call dispatched),
+            # on the rows path: `packed` holds all of it, one array in
+            # one crossing; the cursor's steps are cut on the host (K
+            # rows: nothing to save)
+            (packed,) = readback.read_outputs(packed_dev, 0)
+            out, *extras = _unpack_rows(packed, out_dev.shape,
+                                        len(memo["gid_host"]))
+            out = out[..., j0_b:]
+            readback_bytes = packed.nbytes
+        else:
+            # fold=False leaves the plane programs' series axis
+            # un-folded: rows [g:] are the padded/inactive tail (fold=True
+            # and the rows program give exactly g rows). Both slices
+            # happen on the DEVICE array, so a delta poll reads back only
+            # the unseen steps, and every output of the program crosses
+            # in ONE readback (readback.read_outputs feeds
+            # gtpu_readback_bytes_total{mode=full|delta}).
+            sliced = out_dev if kb or memo["fold"] else out_dev[:, :g]
+            out, *extras = readback.read_outputs(sliced, j0_b, extras,
+                                                 axis=-1)
+            readback_bytes = out.nbytes + sum(x.nbytes for x in extras)
         dcall.transfer(readback_bytes, "readback")
         if win is None:
             win = _fold_window(entry, memo, *extras)

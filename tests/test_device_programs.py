@@ -83,6 +83,14 @@ RANGE_Q = ("SELECT ts, host, avg(v) RANGE '1h' FROM cpu "
            "ALIGN '1h' BY (host)")
 
 
+# the same panel over one host: the tag index matches one series, the
+# rows program runs (query/device_range.py), under its own registry key
+ROWS_Q = RANGE_Q.replace("FROM cpu", "FROM cpu WHERE host = 'h1'")
+RANGE_QS = pytest.mark.parametrize(
+    "q,tag", [(RANGE_Q, "'single'"), (ROWS_Q, "'rows'")],
+    ids=["plane", "rows"])
+
+
 def _rows_by_site(registry, *, analyze=False):
     out = {}
     for d in registry.snapshot(analyze=analyze):
@@ -94,16 +102,20 @@ def _rows_by_site(registry, *, analyze=False):
 # registry folding across the device call sites
 # ---------------------------------------------------------------------------
 
-def test_range_site_folds_one_row_with_calls_accumulating(inst, registry):
+@RANGE_QS
+def test_range_site_folds_one_row_with_calls_accumulating(inst, registry,
+                                                          q, tag):
     _seed(inst)
     for _ in range(4):
-        inst.sql(RANGE_Q)
+        inst.sql(q)
     assert inst.query_engine.last_exec_path == "device"
     sites = _rows_by_site(registry)
     # ONE row per compiled program, calls accumulating across polls
     assert len(sites["range"]) == 1
     row = sites["range"][0]
     assert row["calls"] == 4
+    # the key says which range program ran
+    assert tag in row["key"]
     assert row["compile_ms"] > 0          # first call = compile
     assert row["execute_p50_ms"] > 0      # 3 steady-state samples
     assert row["readback_bytes"] > 0
@@ -199,7 +211,8 @@ def test_flow_sites_fold(tmp_path, registry, no_sessions):
         s.close()
 
 
-def test_session_hit_does_not_count_a_dispatch(tmp_path, registry):
+@RANGE_QS
+def test_session_hit_does_not_count_a_dispatch(tmp_path, registry, q, tag):
     """With sessions ON, the warm poll serves the HBM-resident buffer
     without dispatching — the registry counts real dispatches only."""
     s = Standalone(str(tmp_path / "data"), prefer_device=True,
@@ -207,7 +220,7 @@ def test_session_hit_does_not_count_a_dispatch(tmp_path, registry):
     try:
         _seed(s)
         for _ in range(3):
-            s.sql(RANGE_Q)
+            s.sql(q)
         row = _rows_by_site(DP.global_programs)["range"][0]
         assert row["calls"] == 1  # cold dispatch only
     finally:
@@ -218,10 +231,11 @@ def test_session_hit_does_not_count_a_dispatch(tmp_path, registry):
 # XLA analysis + roofline
 # ---------------------------------------------------------------------------
 
-def test_analysis_and_roofline_verdict(inst, registry):
+@RANGE_QS
+def test_analysis_and_roofline_verdict(inst, registry, q, tag):
     _seed(inst)
     for _ in range(3):
-        inst.sql(RANGE_Q)
+        inst.sql(q)
     # default CPU config: achieved-only (no peaks -> no verdict)
     docs = registry.snapshot()  # triggers the lazy analysis
     rng_row = [d for d in docs if d["site"] == "range"][0]

@@ -52,6 +52,22 @@ def inst(tmp_path):
     i.close()
 
 
+@pytest.fixture(params=["plane", "rows"])
+def path(request, monkeypatch):
+    """Which range program a small selection takes: the rows program
+    (K matched series gathered, what `len(sids) <= _ROWS_MAX` chooses),
+    or, every selection counting as past `_ROWS_MAX`, the plane program
+    with the contract it had before the rows program existed."""
+    if request.param == "plane":
+        monkeypatch.setattr(DR, "_ROWS_MAX", -1)
+    return request.param
+
+
+def _took(path_name: str) -> float:
+    return global_registry.get(
+        "gtpu_range_selection_total").labels(path_name).value
+
+
 def _compare(rh, rd, q):
     assert rh.names == rd.names
     assert rh.num_rows == rd.num_rows, q
@@ -96,7 +112,7 @@ FILLS = ["", "FILL PREV", "FILL LINEAR", "FILL NULL", "FILL 7.5"]
 
 
 @pytest.mark.parametrize("fill", FILLS)
-def test_rows_inside_the_where_bounds_are_trimmed_to(inst, fill):
+def test_rows_inside_the_where_bounds_are_trimmed_to(inst, fill, path):
     """The WHERE admits the whole grid (100 s to 500 s), h7's rows span
     300 s to 400 s: the program's window is the wider one, the answer
     the exact one, and FILL sees the exact one."""
@@ -105,10 +121,13 @@ def test_rows_inside_the_where_bounds_are_trimmed_to(inst, fill):
          "WHERE host = 'h7' AND ts >= 0 AND ts < 900000 ALIGN '10s' "
          "BY (host) ORDER BY ts, host")
     yes0, _ = _trims()
+    took0 = _took(path)
     rh, rd, dev = _both(inst, q)
     _compare(rh, rd, q)
     assert rd.num_rows > 0
     assert [a["site"] for a in dev] == ["range"]
+    assert _took(path) == took0 + 1
+    assert dev[0]["rows"] == (8 if path == "rows" else 0)
     # the bound window's steps run from 90 s to 490 s (41), the exact
     # one's from 290 s to 390 s (11): twenty before and ten after go
     assert dev[0]["steps"] == 41 and dev[0]["trimmed_steps"] == 30
@@ -120,7 +139,7 @@ def test_rows_inside_the_where_bounds_are_trimmed_to(inst, fill):
 @pytest.mark.parametrize("fill", ["", "FILL PREV", "FILL 0"])
 @pytest.mark.parametrize("by", ["host", "region"])
 def test_a_matched_series_with_no_row_in_the_span_makes_no_group(
-        inst, fill, by):
+        inst, fill, by, path):
     """h7 (region r1, with h1 and h4) has no row before 300 s: grouped
     by host it makes no group, FILL or not; grouped by region its
     region's group holds only the others' rows."""
@@ -163,7 +182,7 @@ def test_open_sides_take_the_grid_s_own_extent(inst, where):
     ("WHERE host = 'nobody'", 0),
     ("WHERE ts >= 600000 AND ts < 700000", 0),
 ])
-def test_an_empty_selection_is_empty(inst, where, dispatches):
+def test_an_empty_selection_is_empty(inst, where, dispatches, path):
     q = (f"SELECT ts, host, avg(u) RANGE '10s' FILL PREV FROM cpu {where} "
          "ALIGN '10s' BY (host)")
     DP.global_programs.reset()
@@ -178,11 +197,12 @@ def test_an_empty_selection_is_empty(inst, where, dispatches):
         assert _range_calls() == dispatches
 
 
-def test_twenty_fresh_literals_of_one_span_compile_once(inst):
+def test_twenty_fresh_literals_of_one_span_compile_once(inst, path):
     """Host and hour change with every query, the program does not: its
-    spec holds the bound window's steps and the matched groups, the
-    same for every literal of a panel. (The first series alone is its
-    own group with no fold, another spec: h0 is left out.)"""
+    spec holds the bound window's steps and the matched groups (and the
+    rows program's bucket), the same for every literal of a panel. (On
+    the plane the first series alone is its own group with no fold,
+    another spec: h0 is left out.)"""
     inst.query_engine = QueryEngine(prefer_device=True)
     eh = QueryEngine(prefer_device=False)
     DP.global_programs.reset()
@@ -204,10 +224,13 @@ def test_twenty_fresh_literals_of_one_span_compile_once(inst):
     assert compiles.value == compiles0 + 1
     entry = next(iter(inst.query_engine.range_cache._entries.values()))
     assert len(entry.program_specs) == 1
+    (spec,) = entry.program_specs
+    assert spec[6] == (8 if path == "rows" else 0)
+    assert _took(path) >= 20
 
 
 def test_a_session_hit_dispatches_nothing_and_a_since_poll_reads_the_delta(
-        inst):
+        inst, path):
     q = ("SELECT ts, host, avg(v) RANGE '10s' FROM cpu "
          "WHERE host = 'h7' AND ts >= 0 AND ts < 900000 ALIGN '10s' "
          "BY (host) ORDER BY ts, host")
@@ -219,9 +242,11 @@ def test_a_session_hit_dispatches_nothing_and_a_since_poll_reads_the_delta(
     assert [r[0] for r in full] == list(range(H7_T0, H7_T1, STEP))
     assert _range_calls() == 1
     # the bound window's forty steps (100 s to 490 s) of one group, the
-    # active-series mask and int32[4], in one readback
+    # int32[4] and the activity flags, in one readback: a bool a series
+    # of the plane, or an int32 a row of the bucket inside `packed`
     entry = next(iter(inst.query_engine.range_cache._entries.values()))
-    assert rb.labels("full").value - full0 == 4 * 40 + entry.num_series + 16
+    flags = entry.num_series if path == "plane" else 4 * 8
+    assert rb.labels("full").value - full0 == 4 * 40 + 16 + flags
     # the same poll again: the session's buffer, trimmed as before by
     # what the memo kept of the first answer
     full1 = rb.labels("full").value
@@ -246,10 +271,12 @@ def test_a_session_hit_dispatches_nothing_and_a_since_poll_reads_the_delta(
 
 
 def test_one_selection_s_inputs_stay_on_the_device_from_its_second_dispatch(
-        inst):
+        inst, path):
     """A dashboard slides its hour over the same hosts: the group ids
-    (the mask rides in them) are computed once, go with the first call
-    as a NumPy value and are device-resident from the second on."""
+    (the mask rides in them) are computed once; the plane program's go
+    with the first call as a NumPy value and are device-resident from
+    the second on, the rows program's K of them ride every call's one
+    vector and nothing is ever placed on the device."""
     inst.query_engine = QueryEngine(prefer_device=True)
     eh = QueryEngine(prefer_device=False)
     results = []
@@ -268,20 +295,29 @@ def test_one_selection_s_inputs_stay_on_the_device_from_its_second_dispatch(
     (memo,) = entry.query_memo.values()
     assert len(memo["windows"]) == 3
     assert isinstance(memo["gid_host"], np.ndarray)
-    assert memo["gid"] is not None and not isinstance(memo["gid"],
-                                                      np.ndarray)
+    if path == "plane":
+        assert len(memo["gid_host"]) == entry.num_series
+        assert memo["gid"] is not None and not isinstance(memo["gid"],
+                                                          np.ndarray)
+    else:
+        # h1, h2, h4, h5, h7 and their two regions, padded to the bucket
+        assert memo["gid"] is None and memo["rows"] == 8
+        assert memo["tail"].tolist() == [1, 2, 4, 5, 7, 0, 0, 0,
+                                         0, 1, 0, 1, 0, 2, 2, 2]
     inst.query_engine = eh
     for q, rd in results:
         _compare(inst.sql(q), rd, q)
 
 
-def test_persisted_specs_of_another_signature_are_skipped(inst):
-    """A spec file written before the fused program names programs no
-    query asks for: warm-up dispatches none of them."""
+@pytest.mark.parametrize("where,rows", [("", 0), ("WHERE host = 'h1'", 8)])
+def test_persisted_specs_of_another_signature_are_skipped(inst, where, rows):
+    """A spec file written before the fused programs, or before the
+    rows program's bucket rode the spec, names programs no query asks
+    for: warm-up dispatches none of them."""
     import json
 
-    q = ("SELECT ts, host, avg(u) RANGE '10s' FROM cpu ALIGN '10s' "
-         "BY (host)")
+    q = (f"SELECT ts, host, avg(u) RANGE '10s' FROM cpu {where} "
+         "ALIGN '10s' BY (host)")
     inst.query_engine = QueryEngine(prefer_device=True)
     inst.sql(q)
     table = inst.catalog.table("public", "cpu")
@@ -291,14 +327,27 @@ def test_persisted_specs_of_another_signature_are_skipped(inst):
     path = DR._program_specs_path(entry, region)
     DR._persist_program_specs(entry, table)
     doc = json.loads(region.store.read(path))
-    assert doc["signature"] == DR._SPECS_SIGNATURE
+    assert doc["signature"] == DR._SPECS_SIGNATURE == "fused-2"
+    assert [s["rows"] for s in doc["specs"]] == [rows] == [spec[6]]
     DP.global_programs.reset()
     assert DR.precompile_programs(entry, table) == 1
     assert _range_calls() == 1
-    # the list the parent commit wrote: no signature
-    region.store.write(path, json.dumps(doc["specs"]).encode())
-    assert DR.precompile_programs(entry, table) == 0
-    assert _range_calls() == 1
+    # warm-up called the program a query calls, with the kind of
+    # argument a query passes: the next query compiles nothing
+    compiles = global_registry.get(
+        "gtpu_device_program_compiles_total").labels("range")
+    compiles0 = compiles.value
+    inst.sql(q.replace("'h1'", "'h2'"))
+    assert _range_calls() == (2 if rows else 1)   # no matcher: a session hit
+    assert compiles.value == compiles0
+    # the list PR 28's parent wrote (no signature) and the one this
+    # PR's parent wrote (six-member specs under "fused-1")
+    for old in (doc["specs"], {"signature": "fused-1", "specs": [
+            {k: v for k, v in s.items() if k != "rows"}
+            for s in doc["specs"]]}):
+        region.store.write(path, json.dumps(old).encode())
+        assert DR.precompile_programs(entry, table) == 0
+    assert _range_calls() == (2 if rows else 1)
 
 
 MESH_QUERIES = [
@@ -315,9 +364,19 @@ MESH_QUERIES = [
 ]
 
 
-@pytest.mark.parametrize("q", MESH_QUERIES)
-def test_the_mesh_twin_is_one_call_too(inst, devices, q):
+@pytest.mark.parametrize("q,took", [
+    (MESH_QUERIES[0], "plane"), (MESH_QUERIES[1], "plane"),
+    (MESH_QUERIES[1], "rows"), (MESH_QUERIES[2], "plane"),
+])
+def test_the_mesh_twin_is_one_call_too(inst, devices, q, took, monkeypatch):
+    """`host != 'h3'` matches seven series: the rows program on a mesh
+    too, chosen by K alone; the same statement with every selection
+    past `_ROWS_MAX`, and the two with no matcher, take the sharded
+    twin."""
     from greptimedb_tpu.parallel import mesh as M
+
+    if took == "plane":
+        monkeypatch.setattr(DR, "_ROWS_MAX", -1)
 
     opts = M.MeshOptions(shard_min_series=1, shard_min_rows=1)
     inst.query_engine = QueryEngine(prefer_device=False)
@@ -333,12 +392,18 @@ def test_the_mesh_twin_is_one_call_too(inst, devices, q):
     _compare(rh, rm, q)
     rows = [d for d in DP.global_programs.snapshot() if d["site"] == "range"]
     assert len(rows) == 1 and rows[0]["calls"] == 1
-    # the group ids were placed series-sharded, and kept
     (memo,) = entry.query_memo.values()
-    assert len(memo["gid"].devices()) == 8
+    if took == "plane":
+        # the group ids were placed series-sharded, and kept
+        assert len(memo["gid"].devices()) == 8
+    else:
+        # seven sids in the call's vector, nothing placed anywhere
+        assert memo["gid"] is None and memo["rows"] == 8
+        (spec,) = entry.program_specs
+        assert spec[6] == 8
 
 
-def test_query_threads_share_an_entry_s_memo(inst):
+def test_query_threads_share_an_entry_s_memo(inst, path):
     """More query threads than cores, a short switch interval, more
     selections than the memo holds: every answer is the host path's and
     no thread trips over another's eviction."""

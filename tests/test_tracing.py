@@ -254,9 +254,14 @@ def test_explain_analyze_renders_span_tree(tmp_path):
         inst.close()
 
 
-def test_device_spans_on_range_query(tmp_path):
+@pytest.mark.parametrize("where,rows", [
+    ("", 0),                        # no matcher: the plane program
+    ("WHERE host != 'h1'", 8),      # two series matched: their rows
+])
+def test_device_spans_on_range_query(tmp_path, where, rows):
     """prefer_device forces the grid path: the trace carries a
-    device.execute span with compile/execute/readback attribution."""
+    device.execute span with compile/execute/readback attribution, and
+    says which range program ran."""
     pytest.importorskip("jax")
     inst = Standalone(str(tmp_path / "data"), warm_start=False,
                       prefer_device=True)
@@ -268,7 +273,7 @@ def test_device_spans_on_range_query(tmp_path):
             for i in range(30)
         )
         inst.sql(f"INSERT INTO m (host, v, ts) VALUES {vals}")
-        q = ("SELECT ts, host, avg(v) RANGE '10s' FROM m "
+        q = (f"SELECT ts, host, avg(v) RANGE '10s' FROM m {where} "
              "ALIGN '10s' BY (host)")
         with tracing.span("req") as root:
             inst.sql(q)
@@ -283,6 +288,10 @@ def test_device_spans_on_range_query(tmp_path):
         assert attrs["trimmed_steps"] == 0
         assert attrs["compile"] == "first_call"
         assert attrs["readback_bytes"] > 0
+        assert attrs["rows"] == rows
+        # the rows program's one vector rides the call: (delta, lo, hi),
+        # eight sids, eight group ids
+        assert attrs.get("upload_bytes", 0) >= (4 * 19 if rows else 0)
         assert "execute_ms" in attrs
         # the program-profiler link rides the span
         assert attrs.get("program")
@@ -451,12 +460,19 @@ def _finished_trace(trace_id: str) -> list[dict]:
 
 
 @pytest.fixture
-def panel(tmp_path, monkeypatch):
+def panel(tmp_path, monkeypatch, request):
     """A server holding a small `cpu` table on the device path, warm
-    for the panel query's shape; yields (port, query maker)."""
+    for the panel query's shape; yields (port, query maker). The
+    panel's one host takes the rows program; with the parameter
+    "plane" every selection counts as past `_ROWS_MAX` and takes the
+    plane program."""
     pytest.importorskip("jax")
+    from greptimedb_tpu.query import device_range
     from greptimedb_tpu.servers.http import HttpServer
     from greptimedb_tpu.telemetry import stmt_stats
+
+    if getattr(request, "param", "rows") == "plane":
+        monkeypatch.setattr(device_range, "_ROWS_MAX", -1)
 
     inst = Standalone(str(tmp_path / "data"), warm_start=False,
                       prefer_device=True)
@@ -495,8 +511,10 @@ def panel(tmp_path, monkeypatch):
         inst.close()
 
 
-def _stage_tree(port: int, sql: str, tid: str) -> tuple[float, float]:
-    """One request under trace id `tid`, its tree checked; returns the
+def _stage_tree(port: int, sql: str, tid: str, rows: int
+                ) -> tuple[float, float]:
+    """One request under trace id `tid`, its tree checked (`rows`: the
+    bucket of the rows program, 0 for the plane program); returns the
     time its stages cover and its root's."""
     _post_sql(port, sql, traceparent=f"00-{tid}-{'cd' * 8}-01")
     spans = _finished_trace(tid)
@@ -518,6 +536,7 @@ def _stage_tree(port: int, sql: str, tid: str) -> tuple[float, float]:
     dev = [s for s in stages if s["name"] == "device.execute"]
     assert len(dev) == 1 and all("execute_ms" in s["attributes"]
                                  for s in dev)
+    assert dev[0]["attributes"]["rows"] == rows
     for s in dev:
         for gone in ("flops", "roofline_bound", "pct_of_peak",
                      "achieved_gflops"):
@@ -525,15 +544,20 @@ def _stage_tree(port: int, sql: str, tid: str) -> tuple[float, float]:
     return sum(s["duration_ms"] for s in stages), root["duration_ms"]
 
 
-def test_one_request_yields_the_twelve_stages_in_one_tree(panel):
+@pytest.mark.parametrize("panel,rows,covered", [
+    ("plane", 0, 0.9), ("rows", 8, 0.8)], indirect=["panel"])
+def test_one_request_yields_the_twelve_stages_in_one_tree(
+        panel, rows, covered):
     port, query = panel
-    trees = [_stage_tree(port, query(100 + i), f"{i:02x}" * 16)
+    trees = [_stage_tree(port, query(100 + i), f"{i:02x}" * 16, rows)
              for i in range(1, 6)]
     # what the stages leave is the root's (and the statement's) self
-    # time: under a tenth of the request. A request that a collection
-    # or another test's thread falls into reads lower, so the best of
-    # five is held to it
-    assert max(c / r for c, r in trees) >= 0.9, trees
+    # time: under a tenth of the request on the plane program. The rows
+    # program leaves the same self time beside stages half as long (the
+    # device call and the selection no longer grow with the fleet):
+    # under a fifth. A request that a collection or another test's
+    # thread falls into reads lower, so the best of five is held to it
+    assert max(c / r for c, r in trees) >= covered, trees
 
 
 def test_span_time_is_exported_by_name(panel, monkeypatch):
