@@ -21,6 +21,7 @@ Role topology:
 from __future__ import annotations
 
 import argparse
+import gc
 import os
 import signal
 import sys
@@ -231,10 +232,22 @@ def _split(addr: str) -> tuple[str, int]:
     return host or "127.0.0.1", int(port)
 
 
+def _settle_heap():
+    """Start-up is over: the modules, classes, servers and caches it
+    made live as long as the process does. Put them out of the
+    collector's reach, so that a full collection walks what requests
+    make and not, each time, the hundred thousand objects of start-up
+    (46 ms a pass on the chip's host, in every third answer of a
+    query that returns 32,000 rows: PERF.md section 6, PR 33)."""
+    gc.collect()
+    gc.freeze()
+
+
 def _serve_until_signal(closers):
     stop = []
     signal.signal(signal.SIGTERM, lambda *a: stop.append(1))
     signal.signal(signal.SIGINT, lambda *a: stop.append(1))
+    _settle_heap()
     try:
         while not stop:
             time.sleep(0.2)

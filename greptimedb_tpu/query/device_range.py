@@ -2218,6 +2218,11 @@ def execute_range_device(engine, plan, table):
         (_TOOK_ROWS if kb else _TOOK_PLANE).inc()
         win_key = (lo_c, hi_c)
         win = memo["windows"].get(win_key)
+        # a window the selection has not seen brings the rows' extent
+        # and the active series back with the result, and folds them
+        # (`_fold_window`); the key is the WHERE's own bounds, so a
+        # literal that moves outside the data misses all the same
+        sel_span.attributes["window"] = "miss" if win is None else "hit"
     # the program for this shape and its inputs: state planes, spec,
     # mesh variant, the session buffer of a repeated poll
     with tracing.child_span("query.plan", phase="program"):

@@ -49,6 +49,16 @@ _LATENCY = global_registry.histogram(
 _INGEST_ROWS = global_registry.counter(
     "greptime_servers_ingest_rows_total", "Rows ingested", ("api",)
 )
+# what an answer weighs on its way out: a one-host panel is 60 rows and
+# a few KB, a fleet report tens of thousands of rows and MBs of JSON text
+_RESPONSE_BYTES = global_registry.counter(
+    "gtpu_http_response_bytes_total",
+    "HTTP response body bytes written, by route", ("path",)
+)
+_ROWS_RETURNED = global_registry.counter(
+    "gtpu_query_rows_returned_total",
+    "result rows rendered into HTTP answers, by route", ("path",)
+)
 
 
 def _type_name(tn: str) -> str:
@@ -68,14 +78,18 @@ def _type_name(tn: str) -> str:
     return names.get(tn, tn)
 
 
-def result_to_json(res) -> dict:
+def result_to_json(res, route: str) -> dict:
     schema = {
         "column_schemas": [
             {"name": n, "data_type": _type_name(res.type_name(i))}
             for i, n in enumerate(res.names)
         ]
     }
-    return {"records": {"schema": schema, "rows": res.rows(),
+    # a Python list a row, a Python object a value
+    with tracing.child_span("result.rows"):
+        rows = res.rows()
+    _ROWS_RETURNED.labels(route).inc(res.num_rows)
+    return {"records": {"schema": schema, "rows": rows,
                         "total_rows": res.num_rows}}
 
 
@@ -185,7 +199,9 @@ def _make_handler(instance, user_provider=None, *, enable_scripts=False,
                 self.send_header("Content-Length", str(len(body)))
                 self.end_headers()
                 self.wfile.write(body)
-            _REQS.labels(self._route(), str(code)).inc()
+            route = self._route()
+            _REQS.labels(route, str(code)).inc()
+            _RESPONSE_BYTES.labels(route).inc(len(body))
 
         _KNOWN_ROUTES = (
             "/health", "/ready", "/status", "/metrics", "/v1/sql",
@@ -611,7 +627,7 @@ def _make_handler(instance, user_provider=None, *, enable_scripts=False,
             if not name:
                 return self._error(400, "missing name parameter")
             res = self._script_engine().run_script(name)
-            self._json(200, {"output": [result_to_json(res)]})
+            self._json(200, {"output": [result_to_json(res, self._route())]})
 
         # ------------------------------------------------------------------
         def _handle_sql(self):
@@ -683,7 +699,8 @@ def _make_handler(instance, user_provider=None, *, enable_scripts=False,
                 partial = None
                 for o in outputs:
                     if o.result is not None:
-                        out_json.append(result_to_json(o.result))
+                        out_json.append(
+                            result_to_json(o.result, "/v1/sql"))
                         if getattr(o.result, "partial", False):
                             partial = {
                                 "partial": True,
@@ -703,7 +720,8 @@ def _make_handler(instance, user_provider=None, *, enable_scripts=False,
                     # shed-datanode one ([scheduler]
                     # allow_partial_results)
                     doc.update(partial)
-                body = json.dumps(doc).encode()
+                with tracing.child_span("json.dumps"):
+                    body = json.dumps(doc).encode()
             self._send(200, body)
 
         # ------------------------------------------------------------------
