@@ -8,18 +8,25 @@ per tag column, a CSR (offsets, order) pair where order is the stable
 argsort of that column's codes, so the sids for one tag value are a
 contiguous ascending slice.
 
-Matcher evaluation splits into two domains:
+Matcher evaluation splits into two domains, chosen by the matcher's op:
 
-- `eq`/`in` matchers resolve a value to its dictionary code (O(1) hash
-  lookup) and read the posting slice — no per-series work at all.
+- `eq`/`in` matchers resolve each literal to its dictionary code through
+  the dictionary's own hash (Dictionary.lookup, O(1) per literal) and
+  read the posting slices — no pass over the dictionary's values and no
+  per-series work at all. Their accepting set is a small sorted array
+  of codes; a literal the dictionary lacks contributes none.
 - `re`/`nre`/`ne`/`nin` matchers evaluate once per DISTINCT value
-  (series.ok_codes_for — the same code match_mask broadcasts through),
-  then expand the accepting codes through the postings. String/regex
-  cost scales with value cardinality, not series cardinality.
+  (series.ok_codes_for — the same code match_mask broadcasts through):
+  their verdict is a property of every value, so they keep a bool
+  ok-table over the dictionary. String/regex cost scales with value
+  cardinality, not series cardinality.
 
-The most selective matcher (estimated from posting lengths) seeds the
-candidate set; the rest filter candidates by indexing their ok-tables
-with the candidates' codes — O(|candidates|) int work per matcher.
+The most selective matcher (estimated from the posting lengths of its
+accepting codes) seeds the candidate set; the rest filter candidates by
+their codes — an integer compare against the code set, or an index
+into the ok-table — O(|candidates|) int work per matcher.
+`gtpu_index_lookups_total{path}` says which a lookup took: `codes` when
+no ok-table was built, `postings` when at least one was.
 
 Maintenance is incremental and version-validated like the scan cache:
 sids are dense and append-only, so postings built at registry version v
@@ -95,6 +102,9 @@ def _expand_csr(offsets: np.ndarray, order: np.ndarray,
     multi-slice CSR expand — no per-code Python loop)."""
     if len(codes) == 0:
         return np.zeros(0, dtype=np.int32)
+    if len(codes) == 1:
+        c = int(codes[0])
+        return order[offsets[c]:offsets[c + 1]].copy()
     starts = offsets[codes]
     lens = offsets[codes + 1] - starts
     total = int(lens.sum())
@@ -102,6 +112,36 @@ def _expand_csr(offsets: np.ndarray, order: np.ndarray,
         return np.zeros(0, dtype=np.int32)
     pos = np.repeat(starts - (np.cumsum(lens) - lens), lens)
     return order[pos + np.arange(total, dtype=np.int64)]
+
+
+def _lookup_codes(d, op: str, value) -> np.ndarray:
+    """Accepting codes of an eq/in matcher: the codes dictionary `d`
+    holds for the matcher's literals, distinct and ascending (int64).
+    One hash lookup per literal; a literal `d` lacks has no code."""
+    if op == "eq":
+        c = d.lookup(value)
+        found = [] if c is None else [c]
+    else:
+        found = sorted({c for c in map(d.lookup, value) if c is not None})
+    return np.asarray(found, dtype=np.int64)
+
+
+def _posted_codes(cs, ok, nvals: int) -> np.ndarray:
+    """The accepting codes of one matcher form (code set `cs` or
+    ok-table `ok`) among the `nvals` codes the CSR has slices for."""
+    if ok is None:
+        return cs[cs < nvals]
+    return np.flatnonzero(ok[:nvals])
+
+
+def _accepts(cs, ok, c: np.ndarray) -> np.ndarray:
+    """Verdict of one matcher form on a vector of codes: an integer
+    compare against the code set, or an index into the ok-table (codes
+    past the table were interned after it was made: rejected)."""
+    if ok is None:
+        return c == cs[0] if len(cs) == 1 else np.isin(c, cs)
+    safe = np.minimum(c, len(ok) - 1)
+    return ok[safe] & (c < len(ok))
 
 
 class TagIndex:
@@ -162,8 +202,10 @@ class TagIndex:
     # -- lookup --------------------------------------------------------
     def match_sids(self, matchers) -> np.ndarray:
         """Sids satisfying all matchers, ascending int32 — bit-identical
-        to SeriesRegistry.match_sids by construction (same ok-code
-        tables, broadcast through postings instead of the full plane)."""
+        to SeriesRegistry.match_sids (the same verdict per distinct
+        value, reached through the dictionary's hash for eq/in and the
+        shared ok-code tables otherwise, then broadcast through postings
+        instead of the full plane)."""
         from greptimedb_tpu.query import stats
 
         reg = self._reg
@@ -178,14 +220,14 @@ class TagIndex:
                 stats.add("index_lookups", 1)
                 return hit[1]
             self._misses += 1
-        sids = self._eval(matchers, version)
+        sids, path = self._eval(matchers, version)
         with self._lock:
             self._results[key] = (version, sids)
             self._results.move_to_end(key)
             cap = int(_CFG["result_cache_entries"])
             while len(self._results) > max(cap, 1):
                 self._results.popitem(last=False)
-        _count_lookup("postings")
+        _count_lookup(path)
         stats.add("index_lookups", 1)
         return sids
 
@@ -198,79 +240,84 @@ class TagIndex:
         mask[sids[sids < n]] = True
         return mask
 
-    def _eval(self, matchers, version: int) -> np.ndarray:
+    def _eval(self, matchers, version: int) -> tuple[np.ndarray, str]:
+        """Evaluate a matcher set; returns (sids, path) where path is
+        "codes" when every matcher was resolved through its dictionary's
+        hash and "postings" when at least one ok-table was built."""
         reg = self._reg
         codes = reg.codes_matrix()
         n, k = codes.shape
         empty = np.zeros(0, dtype=np.int32)
+        path = "codes"
         if n == 0:
-            return empty
+            return empty, path
         tag_names = reg.tag_names
         dicts = reg.dicts
-        # dictionary-domain pass: one ok-table per matcher
-        cols: list[int] = []
-        oks: list[np.ndarray] = []
+        # dictionary-domain pass, one accepting form per matcher:
+        # (column, sorted codes, None) for eq/in — hash lookups, the
+        # dictionary's values are never walked — or (column, None,
+        # ok-table) for the ops whose verdict needs every value
+        forms: list[tuple[int, np.ndarray | None, np.ndarray | None]] = []
         for name, op, value in matchers:
             if name not in tag_names:
                 if not missing_tag_ok(op, value):
-                    return empty
+                    return empty, path
                 continue  # constant-true: no constraint
             i = tag_names.index(name)
+            if op in ("eq", "in"):
+                cs = _lookup_codes(dicts[i], op, value)
+                if len(cs) == 0:
+                    return empty, path
+                forms.append((i, cs, None))
+                continue
+            path = "postings"
             vals = np.asarray(list(dicts[i].values), dtype=object)
             ok = ok_codes_for(vals, op, value)
             if not ok.any():
-                return empty
-            cols.append(i)
-            oks.append(ok)
-        if not cols:
-            return np.arange(n, dtype=np.int32)
+                return empty, path
+            forms.append((i, None, ok))
+        if not forms:
+            return np.arange(n, dtype=np.int32), path
         built = self._ensure_built(codes, version)
         postings = self._postings
         # seed candidates from the most selective matcher (estimated
-        # from posting lengths over the built prefix)
-        seed = 0
+        # from the posting lengths of its accepting codes over the built
+        # prefix; a code interned since the build has no slice yet and
+        # counts 0 — its series live in the delta tail only)
+        seed = -1
         if built and postings:
             best = None
-            for j, (i, ok) in enumerate(zip(cols, oks)):
+            for j, (i, cs, ok) in enumerate(forms):
                 offsets, _ = postings[i]
-                nv = min(len(ok), len(offsets) - 1)
-                est = int(
-                    (offsets[1:nv + 1] - offsets[:nv])[ok[:nv]].sum()
-                )
+                acc = _posted_codes(cs, ok, len(offsets) - 1)
+                est = int((offsets[acc + 1] - offsets[acc]).sum())
                 if best is None or est < best:
-                    best, seed = est, j
-            offsets, order = postings[cols[seed]]
-            ok = oks[seed]
-            nv = min(len(ok), len(offsets) - 1)
-            cs = np.flatnonzero(ok[:nv]).astype(np.int64)
-            cand = _expand_csr(offsets, order, cs)
-            if len(cs) > 1:
+                    best, seed, seed_codes = est, j, acc
+            offsets, order = postings[forms[seed][0]]
+            cand = _expand_csr(offsets, order, seed_codes)
+            if len(seed_codes) > 1:
                 # each posting slice is ascending; a multi-code union
                 # needs one merge sort to restore global sid order
                 cand = np.sort(cand)
         else:
             cand = np.arange(built, dtype=np.int32)
-        # remaining matchers filter candidates through their ok-tables
-        for j, (i, ok) in enumerate(zip(cols, oks)):
-            if built and postings and j == seed:
+        # remaining matchers filter candidates by the candidates' codes
+        for j, (i, cs, ok) in enumerate(forms):
+            if j == seed:
                 continue
             if len(cand) == 0:
                 break
-            c = codes[cand, i]
-            safe = np.minimum(c, len(ok) - 1)
-            cand = cand[ok[safe] & (c < len(ok))]
+            cand = cand[_accepts(cs, ok, codes[cand, i])]
         # delta tail (sids registered since the CSR build): direct
         # evaluation over O(delta) rows
         if built < n:
             keep = np.ones(n - built, dtype=bool)
-            for i, ok in zip(cols, oks):
-                c = codes[built:, i]
-                safe = np.minimum(c, len(ok) - 1)
-                keep &= ok[safe] & (c < len(ok))
+            for i, cs, ok in forms:
+                keep &= _accepts(cs, ok, codes[built:, i])
             tail = (np.flatnonzero(keep) + built).astype(np.int32)
             if len(tail):
                 cand = np.concatenate([cand.astype(np.int32), tail])
-        return np.ascontiguousarray(cand, dtype=np.int32)
+        return np.ascontiguousarray(cand, dtype=np.int32), path
 
     # -- observability -------------------------------------------------
     def stats(self) -> dict:
@@ -346,7 +393,7 @@ def _count_lookup(path: str) -> None:
     global_registry.counter(
         "gtpu_index_lookups_total",
         "Secondary tag-index matcher lookups by path "
-        "(cache | postings | host)",
+        "(cache | codes | postings | host)",
         labels=("path",),
     ).labels(path).inc()
 

@@ -58,21 +58,225 @@ def test_match_sids_fuzz_against_oracle():
     rng = np.random.default_rng(7)
     reg = _make_registry(n=5000, hosts=40, regions=9, seed=1)
     ix = TagIndex(reg)
-    ops = ["eq", "ne", "in", "nin", "re", "nre"]
-    for _ in range(150):
+    # eq/in drawn twice as often as the table ops; literals run to
+    # h59/r59 over a dictionary of 40 hosts and 9 regions, so a third of
+    # the host literals and most region literals are absent values
+    ops = ["eq", "in", "eq", "in", "ne", "nin", "re", "nre"]
+
+    def literal(tag):
+        return f"{'r' if tag == 'region' else 'h'}{rng.integers(0, 60)}"
+
+    for _ in range(300):
         m = []
         for _ in range(rng.integers(1, 4)):
             tag = ["host", "region", "ghost"][rng.integers(0, 3)]
             op = ops[rng.integers(0, len(ops))]
             if op in ("in", "nin"):
-                val = [f"h{rng.integers(0, 45)}" for _ in range(3)]
+                val = [literal(tag) for _ in range(rng.integers(0, 9))]
             elif op in ("re", "nre"):
                 val = re.compile(f"[hr]{rng.integers(0, 45)}.*")
             else:
-                val = f"h{rng.integers(0, 45)}"
+                val = literal(tag)
             m.append((tag, op, val))
+        got = ix.match_sids(m)
         np.testing.assert_array_equal(
-            ix.match_sids(m), reg.match_sids(m), err_msg=repr(m))
+            got, reg.match_sids(m), err_msg=repr(m))
+        assert got.dtype == np.int32
+
+
+# -- eq/in by dictionary code ------------------------------------------
+# Every shape the code-set form of an eq/in matcher takes, each against
+# the registry's full-plane oracle. A scene is how the registry and the
+# index got to the lookup; the matchers run on what the scene left.
+
+def _scene_base():
+    """Built CSR, no delta; a sixth of the series leave `region` empty."""
+    rng = np.random.default_rng(3)
+    n = 1200
+    reg = SeriesRegistry(["host", "region"])
+    region = np.asarray(
+        [f"r{v}" if v else "" for v in rng.integers(0, 6, n)], object)
+    reg.intern_rows([
+        np.asarray([f"h{v}" for v in rng.integers(0, 24, n)], object),
+        region,
+    ])
+    ix = TagIndex(reg)
+    ix.match_sids([("host", "eq", "h0")])
+    return reg, ix
+
+
+def _scene_delta(rows):
+    """Series with values interned AFTER the CSR build: `rows` of them
+    against a rebuild_threshold of 64 (below: delta tail; past: rebuild)."""
+    reg, ix = _scene_base()
+    reg.intern_rows([
+        np.asarray([f"new{i % 3}" for i in range(rows)], object),
+        np.asarray([f"rn{i}" for i in range(rows)], object),
+    ])
+    # one old host gains a series in the tail too
+    reg.intern_rows([np.asarray(["h1"], object),
+                     np.asarray(["rn0"], object)])
+    return reg, ix
+
+
+def _scene_alter():
+    """A tag added by ALTER after the build: old series read "" for it."""
+    reg, ix = _scene_base()
+    reg.add_tag("dc")
+    reg.intern_rows([np.asarray(["h0", "h1"], object),
+                     np.asarray(["r1", ""], object),
+                     np.asarray(["east", "west"], object)])
+    return reg, ix
+
+
+# scene -> (registry, index, CSR rebuilds the lookup must make; None: any)
+_SCENES = {
+    "base": lambda: (*_scene_base(), None),
+    "alter": lambda: (*_scene_alter(), None),
+    "delta_tail": lambda: (*_scene_delta(10), 0),
+    "delta_rebuild": lambda: (*_scene_delta(200), 1),
+}
+
+_RE = re.compile(r"h1[0-9]?")
+_TABLE_MATCHERS = {
+    "ne": ("host", "ne", "h3"),
+    "nin": ("host", "nin", ["h3", "h4", "zz"]),
+    "re": ("host", "re", _RE),
+    "nre": ("host", "nre", _RE),
+}
+_CODE_MATCHERS = {
+    "eq": ("region", "eq", "r2"),
+    "in": ("region", "in", ["r1", "r4", "nope"]),
+}
+
+CODE_CASES = [
+    ("base", "eq-one", [("host", "eq", "h7")]),
+    ("base", "in-one", [("host", "in", ["h7"])]),
+    ("base", "in-eight", [("host", "in", [f"h{v}" for v in range(2, 10)])]),
+    ("base", "eq-absent", [("host", "eq", "h999")]),
+    ("base", "in-absent", [("host", "in", ["h999", "h998"])]),
+    ("base", "in-empty-list", [("host", "in", [])]),
+    ("base", "in-some-absent-repeated",
+     [("host", "in", ["h5", "zz", "h5", "h11", "h5", "yy"])]),
+    ("base", "eq-empty-string", [("region", "eq", "")]),
+    ("base", "in-empty-string", [("region", "in", ["", "r3"])]),
+    ("base", "eq-empty-string-never-left-empty", [("host", "eq", "")]),
+    ("base", "eq-eq", [("host", "eq", "h2"), ("region", "eq", "r1")]),
+    ("base", "in-eq-wide-seed",
+     [("host", "in", [f"h{v}" for v in range(20)]), ("region", "eq", "r5")]),
+    ("base", "eq-then-absent", [("host", "eq", "h2"), ("region", "eq", "zz")]),
+    ("base", "missing-tag-eq", [("rack", "eq", "x")]),
+    ("base", "missing-tag-eq-empty", [("rack", "eq", ""), ("host", "eq", "h2")]),
+    ("base", "missing-tag-in-empty", [("rack", "in", ["", "a"])]),
+    ("base", "missing-tag-in", [("host", "in", ["h1"]), ("rack", "in", ["a"])]),
+    ("delta_tail", "eq-interned-after-build", [("host", "eq", "new1")]),
+    ("delta_tail", "in-interned-after-build",
+     [("host", "in", ["new0", "new2", "h1"])]),
+    ("delta_tail", "eq-old-value-in-tail", [("host", "eq", "h1")]),
+    ("delta_tail", "eq-tail-two-tags",
+     [("host", "eq", "new0"), ("region", "in", ["rn0", "rn3", "r1"])]),
+    ("delta_tail", "tail-mixed-ne",
+     [("region", "ne", "rn0"), ("host", "in", ["new0", "h1"])]),
+    ("delta_rebuild", "eq-interned-after-build", [("host", "eq", "new1")]),
+    ("delta_rebuild", "in-interned-after-build",
+     [("host", "in", ["new0", "new2", "h1"])]),
+    ("delta_rebuild", "eq-tail-two-tags",
+     [("host", "eq", "new0"), ("region", "in", ["rn0", "rn70", "r1"])]),
+    ("alter", "eq-added-tag", [("dc", "eq", "east")]),
+    ("alter", "eq-added-tag-empty", [("dc", "eq", "")]),
+    ("alter", "in-added-tag", [("dc", "in", ["west", "", "south"])]),
+    ("alter", "eq-with-added-tag-ne",
+     [("host", "eq", "h0"), ("dc", "ne", "east")]),
+    ("alter", "added-tag-eq-with-in",
+     [("dc", "eq", "west"), ("host", "in", ["h1", "h2"])]),
+] + [
+    ("base", f"{c}-then-{t}", [_CODE_MATCHERS[c], _TABLE_MATCHERS[t]])
+    for c in _CODE_MATCHERS for t in _TABLE_MATCHERS
+] + [
+    ("base", f"{t}-then-{c}", [_TABLE_MATCHERS[t], _CODE_MATCHERS[c]])
+    for c in _CODE_MATCHERS for t in _TABLE_MATCHERS
+]
+
+
+_SELECT_NOTHING = {
+    "eq-absent", "in-absent", "in-empty-list", "eq-then-absent",
+    "eq-empty-string-never-left-empty", "missing-tag-eq", "missing-tag-in",
+}
+
+
+@pytest.mark.parametrize(
+    "scene,case,matchers", CODE_CASES,
+    ids=[f"{s}-{name}" for s, name, _ in CODE_CASES])
+def test_code_lookup_bit_identical_to_registry(scene, case, matchers):
+    _index.configure({"rebuild_threshold": 64})
+    try:
+        reg, ix, rebuilds = _SCENES[scene]()
+        b0 = ix.stats()["builds"]
+        got = ix.match_sids(matchers)
+        if rebuilds is not None:
+            assert ix.stats()["builds"] == b0 + rebuilds
+    finally:
+        _index.configure({"rebuild_threshold": 4096})
+    want = reg.match_sids(matchers)
+    assert np.array_equal(got, want), (matchers, got, want)
+    assert got.dtype == np.int32
+    assert np.all(np.diff(got) > 0)
+    # a case selects nothing only where it is made to
+    assert (len(want) == 0) == (case in _SELECT_NOTHING), case
+
+
+def _lookups(path: str) -> float:
+    return global_registry.counter(
+        "gtpu_index_lookups_total", labels=("path",)
+    ).labels(path).value
+
+
+def test_eq_in_resolve_by_code_without_a_dictionary_pass(monkeypatch):
+    from greptimedb_tpu.index import tag_index
+
+    calls = []
+
+    def counting(vals, op, value):
+        calls.append((len(vals), op))
+        return _real(vals, op, value)
+
+    _real = tag_index.ok_codes_for
+    monkeypatch.setattr(tag_index, "ok_codes_for", counting)
+    n = 4000
+    reg = SeriesRegistry(["hostname", "dc"])
+    reg.intern_rows([
+        np.asarray([f"host_{i}" for i in range(n)], object),
+        np.asarray([f"dc{i % 5}" for i in range(n)], object),
+    ])
+    assert len(reg.dicts[0]) == n
+    ix = TagIndex(reg)
+    fresh = [[("hostname", "eq", "host_17")],
+             [("hostname", "in", ["host_18"])],
+             [("hostname", "in", ["host_19", "host_3999", "host_4000"])],
+             [("hostname", "eq", "host_20"), ("dc", "in", ["dc0", "dc1"])],
+             [("hostname", "eq", "host_99999")]]
+    for m in fresh:
+        c0, p0 = _lookups("codes"), _lookups("postings")
+        got = ix.match_sids(m)
+        assert np.array_equal(got, reg.match_sids(m))
+        assert _lookups("codes") == c0 + 1, m
+        assert _lookups("postings") == p0, m
+    # the oracle above ran the registry's own module, not tag_index's name
+    assert calls == []
+    # one regex matcher in the set: one ok-table, counted as postings
+    m = [("hostname", "in", ["host_21", "host_22"]),
+         ("dc", "re", re.compile("dc[12]"))]
+    c0, p0, h0 = _lookups("codes"), _lookups("postings"), _lookups("cache")
+    got = ix.match_sids(m)
+    assert calls == [(5, "re")]
+    assert (_lookups("codes"), _lookups("postings")) == (c0, p0 + 1)
+    assert got.tolist() == [21, 22]
+    # a repeat of either kind is a memo hit
+    assert ix.match_sids(m) is got
+    ix.match_sids(fresh[0])
+    assert calls == [(5, "re")]
+    assert _lookups("cache") == h0 + 2
+    assert (_lookups("codes"), _lookups("postings")) == (c0, p0 + 1)
 
 
 def test_result_cache_hits_and_version_invalidation():
