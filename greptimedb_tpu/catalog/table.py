@@ -267,6 +267,31 @@ class Table:
             tuple(self.tag_names),
         )
 
+    def appended_since(self, version: tuple):
+        """What was written since `version` (an earlier
+        `data_version()`), if rows were put and nothing else happened
+        -> (rows, appends, the version they reach, None). Otherwise
+        (None, 0, None, reason): "mutation" (a truncate, a delete or a
+        schema change lies between the two versions), "multi_region"
+        (each region numbers its own series), "flushed" (the rows have
+        left the memtable). The parts of a version compare one by one:
+        schema and tags, then per region the truncate marks, then the
+        sequence, which only a write moves."""
+        if len(self.regions) != 1:
+            return None, 0, None, "multi_region"
+        region = self.regions[0]
+        (then,), columns, tags = version
+        if (columns != tuple(self.schema.column_names)
+                or tags != tuple(self.tag_names)
+                or then[1:] != region.data_version[1:]):
+            return None, 0, None, "mutation"
+        rows, appends, seq_now = region.rows_since(then[0])
+        if rows is False:
+            return None, 0, None, "flushed"
+        if rows is not None and (rows.op != OP_PUT).any():
+            return None, 0, None, "mutation"
+        return rows, appends, (((seq_now,) + then[1:],),) + version[1:], None
+
     def physical_version(self) -> tuple:
         """data_version extended with each region's manifest version:
         additionally bumps on flush/compact/schema commits. The frontend

@@ -56,8 +56,16 @@ Cache design:
 - cell resolution = gcd(align, range, data interval) when affordable, so
   the grid *is* the data for regular series (one sample per cell) and the
   per-query device reduction does the real work;
-- entries are invalidated by Table.data_version (every write/truncate bumps
-  it) — the page-cache-invalidation analog;
+- an entry carries the Table.data_version it holds. A query that finds it
+  behind asks the table for what was written since; rows that were only
+  appended (`_upkeep`) are scattered into the resident planes by one
+  program (`jit_grid_append`), on the query path and under `query.grid`,
+  so a body nobody queries costs nothing and several become one scatter.
+  Anything else (a truncate, a delete, a new series, a row that is not
+  the newest of its series, the spare cells used up) evicts and rebuilds,
+  the page-cache-invalidation analog;
+- the time axis has spare cells past the data (`_cell_capacity`), so an
+  appended tick changes no shape and compiles nothing;
 - partial states compose exactly, so results are identical to the host path
   up to f32 accumulation (the device stays in f32/int32: no x64 on TPU).
 
@@ -68,6 +76,7 @@ bounds, expression-valued aggregate args, quantiles).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import logging
 import math
@@ -110,6 +119,27 @@ _SELECTION = global_registry.counter(
 )
 _TOOK_ROWS, _TOOK_PLANE = _SELECTION.labels("rows"), _SELECTION.labels("plane")
 
+# what became of an entry that a query found behind its table's version:
+# brought forward by `_upkeep` ("append"), or evicted and rebuilt because
+# what was written since is not a plain append ("rebuild_<reason>")
+_UPKEEP = global_registry.counter(
+    "gtpu_grid_upkeep_total",
+    "range grid entries found behind their table's version, by outcome: "
+    "append (the rows written since were scattered into the resident "
+    "planes) or rebuild_<reason> (evicted and built again)",
+    labels=("outcome",),
+)
+# every outcome reads 0 from the start, so that a scrape can tell "no
+# rebuild" from "no such counter"
+for _reason in ("out_of_order", "new_series", "capacity", "flushed",
+                "mutation", "mesh", "multi_region"):
+    _UPKEEP.labels("rebuild_" + _reason)
+_UPKEEP.labels("append")
+_UPKEEP_ROWS = global_registry.counter(
+    "gtpu_grid_upkeep_rows_total",
+    "rows scattered into resident range grid planes by the upkeep",
+)
+
 DEVICE_THRESHOLD = 262_144       # min table rows before the cache pays off
 _CELL_CAP = 256 * 1024 * 1024    # max S*NB cells per cached array (1GB f32)
 _MAX_ENTRIES = 8                 # LRU entry-count cap across all tables
@@ -146,13 +176,56 @@ _STATE_KEYS = {
 }
 
 
+class _PlaneGate:
+    """Who may touch an entry's planes and what is filed about them:
+    any number of queries, from reading the plane references to the
+    return of their dispatch, and again to file a window's record or a
+    session's buffer (`shared`), or the one upkeep that donates the
+    planes to its program, and so deletes the arrays a query might
+    still hold, and forgets the windows it wrote to (`exclusive`)."""
+
+    def __init__(self):
+        self._cv = concurrency.Condition()
+        self._readers = 0
+        self._writer = False
+
+    @contextlib.contextmanager
+    def shared(self):
+        with self._cv:
+            while self._writer:
+                self._cv.wait()
+            self._readers += 1
+        try:
+            yield
+        finally:
+            with self._cv:
+                self._readers -= 1
+                if not self._readers:
+                    self._cv.notify_all()
+
+    @contextlib.contextmanager
+    def exclusive(self):
+        with self._cv:
+            while self._writer:
+                self._cv.wait()
+            self._writer = True
+            while self._readers:
+                self._cv.wait()
+        try:
+            yield
+        finally:
+            with self._cv:
+                self._writer = False
+                self._cv.notify_all()
+
+
 @dataclass
 class _Entry:
     version: tuple
     res: int                     # cell width, ms
     phase: int                   # cell boundary phase: boundaries ≡ phase (mod res)
     t0c: int                     # absolute ms of cell 0's left edge
-    nb: int                      # number of cells
+    nb: int                      # cells of the time axis: its capacity
     num_series: int
     registry: object             # SeriesRegistry of the building scan
     rows_scanned: int
@@ -187,6 +260,24 @@ class _Entry:
     # dict keys); persisted so a restart can precompile them during
     # warm (cold-start killer)
     program_specs: dict = dc_field(default_factory=dict)
+    # cells [0, nb_data) reach the newest row; [nb_data, nb) are spare
+    # (`_cell_capacity`) and read as cells outside the data do
+    nb_data: int = 0
+    # what the upkeep holds a batch of new rows against (host side):
+    # the newest ts of every series, and the registry's version, which
+    # a new series moves
+    last_ts: np.ndarray | None = None
+    registry_version: int = -1
+    # the version the entry was built or restored at: the session
+    # registry's stamp for its result buffers, which the upkeep purges
+    # by window instead of by version
+    session_version: tuple = ()
+    # queries read the planes shared, the upkeep donates them exclusive
+    gate: object = dc_field(default_factory=lambda: _PlaneGate())
+    # batches the upkeep has applied: a query reads it with the planes
+    # and files what it learned of a window (`_file`) only while it
+    # stands, so nothing read from older planes outlives the purge
+    appends: int = 0
 
     def recount_bytes(self) -> int:
         per = self.num_series * self.nb * 4
@@ -241,20 +332,17 @@ class DeviceRangeCache:
         self._evictions += 1
         _sessions.global_sessions.purge_table(("range", id(entry)))
 
-    def lookup_compatible(self, tkey, version, r0: int, align_to: int
+    def lookup_compatible(self, tkey, r0: int, align_to: int
                           ) -> _Entry | None:
         """Find a live entry for `tkey` whose resolution serves a query
-        with bucket gcd r0 and phase align_to. Evicts stale-version
-        entries for the table; LRU-touches the hit."""
+        with bucket gcd r0 and phase align_to; LRU-touches the hit. The
+        entry may be behind the table's version: the caller brings it
+        forward (`_upkeep`) or drops it (`evict`)."""
         with self._lock:
             for key in list(self._entries):
                 if key[0] != tkey:
                     continue
                 e = self._entries[key]
-                if e.version != version:
-                    del self._entries[key]
-                    self._release(e)
-                    continue
                 if r0 % e.res == 0 and align_to % e.res == e.phase:
                     self._entries.pop(key)
                     self._entries[key] = e
@@ -262,6 +350,14 @@ class DeviceRangeCache:
                     return e
             self._misses += 1
         return None
+
+    def evict(self, entry: _Entry) -> None:
+        """Drop an entry that cannot be brought forward."""
+        with self._lock:
+            for key, e in list(self._entries.items()):
+                if e is entry:
+                    del self._entries[key]
+                    self._release(e)
 
     def insert(self, key: tuple, entry: _Entry):
         with self._lock:
@@ -502,6 +598,37 @@ def _series_pad(s: int, mesh) -> int:
     return -(-s // mult) * mult
 
 
+_LANE = 128     # the chip tiles an array's last axis by this many
+
+
+def _cell_capacity(nb: int, fits) -> int:
+    """Cells to give a time axis whose data spans `nb`: a quarter more
+    (a geometric rule from the entry's own span: a table that holds
+    days has hours to spare, one that holds minutes has minutes), to a
+    whole lane tile once past one (the chip pads to it anyway), so that
+    appended ticks change no shape; `nb` itself where `fits(cells)`
+    refuses that (the cell cap, the byte budget): an entry that fits
+    without spare cells is still built, and rebuilds when it grows."""
+    cap = nb + max(nb // 4, 1)
+    if cap > _LANE:
+        cap = -(-cap // _LANE) * _LANE
+    return cap if fits(cap) else nb
+
+
+_NO_ROW = np.iinfo(np.int64).min
+
+
+def _series_last_ts(nrow, imax, t0c: int, res: int) -> np.ndarray:
+    """(S,) int64: the newest ts each series holds in the host-side
+    (S, NB) planes, `_NO_ROW` for a series with none."""
+    has = np.asarray(nrow) > 0
+    nb = has.shape[1]
+    last_cell = nb - 1 - np.argmax(has[:, ::-1], axis=1)
+    last = (t0c + last_cell.astype(np.int64) * res
+            + np.asarray(imax)[np.arange(len(has)), last_cell])
+    return np.where(has.any(axis=1), last, _NO_ROW)
+
+
 def build_entry(plan, table, items, mesh=None, mesh_opts=None,
                 byte_budget: int = _BYTE_BUDGET,
                 keep_host: bool = False) -> _Entry | None:
@@ -556,12 +683,16 @@ def build_entry(plan, table, items, mesh=None, mesh_opts=None,
     data_min = int(ts.min())
     data_max = int(ts.max())
     t0c = phase + ((data_min - phase) // res) * res
-    nb = (data_max - t0c) // res + 1
-    if S * nb > _CELL_CAP:
-        return None
+    nb_data = (data_max - t0c) // res + 1
     # projected device bytes for the full entry must fit the cache budget
     n_arr = 3 + sum(len(k) for k in needed.values())
-    if S * nb * 4 * n_arr > byte_budget:
+
+    def fits(cells):
+        return (S * cells <= _CELL_CAP
+                and S * cells * 4 * n_arr <= byte_budget)
+
+    nb = _cell_capacity(nb_data, fits)
+    if not fits(nb):
         return None
 
     cell = (ts - t0c) // res
@@ -573,7 +704,8 @@ def build_entry(plan, table, items, mesh=None, mesh_opts=None,
     entry = _Entry(
         version=version, res=res, phase=phase, t0c=t0c, nb=nb,
         num_series=S, registry=data.registry,
-        rows_scanned=len(rows),
+        rows_scanned=len(rows), nb_data=nb_data,
+        registry_version=data.registry.version, session_version=version,
     )
     entry.mesh = mesh
     entry.mesh_decision = decision
@@ -605,6 +737,7 @@ def build_entry(plan, table, items, mesh=None, mesh_opts=None,
         snap["imax"] = imax
     entry.imin = put2(imin)
     entry.imax = put2(imax)
+    entry.last_ts = _series_last_ts(nrow, imax, t0c, res)
 
     for fname, keys in needed.items():
         vals = rows.fields[fname]
@@ -629,6 +762,9 @@ def build_entry(plan, table, items, mesh=None, mesh_opts=None,
     _ensure_rows_pseudo(entry, items, jnp)
     entry.recount_bytes()
     if snap is not None:
+        # the host copies are of this version whatever the entry's
+        # planes become later (`persist_entry` writes no other)
+        snap["__stamp__"] = (version, nb_data)
         entry.host_snap = snap
     return entry
 
@@ -745,7 +881,11 @@ _SNAP_ALIGN = 64
 
 def persist_entry(entry: _Entry, table) -> bool:
     """Write the entry's host grids as a restart snapshot under the
-    region dir (single-region tables only). Clears entry.host_snap.
+    region dir (single-region tables only). Clears entry.host_snap. A
+    snapshot is read back only at the version it was taken at, so none
+    is written once the table has been written to since the build: under
+    steady writes that is every build, and a whole grid would go to
+    disk for the next body to make stale.
 
     Format: magic + u64 json-meta length + meta + 64-aligned raw array
     bytes — flat on purpose, so load_entry_snapshot can memory-map each
@@ -754,6 +894,9 @@ def persist_entry(entry: _Entry, table) -> bool:
     snap = entry.host_snap
     entry.host_snap = None
     if snap is None or len(table.regions) != 1:
+        return False
+    version, nb_data = snap.pop("__stamp__")
+    if table.data_version() != version:
         return False
     region = table.regions[0]
     import io
@@ -774,9 +917,10 @@ def persist_entry(entry: _Entry, table) -> bool:
         })
         off += arr.nbytes
     meta = {
-        "version": _ver_json(entry.version),
+        "version": _ver_json(version),
         "res": entry.res, "phase": entry.phase, "t0c": entry.t0c,
-        "nb": entry.nb, "num_series": entry.num_series,
+        "nb": entry.nb, "nb_data": nb_data,
+        "num_series": entry.num_series,
         "rows_scanned": entry.rows_scanned,
         "nan_ok": {k: bool(v) for k, v in entry.nan_ok.items()},
         "n_alias": sorted(entry.n_aliased),
@@ -934,13 +1078,19 @@ def load_entry_snapshot(table, r0: int, align_to: int, mesh=None,
             t0c=meta["t0c"], nb=meta["nb"],
             num_series=meta["num_series"], registry=region.series,
             rows_scanned=meta["rows_scanned"],
+            # a snapshot from before the spare cells has none
+            nb_data=meta.get("nb_data", meta["nb"]),
+            registry_version=region.series.version,
+            session_version=version,
         )
         entry.mesh = mesh
         entry.mesh_decision = decision
         by_key = {ent["key"]: ent for ent in meta["arrays"]}
-        entry.nrow = put2(fetch(by_key["nrow"]))
+        nrow, imax = fetch(by_key["nrow"]), fetch(by_key["imax"])
+        entry.nrow = put2(nrow)
         entry.imin = put2(fetch(by_key["imin"]))
-        entry.imax = put2(fetch(by_key["imax"]))
+        entry.imax = put2(imax)
+        entry.last_ts = _series_last_ts(nrow, imax, entry.t0c, res)
         for key, ent in by_key.items():
             if not key.startswith("f::"):
                 continue
@@ -1239,18 +1389,17 @@ def ensure_states(entry: _Entry, plan, table, items,
     resolution/phase, different ops). Returns False if a rescan failed."""
     import jax.numpy as jnp
 
-    if table.data_version() != entry.version:
-        return False  # racing write; caller falls back / rebuilds later
     with entry.grow_lock:
-        ok = _ensure_states_locked(entry, plan, table, items, cache, jnp)
-    if ok:
+        grown = _ensure_states_locked(entry, plan, table, items, cache, jnp)
+    if grown:
         from greptimedb_tpu.telemetry import memory as _memory
 
         _memory.note_device_bytes()
-    return ok
+    return grown is not False
 
 
-def _ensure_states_locked(entry, plan, table, items, cache, jnp) -> bool:
+def _ensure_states_locked(entry, plan, table, items, cache, jnp):
+    """-> None (nothing was missing), True (grown) or False (refused)."""
     missing: dict[str, set] = {}
     for fname, op in items:
         if fname == "__rows__":
@@ -1261,7 +1410,9 @@ def _ensure_states_locked(entry, plan, table, items, cache, jnp) -> bool:
         if want:
             missing.setdefault(fname, set()).update(want)
     if not missing:
-        return True
+        return None
+    if table.data_version() != entry.version:
+        return False  # racing write; caller falls back / rebuilds later
     # the same scan, assembly and upload as a build, for the states
     # this entry lacks
     with tracing.child_span("grid.build", grow=True):
@@ -1318,6 +1469,219 @@ def _grow_states_locked(entry, table, missing, cache) -> bool:
             entry.n_aliased.add(fname)
     entry.recount_bytes()
     return True
+
+
+# ----------------------------------------------------------------------
+# upkeep: an entry behind its table's version is brought forward by the
+# rows written since, where they are a plain append
+# ----------------------------------------------------------------------
+
+# the cells one call of the append program updates, padded to a bucket:
+# one compile a bucket and a layout of planes
+_APPEND_BUCKETS = (512, 4096, 32768, 262144)
+# per-field delta columns, in the order the program reads them
+_APPEND_KEYS = ("n", "s", "s2", "mn", "mx", "vf", "if", "vl", "il")
+# a value past this could take a cell's float32 `s2` to infinity, which
+# only a readback would show: the field's NaN encoding is given up
+_NAN_OK_MAX = 1e12
+
+
+def _upkeep(entry: _Entry, table, cache: "DeviceRangeCache") -> str:
+    """Bring `entry` forward to what its table holds now, if what was
+    written since its stamp is a plain append: puts of series the entry
+    knows, each strictly newer than the newest row the entry holds of
+    its series, inside the spare cells, with no truncate, delete or
+    schema change between, the entry not on a mesh. Returns "append"
+    (also when another query did it first), or "rebuild_<reason>" with
+    the entry untouched: the caller evicts it. Runs under
+    `query.grid`."""
+    with tracing.child_span("grid.upkeep") as span, entry.grow_lock:
+        rows, appends, batch = None, 0, None
+        if getattr(entry, "mesh", None) is not None:
+            reason = "mesh"
+        else:
+            rows, appends, version, reason = table.appended_since(
+                entry.version)
+        if reason is None and rows is None:
+            # another query brought it forward first
+            entry.version = version
+            span.attributes["outcome"] = "current"
+            return "append"
+        if reason is None:
+            batch, reason = _append_batch(entry, table, rows)
+        if reason is None and batch["dealias"] and not cache.reserve_growth(
+                entry, len(batch["dealias"]) * entry.num_series
+                * entry.nb * 4):
+            reason = "capacity"
+        cells_new = 0
+        if reason is None:
+            cells_new = -entry.nb_data
+            _apply_append(entry, batch)
+            cells_new += entry.nb_data
+            entry.version = version
+            _UPKEEP_ROWS.inc(len(rows))
+        outcome = "append" if reason is None else "rebuild_" + reason
+        span.attributes.update(
+            outcome=outcome, bodies=appends, cells_new=cells_new,
+            rows=0 if rows is None else len(rows))
+        _UPKEEP.labels(outcome).inc()
+        return outcome
+
+
+def _forget_windows_from(entry: _Entry, c0: int) -> None:
+    """What the program said of a window, and the result buffers of a
+    session, hold only for windows that end at or before cell `c0`, the
+    first a batch was appended to; selections (group ids, key columns,
+    the series mask) depend on the registry alone and stay. Under the
+    gate, exclusive."""
+    from greptimedb_tpu.query import sessions as _sessions
+
+    for memo in list(entry.query_memo.values()):
+        memo["windows"] = {k: w for k, w in memo["windows"].items()
+                           if k[1] <= c0}
+    _sessions.global_sessions.purge_table(
+        ("range", id(entry)), keep=lambda key: key[1][1] <= c0)
+
+
+def _file(entry: _Entry, seen: int, put, *args) -> None:
+    """File what a query learned from the planes as it read them when
+    `entry.appends` stood at `seen`: not if a batch was applied since,
+    whose purge has run and would have missed it (a later query, sent
+    after that batch's rows were acknowledged, would be answered
+    without them)."""
+    with entry.gate.shared():
+        if entry.appends == seen:
+            put(*args)
+
+
+def _append_batch(entry: _Entry, table, rows):
+    """The rows written since the entry's stamp as the append program
+    takes them -> (batch, None), or (None, reason) where they are no
+    plain append. The rows of a cell are reduced here, on the host and
+    in float64, exactly as a build reduces them (`_build_field_states`),
+    so each cell the batch touches comes with one delta a plane: the
+    program's indices are unique."""
+    reg = entry.registry
+    if reg is not table.regions[0].series:
+        return None, "multi_region"
+    sid, ts = rows.sid.astype(np.int64), rows.ts
+    if reg.version != entry.registry_version or int(sid.max()) >= len(
+            entry.last_ts):
+        return None, "new_series"
+    order = None
+    if not _is_sid_ts_sorted(sid, ts):
+        order = np.lexsort((ts, sid))
+        sid, ts = sid[order], ts[order]
+    first = np.r_[True, sid[1:] != sid[:-1]]
+    if ((ts[1:] <= ts[:-1]) & ~first[1:]).any() or (
+            ts[first] <= entry.last_ts[sid[first]]).any():
+        # a row at or before the newest its series holds: an overwrite
+        # or a late arrival, which cell states cannot take back
+        return None, "out_of_order"
+    cell = (ts - entry.t0c) // entry.res
+    if int(cell.min()) < 0:
+        return None, "out_of_order"
+    if int(cell.max()) >= entry.nb:
+        return None, "capacity"
+    intra = ts - entry.t0c - cell * entry.res
+    seg = sid * entry.nb + cell
+    change = np.r_[True, seg[1:] != seg[:-1]]
+    starts = np.nonzero(change)[0]
+    ends = np.r_[starts[1:], len(seg)] - 1
+    local = np.cumsum(change) - 1          # the batch's own cell ids
+    k = len(starts)
+    cols = [sid[starts], cell[starts], ends - starts + 1,
+            intra[starts], intra[ends]]
+    layout, nan_ok, dealias = [], {}, []
+    for fname in sorted(entry.fields):
+        if fname == "__rows__":
+            continue
+        planes = entry.fields[fname]
+        vals = rows.fields[fname]
+        valid = (rows.field_valid or {}).get(fname)
+        if order is not None:
+            vals = vals[order]
+            valid = valid[order] if valid is not None else None
+        if valid is None:
+            valid = np.ones(len(vals), bool)
+        delta, ok, _ = _build_field_states(
+            set(planes), vals.astype(np.float64, copy=False), valid,
+            local, k, intra, (k,), lambda a: a)
+        nan_ok[fname] = ok and bool(
+            (np.abs(vals[valid]) <= _NAN_OK_MAX).all())
+        aliased = fname in entry.n_aliased
+        if aliased and not valid.all():
+            # the field's count stops being the row count
+            dealias.append(fname)
+            aliased = False
+        keys = tuple(key for key in _APPEND_KEYS if key in planes
+                     and not (key == "n" and aliased))
+        layout.append((fname, keys, aliased))
+        cols.extend(delta[key] for key in keys)
+    upd = np.stack([
+        c.astype(np.float32).view(np.int32) if c.dtype.kind == "f"
+        else c.astype(np.int32) for c in cols])
+    run_ends = np.r_[np.nonzero(first)[0][1:] - 1, len(sid) - 1]
+    return {
+        "upd": upd, "layout": tuple(layout), "nan_ok": nan_ok,
+        "dealias": dealias, "newest": (sid[run_ends], ts[run_ends]),
+        "cells": (int(cell.min()), int(cell.max()) + 1),
+    }, None
+
+
+def _apply_append(entry: _Entry, batch: dict) -> None:
+    """Scatter a batch into the entry's planes: one dispatch of the
+    append program a bucket of cells, every plane donated and taken back
+    updated. Not waited for: the query's own program queues behind it."""
+    import jax.numpy as jnp
+
+    from greptimedb_tpu.telemetry import device_trace
+
+    for fname in batch["dealias"]:
+        entry.fields[fname]["n"] = jnp.array(entry.nrow)
+        entry.n_aliased.discard(fname)
+    layout, upd = batch["layout"], batch["upd"]
+    program = get_append_program()
+    big = _APPEND_BUCKETS[-1]
+    with entry.gate.exclusive():
+        for at in range(0, upd.shape[1], big):
+            part = upd[:, at:at + big]
+            k = part.shape[1]
+            kb = next(b for b in _APPEND_BUCKETS if k <= b)
+            if k < kb:
+                # padding: series past the axis, each its own, dropped
+                pad = np.zeros((len(part), kb - k), np.int32)
+                pad[0] = entry.num_series + np.arange(kb - k)
+                part = np.concatenate([part, pad], axis=1)
+            spec = (layout, kb)
+            planes = {f: {key: entry.fields[f][key] for key in keys}
+                      for f, keys, _ in layout}
+            with device_trace.device_call(
+                    "grid_upkeep", key=("grid_upkeep", spec),
+                    cells=k) as dcall:
+                planes, entry.nrow, entry.imin, entry.imax = dcall.run(
+                    program, planes, entry.nrow, entry.imin, entry.imax,
+                    part, spec=spec)
+                dcall.transfer(part.nbytes, "upload")
+                dcall.executed(dispatch_only=True)
+            for f, _keys, aliased in layout:
+                entry.fields[f].update(planes[f])
+                if aliased:
+                    entry.fields[f]["n"] = entry.nrow
+            if "__rows__" in entry.fields:
+                entry.fields["__rows__"]["n"] = entry.nrow
+        # with the queries still shut out: what one of them files from
+        # here on was read from these planes
+        entry.appends += 1
+        _forget_windows_from(entry, batch["cells"][0])
+    for fname, ok in batch["nan_ok"].items():
+        entry.nan_ok[fname] = entry.nan_ok.get(fname, True) and ok
+    sids, ts = batch["newest"]
+    entry.last_ts[sids] = ts
+    entry.nb_data = max(entry.nb_data, batch["cells"][1])
+    entry.rows_scanned += int(upd[2].sum())
+    if batch["dealias"]:
+        entry.recount_bytes()
 
 
 # ----------------------------------------------------------------------
@@ -1883,6 +2247,91 @@ def get_program():
     return _PROGRAM
 
 
+def _make_append_program():
+    import jax
+    import jax.numpy as jnp
+
+    @functools.partial(jax.jit, static_argnames=("spec",),
+                       donate_argnums=(0, 1, 2, 3))
+    def grid_append(planes, nrow, imin, imax, upd, *, spec):
+        """Every plane of an entry, donated, with the cells of one batch
+        of appended rows brought up to date: `upd` int32[columns, Kb]
+        holds a cell a column entry (series, cell, rows, first and last
+        intra-cell offset, then for each field of `spec`'s layout the
+        deltas of its planes, float32 ones as their bits), padded with
+        series past the axis, which the scatters drop. The cells are
+        distinct, and their rows newer than any the planes hold of
+        their series, so states compose as `_build_field_states` builds
+        them: counts and sums add, extremes fold, a cell's first row
+        stays and its last is replaced."""
+        layout, _kb = spec
+        at = (upd[0], upd[1])
+
+        def old(plane):
+            return plane.at[at].get(mode="clip")
+
+        def put(plane, value):
+            return plane.at[at].set(value.astype(plane.dtype), mode="drop",
+                                    unique_indices=True)
+
+        def f32(col):
+            return jax.lax.bitcast_convert_type(col, jnp.float32)
+
+        n0 = old(nrow)
+        fresh = n0 == 0
+        out_imin = put(imin, jnp.where(
+            fresh, upd[3], jnp.minimum(old(imin), upd[3])))
+        out_imax = put(imax, jnp.where(
+            fresh, upd[4], jnp.maximum(old(imax), upd[4])))
+        out_nrow = put(nrow, n0 + upd[2])
+        col = 5
+        out = {}
+        for fname, keys, aliased in layout:
+            cur, new = planes[fname], {}
+            delta = {}
+            for key in keys:
+                delta[key] = upd[col]
+                col += 1
+            nf0 = n0 if aliased else old(cur["n"])
+            cnt = upd[2] if aliased else delta["n"]
+            if not aliased:
+                new["n"] = put(cur["n"], nf0 + cnt)
+            for key in ("s", "s2"):
+                if key in keys:
+                    new[key] = put(cur[key], old(cur[key]) + f32(delta[key]))
+            if "mn" in keys:
+                new["mn"] = put(cur["mn"], jnp.minimum(old(cur["mn"]),
+                                                      f32(delta["mn"])))
+            if "mx" in keys:
+                new["mx"] = put(cur["mx"], jnp.maximum(old(cur["mx"]),
+                                                      f32(delta["mx"])))
+            if "vf" in keys:
+                first = (nf0 == 0) & (cnt > 0)
+                new["vf"] = put(cur["vf"], jnp.where(
+                    first, f32(delta["vf"]), old(cur["vf"])))
+                new["if"] = put(cur["if"], jnp.where(
+                    first, delta["if"], old(cur["if"])))
+            if "vl" in keys:
+                new["vl"] = put(cur["vl"], jnp.where(
+                    cnt > 0, f32(delta["vl"]), old(cur["vl"])))
+                new["il"] = put(cur["il"], jnp.where(
+                    cnt > 0, delta["il"], old(cur["il"])))
+            out[fname] = new
+        return out, out_nrow, out_imin, out_imax
+
+    return grid_append
+
+
+_APPEND = None
+
+
+def get_append_program():
+    global _APPEND
+    if _APPEND is None:
+        _APPEND = _make_append_program()
+    return _APPEND
+
+
 # ----------------------------------------------------------------------
 # orchestration
 # ----------------------------------------------------------------------
@@ -2079,13 +2528,24 @@ def execute_range_device(engine, plan, table):
         version = table.data_version()
         cache: DeviceRangeCache = engine.range_cache
         tkey = (table.info.database, table.info.name, id(table))
-        entry = cache.lookup_compatible(tkey, version, r0, plan.align_to)
-        hit_note = "hit"
+
+        def lookup():
+            """The table's compatible entry at the table's version: an
+            entry behind it is brought forward, or dropped where what
+            was written since is not a plain append."""
+            found = cache.lookup_compatible(tkey, r0, plan.align_to)
+            if found is None or found.version == version:
+                return found, "hit"
+            if _upkeep(found, table, cache) == "append":
+                return found, "upkeep"
+            cache.evict(found)
+            return None, "hit"
+
+        entry, hit_note = lookup()
         if entry is None and getattr(engine, "persist_device_cache", True):
             with stats.timed("grid_cache_restore_ms"), _restore_lock(tkey):
                 # the warm thread may have restored while we waited
-                entry = cache.lookup_compatible(tkey, version, r0,
-                                                plan.align_to)
+                entry, hit_note = lookup()
                 if entry is None:
                     entry = load_entry_snapshot(
                         table, r0, plan.align_to,
@@ -2115,7 +2575,7 @@ def execute_range_device(engine, plan, table):
         else:
             stats.note("grid_cache", hit_note)
             grid_span.attributes["grid_cache"] = (
-                "hit" if hit_note == "hit" else "restore")
+                hit_note if hit_note in ("hit", "upkeep") else "restore")
             with stats.timed("grid_cache_ensure_ms"):
                 ok = ensure_states(entry, plan, table, items, cache=cache)
             if not ok:
@@ -2158,7 +2618,7 @@ def execute_range_device(engine, plan, table):
         # the grid's cells the WHERE admits (the grid's own extent where
         # it leaves a side open): an outer bound of the rows' extent,
         # known before any dispatch
-        cell_lo, cell_hi = max(lo, 0), min(hi, entry.nb)
+        cell_lo, cell_hi = max(lo, 0), min(hi, entry.nb_data)
         if cell_lo >= cell_hi:
             return empty
         sids = None
@@ -2237,11 +2697,6 @@ def execute_range_device(engine, plan, table):
             (op, it.range_ms // res, fname)
             for (fname, op), it in zip(items, plan.range_items)
         )
-        arrs = {}
-        for fname, op in items:
-            d = arrs.setdefault(fname, {})
-            for bk in _STATE_KEYS[op]:
-                d[bk] = entry.fields[fname][bk]
         nanenc = all(
             entry.nan_ok.get(fname, fname == "__rows__") for fname, _ in items
         )
@@ -2305,7 +2760,7 @@ def execute_range_device(engine, plan, table):
         session_tkey = ("range", id(entry))
         session_key = (sel_key, win_key, delta, prog_spec)
         out_dev = (sessions.global_sessions.get(
-            session_tkey, session_key, entry.version
+            session_tkey, session_key, entry.session_version
         ) if use_sessions else None)
         if win is None:
             # what the program said of this window went with the memo:
@@ -2335,34 +2790,41 @@ def execute_range_device(engine, plan, table):
             window = np.array([delta, lo_c, hi_c], np.int32)
             if kb:
                 # the call's one host argument, uploaded with it
-                vec = np.concatenate([window, memo["tail"]])
-                out_dev, packed_dev = dcall.run(
-                    program,
-                    arrs, entry.nrow, entry.imin, entry.imax, vec,
-                    spec=prog_spec,
-                )
-                dcall.transfer(vec.nbytes, "upload")
+                inputs = (np.concatenate([window, memo["tail"]]),)
+                uploaded = inputs[0].nbytes
             else:
                 gid_in, uploaded = _selection_gid(memo, entry_mesh)
                 # the three scalars are one NumPy value too: the call
                 # uploads it, in one transfer
-                out_dev, act_dev, extent_dev = dcall.run(
+                inputs = (gid_in, window)
+            # the planes are read and handed to the dispatch with the
+            # upkeep, which donates them, shut out
+            with entry.gate.shared():
+                seen = entry.appends
+                arrs = {}
+                for fname, op in items:
+                    d = arrs.setdefault(fname, {})
+                    for bk in _STATE_KEYS[op]:
+                        d[bk] = entry.fields[fname][bk]
+                outs = dcall.run(
                     program,
-                    arrs, entry.nrow, entry.imin, entry.imax,
-                    gid_in, window,
+                    arrs, entry.nrow, entry.imin, entry.imax, *inputs,
                     spec=prog_spec,
                 )
-                if uploaded:
-                    dcall.transfer(uploaded, "upload")
+            if uploaded:
+                dcall.transfer(uploaded, "upload")
+            if kb:
+                out_dev, packed_dev = outs
+            else:
+                out_dev, act_dev, extent_dev = outs
                 if win is None:
                     extras = (act_dev, extent_dev)
             out_dev.block_until_ready()
         dcall.executed()
         if dispatch and use_sessions:
-            sessions.global_sessions.put(
-                session_tkey, session_key, entry.version, out_dev,
-                int(out_dev.nbytes),
-            )
+            _file(entry, seen, sessions.global_sessions.put,
+                  session_tkey, session_key, entry.session_version, out_dev,
+                  int(out_dev.nbytes))
         if kb and win is None:
             # a window the memo does not know (so this call dispatched),
             # on the rows path: `packed` holds all of it, one array in
@@ -2387,8 +2849,10 @@ def execute_range_device(engine, plan, table):
             readback_bytes = out.nbytes + sum(x.nbytes for x in extras)
         dcall.transfer(readback_bytes, "readback")
         if win is None:
+            # (no record means this call dispatched: `seen` is its own)
             win = _fold_window(entry, memo, *extras)
-            _memo_put(memo["windows"], win_key, win)
+            _file(entry, seen, lambda: _memo_put(
+                memo["windows"], win_key, win))
         # the exact window, from the exact extent of the selected rows:
         # the steps to keep of the bound window's
         n_steps = 0

@@ -135,11 +135,15 @@ class SessionRegistry:
         _memory.note_device_bytes()
 
     # ------------------------------------------------------------------
-    def purge_table(self, tkey) -> None:
+    def purge_table(self, tkey, keep=None) -> None:
         """Drop every buffer for `tkey` (table drop/close: a recreated
-        table could reuse the id and coincidentally match versions)."""
+        table could reuse the id and coincidentally match versions);
+        with `keep`, those whose shape key it does not vouch for (the
+        range grid's upkeep: buffers of windows that meet appended
+        cells)."""
         with self._lock:
-            stale = [k for k in self._entries if k[0] == tkey]
+            stale = [k for k in self._entries if k[0] == tkey
+                     and not (keep is not None and keep(k[1]))]
             for k in stale:
                 self._drop_locked(k)
             if stale:

@@ -126,6 +126,25 @@ class Memtable:
         return out
 
 
+    def rows_since(self, seq: int) -> tuple[ColumnarRows | None, int]:
+        """-> (the rows with sequence >= `seq`, the appends they came
+        in). A partition's chunks are in append order, which is sequence
+        order, so each is walked from its newest chunk back to the first
+        that is older: the cost is that of the rows asked for, not of
+        the memtable."""
+        picks: list[ColumnarRows] = []
+        with self._lock:
+            for part in self._parts.values():
+                for chunk in reversed(part.chunks):
+                    if int(chunk.seq[-1]) < seq:
+                        break
+                    picks.append(chunk if int(chunk.seq[0]) >= seq
+                                 else _slice_rows(chunk, chunk.seq >= seq))
+        if not picks:
+            return None, 0
+        return _concat_rows(picks, self.field_names), len(picks)
+
+
 def _slice_rows(rows: ColumnarRows, sel: np.ndarray) -> ColumnarRows:
     return ColumnarRows(
         sid=rows.sid[sel], ts=rows.ts[sel], seq=rows.seq[sel], op=rows.op[sel],

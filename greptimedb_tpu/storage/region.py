@@ -219,6 +219,9 @@ class Region:
         # appends); the next WAL-on entry carries them, flush clears them
         self._pending_new_series: list[tuple[int, list[str]]] = []
         self._seq = self.manifest.state.committed_sequence
+        # rows with a sequence at or above this are all in the active
+        # memtable (`rows_since`); a flush's freeze moves it up
+        self._memtable_floor_seq = self._seq
         self._truncate_epoch = 0
         self._scan_cache: tuple | None = None  # (data_version, ColumnarRows)
         self._lock = concurrency.RLock()
@@ -362,6 +365,21 @@ class Region:
                 self.memtable.append(rows)
             return base_seq
 
+    def rows_since(self, seq: int):
+        """What was written from sequence `seq` on, read from the
+        memtable by sequence with no scan of the region -> (rows, the
+        appends they came in, the sequence they reach up to). `rows` is
+        None when nothing was written, and False when a flush has moved
+        some of them out of the memtable. Taken under the write lock,
+        which a write holds from its sequence bump to its memtable
+        insert, so the rows are exactly those below the sequence
+        returned."""
+        with self._lock:
+            if seq < self._memtable_floor_seq:
+                return False, 0, self._seq
+            rows, appends = self.memtable.rows_since(seq)
+            return rows, appends, self._seq
+
     def _make_rows(self, tag_columns, ts, fields, field_valid, op, base_seq):
         """Intern tags and normalize fields into sid-resolved ColumnarRows.
         Returns (rows, new_series_delta)."""
@@ -488,7 +506,7 @@ class Region:
             )
             self._frozen.append(frozen)
             flushed_entry_id = self.wal.next_entry_id - 1
-            seq_now = self._seq
+            seq_now = self._memtable_floor_seq = self._seq
         rows = frozen.scan()
         file_id = uuid.uuid4().hex
         meta = write_sst(
@@ -708,6 +726,7 @@ class Region:
         with self._lock:
             _scan_pool.drop(self)
             self._truncate_epoch += 1
+            self._memtable_floor_seq = self._seq
             entry_id = self.wal.next_entry_id - 1
             self.memtable = Memtable(
                 self.meta.field_names,

@@ -102,15 +102,38 @@ def test_device_range_cache_hit_and_invalidation(cpu):
     r2 = inst.sql(q)
     assert next(iter(cache._entries.values())) is entry  # reused
     assert r1.rows() == r2.rows()
-    # a write bumps the data version and invalidates the entry
+    # a write bumps the data version; a row newer than any the entry
+    # holds of its series is a plain append: the entry is brought
+    # forward in place (query/device_range.py `_upkeep`), not evicted
+    appended = _upkeep_count("append")
     inst.execute_sql(
         "insert into cpu (ts, host, region, u, v) "
         "values (400000, 'h0', 'r0', 50.0, 5.0)"
     )
     r3 = inst.sql(q)
-    entry2 = next(iter(cache._entries.values()))
-    assert entry2 is not entry
+    assert next(iter(cache._entries.values())) is entry
+    assert entry.version == inst.catalog.table(
+        "public", "cpu").data_version()
+    assert _upkeep_count("append") == appended + 1
     assert r3.num_rows == r1.num_rows + 1
+    assert r3.rows()[-1][:2] == [400000, "h0"]
+    assert float(r3.rows()[-1][2]) == 50.0
+    # an overwrite is not: the entry is evicted and built again
+    rebuilt = _upkeep_count("rebuild_out_of_order")
+    inst.execute_sql(
+        "insert into cpu (ts, host, region, u, v) "
+        "values (400000, 'h0', 'r0', 70.0, 5.0)"
+    )
+    r4 = inst.sql(q)
+    assert next(iter(cache._entries.values())) is not entry
+    assert _upkeep_count("rebuild_out_of_order") == rebuilt + 1
+    assert float(r4.rows()[-1][2]) == 70.0
+
+
+def _upkeep_count(outcome):
+    from greptimedb_tpu.query.device_range import _UPKEEP
+
+    return _UPKEEP.labels(outcome).value
 
 
 def test_device_range_falls_back_on_residual(cpu):
