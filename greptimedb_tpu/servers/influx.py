@@ -11,12 +11,15 @@ from __future__ import annotations
 import logging
 import time
 from collections import defaultdict
+from itertools import chain
 
 import numpy as np
 
 from greptimedb_tpu.datatypes.schema import ColumnSchema, Schema, SemanticType
 from greptimedb_tpu.datatypes.types import ConcreteDataType
 from greptimedb_tpu.errors import GreptimeError, InvalidArgumentError
+from greptimedb_tpu.telemetry import tracing
+from greptimedb_tpu.telemetry.metrics import global_registry
 
 # exact (numerator, denominator) ms conversion per precision: float
 # scaling at epoch-scale ns values (~1.7e18) rounds the INPUT to
@@ -184,16 +187,6 @@ def _parse_field_value(v: str):
         raise LineProtocolError(f"bad field value {v!r}") from None
 
 
-def _field_type(v) -> ConcreteDataType:
-    if isinstance(v, bool):
-        return ConcreteDataType.bool_()
-    if isinstance(v, int):
-        return ConcreteDataType.int64()
-    if isinstance(v, float):
-        return ConcreteDataType.float64()
-    return ConcreteDataType.string()
-
-
 # native tokenizer (greptimedb_tpu/native/lineproto.c, built by `make -C
 # greptimedb_tpu/native`); the pure-Python parser below is the always-
 # available fallback AND the behavioral spec the C version mirrors
@@ -243,7 +236,9 @@ def write_lines(instance, body: str, *, db: str = "public",
 
     # batch rows per measurement
     per_table: dict[str, list] = defaultdict(list)
-    for m, tags, fields, ts_raw in parse_payload(body):
+    with tracing.child_span("influx.parse"):
+        parsed = parse_payload(body)
+    for m, tags, fields, ts_raw in parsed:
         ts = (now_ms if ts_raw is None
               else int(ts_raw) * num // den)    # exact integer math
         per_table[m].append((tags, fields, ts))
@@ -254,53 +249,116 @@ def write_lines(instance, body: str, *, db: str = "public",
     return total
 
 
-def _write_measurement(instance, db: str, measurement: str, rows) -> int:
-    tag_keys: list[str] = []
-    field_types: dict[str, ConcreteDataType] = {}
-    for tags, fields, _ in rows:
-        for k in tags:
-            if k not in tag_keys:
-                tag_keys.append(k)
-        for k, v in fields.items():
-            t = _field_type(v)
-            prev = field_types.get(k)
-            if prev is None or (prev.id.value == "int64"
-                                and t.id.value == "float64"):
-                field_types[k] = t
-    table = ensure_table(instance, db, measurement, tag_keys, field_types)
+# how a body's field columns became arrays; both read 0 from the start
+_FIELD_COLUMNS = global_registry.counter(
+    "gtpu_influx_field_columns_total",
+    "line-protocol field columns built, by path: typed (one array "
+    "conversion of the column) or mixed (value by value, for a column "
+    "that holds kinds its table type does not take)",
+    labels=("path",),
+)
+_TYPED_COLUMNS = _FIELD_COLUMNS.labels("typed")
+_MIXED_COLUMNS = _FIELD_COLUMNS.labels("mixed")
 
-    n = len(rows)
-    ts = np.fromiter((r[2] for r in rows), np.int64, n)
-    tag_cols = {
-        k: np.asarray([r[0].get(k, "") for r in rows], object)
-        for k in table.tag_names
-    }
-    fields_out = {}
-    valid_out = {}
-    for k in field_types:
-        cs = table.schema.column(k)
-        vals = [r[1].get(k) for r in rows]
-        if cs.data_type.is_string():
-            arr = np.asarray(
-                ["" if v is None else str(v) for v in vals], object
-            )
-        else:
+_NONE = type(None)
+_FIELD_TYPES = {bool: ConcreteDataType.bool_, int: ConcreteDataType.int64,
+                float: ConcreteDataType.float64}
+# by the kind of a column's numpy dtype, the sets of Python types that
+# one array conversion turns into what assigning value by value gives
+_TYPED_KINDS = {"O": ({str},), "b": ({bool},), "f": ({int, float},),
+                "i": ({int}, {float}), "u": ({int}, {float})}
+
+
+def _field_type(present: list, kinds: set) -> ConcreteDataType:
+    """The type a new column gets: the first value's kind stands, and
+    int64 widens to float64 if a later value is a float."""
+    first = type(present[0])
+    if first is int and float in kinds:
+        first = float
+    return _FIELD_TYPES.get(first, ConcreteDataType.string)()
+
+
+def _non_integral(k: str, cs: ColumnSchema, v) -> LineProtocolError:
+    return LineProtocolError(
+        f"field {k!r} is {cs.data_type.name} but got non-integral value {v}")
+
+
+def _typed_column(k: str, cs: ColumnSchema, np_t: np.dtype, present: list,
+                  kinds: set) -> np.ndarray:
+    """A column whose kinds `_TYPED_KINDS` lists for its type, converted
+    at once."""
+    if np_t.kind in "iu" and float in kinds:
+        f = np.array(present, np.float64)
+        with np.errstate(invalid="ignore"):
+            col = f.astype(np_t)
+        # a fraction, and also a NaN, an infinity, a value out of range
+        bad = col != f
+        if bad.any():
+            raise _non_integral(k, cs, present[int(bad.argmax())])
+        return col
+    return np.array(present, np_t)
+
+
+def _mixed_column(k: str, cs: ColumnSchema, np_t: np.dtype,
+                  present: list) -> np.ndarray:
+    """Any other column (a string among floats, a bool among ints),
+    assigned value by value."""
+    if np_t.kind == "O":
+        return np.array([str(v) for v in present], object)
+    is_int = np_t.kind in "iu"
+    col = np.zeros(len(present), np_t)
+    for i, v in enumerate(present):
+        if is_int and isinstance(v, float) and v != int(v):
+            raise _non_integral(k, cs, v)
+        col[i] = v
+    return col
+
+
+def _write_measurement(instance, db: str, measurement: str, rows) -> int:
+    with tracing.child_span("influx.columns"):
+        n = len(rows)
+        tag_dicts, field_dicts, ts = zip(*rows)
+        # keys in first-seen order over the rows
+        tag_keys = list(dict.fromkeys(chain.from_iterable(tag_dicts)))
+        columns = {}
+        field_types = {}
+        for k in dict.fromkeys(chain.from_iterable(field_dicts)):
+            vals = [f.get(k) for f in field_dicts]
+            kinds = set(map(type, vals))
+            valid = None
+            if _NONE in kinds:
+                kinds.discard(_NONE)
+                valid = np.array([v is not None for v in vals])
+                vals = [v for v in vals if v is not None]
+            columns[k] = vals, kinds, valid
+            field_types[k] = _field_type(vals, kinds)
+        table = ensure_table(instance, db, measurement, tag_keys,
+                             field_types)
+
+        ts = np.array(ts, np.int64)
+        tag_cols = {
+            k: np.asarray([t.get(k, "") for t in tag_dicts], object)
+            for k in table.tag_names
+        }
+        fields_out = {}
+        valid_out = {}
+        typed = 0
+        for k, (present, kinds, valid) in columns.items():
+            cs = table.schema.column(k)
             np_t = cs.data_type.to_numpy()
-            is_int = np.issubdtype(np_t, np.integer)
-            arr = np.zeros(n, np_t)
-            for i, v in enumerate(vals):
-                if v is None:
-                    continue
-                if is_int and isinstance(v, float) and v != int(v):
-                    raise LineProtocolError(
-                        f"field {k!r} is {cs.data_type.name} but got "
-                        f"non-integral value {v}"
-                    )
-                arr[i] = v
-        fields_out[k] = arr
-        validity = np.asarray([v is not None for v in vals], bool)
-        if not validity.all():
-            valid_out[k] = validity
+            if any(kinds <= s for s in _TYPED_KINDS[np_t.kind]):
+                typed += 1
+                col = _typed_column(k, cs, np_t, present, kinds)
+            else:
+                col = _mixed_column(k, cs, np_t, present)
+            if valid is not None:
+                # a missing value reads zero, "" in a string column
+                full = np.full(n, "" if np_t.kind == "O" else 0, np_t)
+                full[valid] = col
+                col, valid_out[k] = full, valid
+            fields_out[k] = col
+        _TYPED_COLUMNS.inc(typed)
+        _MIXED_COLUMNS.inc(len(columns) - typed)
     table.write(tag_cols, ts, fields_out, field_valid=valid_out or None)
     data = {table.ts_name: ts, **tag_cols, **fields_out}
     instance._notify_flows(db, measurement, table, data, valid_out)
