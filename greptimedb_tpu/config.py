@@ -216,7 +216,9 @@ DEFAULTS: dict = {
     # compile/execute/transfer spans under a shared trace_id), served
     # by /v1/traces + information_schema.traces. Sampling is
     # TAIL-BASED: slow (>= slow_ms), errored and shed statements are
-    # ALWAYS kept; the rest keep with probability sample_ratio
+    # kept for cause (against sampling, and evicted from the ring after
+    # the traces kept by chance); the rest keep with probability
+    # sample_ratio
     "tracing": {
         "enable": True,
         "sample_ratio": 1.0,    # head probability for unremarkable traces

@@ -419,7 +419,7 @@ class DeviceFlowState:
                 self.state, d_gid, d_hi, d_lo, d_vals, d_has,
                 ops=self.ops, g=self.capacity,
             )
-            dcall.executed(dispatch_only=True)
+            dcall.wait(dispatch_only=True)
         self.dirty[np.unique(gids)] = True
         self.processed += n
 
@@ -438,6 +438,7 @@ class DeviceFlowState:
         """Outside the lock: one finalize program for every group; the
         dirty slice is gathered on device so only it crosses to the
         host. Returns (dirty_gids, {agg_idx: (values, present)})."""
+        from greptimedb_tpu.query import readback
         from greptimedb_tpu.telemetry import device_trace
 
         state, cap, dirty = snap
@@ -448,21 +449,18 @@ class DeviceFlowState:
             outs, pres = dcall.run(
                 _finalize_program, state, ops=self.ops, g=cap
             )
-            outs[0].block_until_ready()
-            dcall.executed()
+            dcall.wait(outs, pres)
             didx = jnp.asarray(dirty.astype(np.int32))
-            per_agg = {}
-            nbytes = 0
-            for j in range(len(self.ops)):
-                v_d = jnp.take(outs[j], didx)
-                p_d = jnp.take(pres[j], didx)
-                # count the DEVICE arrays' bytes: the host copies widen
-                # to float64, which would double the reported transfer
-                # bytes in the platform-float32 device mode
-                nbytes += int(v_d.nbytes) + int(p_d.nbytes)
-                per_agg[j] = (np.asarray(v_d, np.float64),
-                              np.asarray(p_d, bool))
-            dcall.transfer(nbytes)
+            k = len(self.ops)
+            dirty_dev = [jnp.take(a, didx) for a in (*outs, *pres)]
+            # one crossing, in the device's own widths: the host copies
+            # widen to float64 below, which would double the reported
+            # transfer bytes in the platform-float32 device mode
+            host = dcall.read(readback.read_outputs, dirty_dev[0], 0,
+                              dirty_dev[1:])
+            per_agg = {j: (host[j].astype(np.float64),
+                           host[k + j].astype(bool))
+                       for j in range(k)}
         return dirty, per_agg
 
     # ---- demotion ------------------------------------------------------

@@ -409,7 +409,8 @@ def _device_programs_doc(inst) -> dict[str, list]:
 
     cols = [
         "site", "program", "key", "calls", "errors", "compile_ms",
-        "execute_ms_total", "execute_p50_ms", "execute_p99_ms",
+        "execute_ms_total", "dispatch_ms_total", "wait_ms_total",
+        "readback_ms_total", "execute_p50_ms", "execute_p99_ms",
         "device_ms_total", "upload_bytes", "readback_bytes",
         "dispatch_only", "analysis", "analysis_error", "flops",
         "bytes_accessed", "temp_bytes", "output_bytes",

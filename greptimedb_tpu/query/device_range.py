@@ -81,6 +81,7 @@ import functools
 import logging
 import math
 import threading
+import time
 
 from dataclasses import dataclass, field as dc_field
 
@@ -140,6 +141,19 @@ _UPKEEP_ROWS = global_registry.counter(
     "rows scattered into resident range grid planes by the upkeep",
 )
 
+# time spent held at an entry's `_PlaneGate`, stamped only by a caller
+# that actually waits: a query behind the upkeep's donation ("shared"),
+# the upkeep behind the queries reading the planes or another upkeep
+# ("exclusive"). One connection never contends; many will.
+_GATE_WAIT = global_registry.counter(
+    "gtpu_plane_gate_wait_seconds_total",
+    "seconds callers waited at a range grid entry's plane gate, by the "
+    "side they asked for (shared: a query; exclusive: the upkeep)",
+    labels=("side",),
+)
+_GATE_WAIT_SHARED = _GATE_WAIT.labels("shared")
+_GATE_WAIT_EXCLUSIVE = _GATE_WAIT.labels("exclusive")
+
 DEVICE_THRESHOLD = 262_144       # min table rows before the cache pays off
 _CELL_CAP = 256 * 1024 * 1024    # max S*NB cells per cached array (1GB f32)
 _MAX_ENTRIES = 8                 # LRU entry-count cap across all tables
@@ -192,8 +206,11 @@ class _PlaneGate:
     @contextlib.contextmanager
     def shared(self):
         with self._cv:
-            while self._writer:
-                self._cv.wait()
+            if self._writer:
+                t0 = time.monotonic()
+                while self._writer:
+                    self._cv.wait()
+                _GATE_WAIT_SHARED.inc(time.monotonic() - t0)
             self._readers += 1
         try:
             yield
@@ -206,11 +223,15 @@ class _PlaneGate:
     @contextlib.contextmanager
     def exclusive(self):
         with self._cv:
+            t0 = (time.monotonic() if self._writer or self._readers
+                  else None)
             while self._writer:
                 self._cv.wait()
             self._writer = True
             while self._readers:
                 self._cv.wait()
+            if t0 is not None:
+                _GATE_WAIT_EXCLUSIVE.inc(time.monotonic() - t0)
         try:
             yield
         finally:
@@ -1234,8 +1255,6 @@ def precompile_programs(entry: _Entry, table) -> int:
 
 
 def _precompile_loop(entry, doc, entry_mesh, zero_sid):
-    import jax
-
     done = 0
     for s in doc:
         try:
@@ -1292,8 +1311,7 @@ def _precompile_loop(entry, doc, entry_mesh, zero_sid):
                     arrs, entry.nrow, entry.imin, entry.imax, *inputs,
                     spec=spec,
                 )
-                jax.block_until_ready(out)
-                dcall.executed()
+                dcall.wait(out)
             entry.program_specs[spec] = True
             done += 1
         except Exception:  # noqa: BLE001 - best-effort warm
@@ -1663,7 +1681,7 @@ def _apply_append(entry: _Entry, batch: dict) -> None:
                     program, planes, entry.nrow, entry.imin, entry.imax,
                     part, spec=spec)
                 dcall.transfer(part.nbytes, "upload")
-                dcall.executed(dispatch_only=True)
+                dcall.wait(dispatch_only=True)
             for f, _keys, aliased in layout:
                 entry.fields[f].update(planes[f])
                 if aliased:
@@ -2767,9 +2785,9 @@ def execute_range_device(engine, plan, table):
             # only a dispatch brings it back
             out_dev = None
         # device-time attribution: one span per query carrying compile
-        # (first-call vs cache-hit), block_until_ready execute time and
-        # transfer bytes — the transfer cost becomes a named span on the
-        # trace. Attribution comes from device_trace's PROCESS-level memo,
+        # (first-call vs cache-hit), the crossing's legs (dispatch, wait,
+        # readback) and transfer bytes — the transfer cost becomes a
+        # named span on the trace. Attribution comes from device_trace's PROCESS-level memo,
         # matching the jit cache's scope (the entry-level program_specs
         # memo resets with every rebuilt grid entry — e.g. each datanode
         # partial builds a fresh table — and would mislabel warm programs
@@ -2819,8 +2837,7 @@ def execute_range_device(engine, plan, table):
                 out_dev, act_dev, extent_dev = outs
                 if win is None:
                     extras = (act_dev, extent_dev)
-            out_dev.block_until_ready()
-        dcall.executed()
+            dcall.wait(out_dev)
         if dispatch and use_sessions:
             _file(entry, seen, sessions.global_sessions.put,
                   session_tkey, session_key, entry.session_version, out_dev,
@@ -2830,7 +2847,7 @@ def execute_range_device(engine, plan, table):
             # on the rows path: `packed` holds all of it, one array in
             # one crossing; the cursor's steps are cut on the host (K
             # rows: nothing to save)
-            (packed,) = readback.read_outputs(packed_dev, 0)
+            (packed,) = dcall.read(readback.read_outputs, packed_dev, 0)
             out, *extras = _unpack_rows(packed, out_dev.shape,
                                         len(memo["gid_host"]))
             out = out[..., j0_b:]
@@ -2844,10 +2861,9 @@ def execute_range_device(engine, plan, table):
             # in ONE readback (readback.read_outputs feeds
             # gtpu_readback_bytes_total{mode=full|delta}).
             sliced = out_dev if kb or memo["fold"] else out_dev[:, :g]
-            out, *extras = readback.read_outputs(sliced, j0_b, extras,
-                                                 axis=-1)
+            out, *extras = dcall.read(readback.read_outputs, sliced, j0_b,
+                                      extras, axis=-1)
             readback_bytes = out.nbytes + sum(x.nbytes for x in extras)
-        dcall.transfer(readback_bytes, "readback")
         if win is None:
             # (no record means this call dispatched: `seen` is its own)
             win = _fold_window(entry, memo, *extras)

@@ -529,8 +529,8 @@ def _device_reduce_fused(specs, values: dict, gid, valid_map, g: int, ts,
     )
     spec = (gb, blocks, len(mask_arrays), items)
     # device-time attribution at the jit/shard_map call boundary
-    # (telemetry/device_trace): compile first-call vs cache-hit,
-    # block_until_ready execute time, host<->device bytes
+    # (telemetry/device_trace): compile first-call vs cache-hit, the
+    # crossing's legs (dispatch, wait, readback), host<->device bytes
     from greptimedb_tpu.telemetry import device_trace
 
     upload = sum(int(a.nbytes) for a in (
@@ -544,13 +544,11 @@ def _device_reduce_fused(specs, values: dict, gid, valid_map, g: int, ts,
             dcall.transfer(upload, "upload")
             out_b, out_s = dcall.run(prog, d_vals, d_masks, d_gid,
                                      d_tshi, d_tslo, spec=spec)
-            out_b.block_until_ready()
-            dcall.executed()
+            dcall.wait(out_b, out_s)
             from greptimedb_tpu.query import readback as _readback
 
-            out_b = _readback.read_full(out_b, np.float64)
-            out_s = _readback.read_full(out_s, np.float64)
-            dcall.transfer(out_b.nbytes + out_s.nbytes, "readback")
+            out_b = dcall.read(_readback.read_full, out_b, np.float64)
+            out_s = dcall.read(_readback.read_full, out_s, np.float64)
         # reassemble the single-device program's row layout so the host
         # f64 combine below is shared verbatim
         pieces = []
@@ -574,12 +572,10 @@ def _device_reduce_fused(specs, values: dict, gid, valid_map, g: int, ts,
             dcall.transfer(upload, "upload")
             out_dev = dcall.run(_FUSED, d_vals, d_masks, d_gid, d_tshi,
                                 d_tslo, spec=spec)
-            out_dev.block_until_ready()
-            dcall.executed()
+            dcall.wait(out_dev)
             from greptimedb_tpu.query import readback as _readback
 
-            out_mat = _readback.read_full(out_dev, np.float64)
-            dcall.transfer(out_mat.nbytes, "readback")
+            out_mat = dcall.read(_readback.read_full, out_dev, np.float64)
 
     # decode: host f64 combine of the blocked partials
     cnts = []

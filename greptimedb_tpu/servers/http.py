@@ -414,6 +414,12 @@ def _make_handler(instance, user_provider=None, *, enable_scripts=False,
                         "trace_id": tid,
                         "spans": global_traces.trace(tid),
                     })
+                if params.get("slowest") in ("1", "true"):
+                    # the slowest finished trace of each route (and of
+                    # each slow background task), held beside the ring;
+                    # &reset=1 empties the slots once read
+                    return self._json(200, {"slowest": global_traces.slowest(
+                        reset=params.get("reset") in ("1", "true"))})
                 try:
                     limit = int(params.get("limit", "50") or 50)
                 except ValueError:

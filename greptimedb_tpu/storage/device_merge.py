@@ -167,14 +167,11 @@ def _device_merge_indices(rows: ColumnarRows, *, backfill: bool,
             up["seq_lo"], up["op"], np.int32(n), valids,
             drop_deletes=drop_deletes,
         )
-        order_d.block_until_ready()
-        d.executed()
-        order = readback.read_full(order_d, np.int64)
-        keep = readback.read_full(keep_d)
-        fills = {name: readback.read_full(f, np.int64)
+        d.wait(order_d, keep_d, fills_d)
+        order = d.read(readback.read_full, order_d, np.int64)
+        keep = d.read(readback.read_full, keep_d)
+        fills = {name: d.read(readback.read_full, f, np.int64)
                  for name, f in fills_d.items()}
-        d.transfer(order.nbytes + keep.nbytes
-                   + sum(f.nbytes for f in fills.values()))
     keep_idx = order[keep]
     fill_src = None
     if fills:

@@ -10,7 +10,11 @@ into, one row per compiled program (site + static program key):
 
 - per-call stats: calls, compile_ms (wall time of the process's FIRST
   execution, which includes XLA compilation), cumulative execute_ms and
-  p50/p99 from a bucketed histogram, upload/readback bytes;
+  p50/p99 from a bucketed histogram, upload/readback bytes, and the
+  three legs of the crossing as `device_call` stamps them:
+  dispatch_ms (the jit call's own return), wait_ms (the return of
+  block_until_ready, counted from the dispatch's) and readback_ms (the
+  device->host copy); execute_ms = dispatch_ms + wait_ms;
 - XLA analysis (lazy, on first surface consult): ``Lowered.
   cost_analysis()`` flops + bytes accessed, and ``Compiled.
   memory_analysis()`` temp/output/argument bytes. Argument SHAPES are
@@ -126,6 +130,26 @@ _M_EXEC = global_registry.counter(
     "(excludes the first call, whose wall time is compile_ms)",
     labels=("site", "program"),
 )
+_M_DISPATCH = global_registry.counter(
+    "gtpu_device_program_dispatch_ms_total",
+    "cumulative steady-state ms inside the jit call itself per (site, "
+    "program): argument flattening, the host arguments' upload, the "
+    "enqueue",
+    labels=("site", "program"),
+)
+_M_WAIT = global_registry.counter(
+    "gtpu_device_program_wait_ms_total",
+    "cumulative steady-state ms from the jit call's return to the "
+    "return of block_until_ready per (site, program); with "
+    "dispatch_ms it sums to execute_ms",
+    labels=("site", "program"),
+)
+_M_READBACK_MS = global_registry.counter(
+    "gtpu_device_program_readback_ms_total",
+    "cumulative steady-state ms of device->host result readback per "
+    "(site, program)",
+    labels=("site", "program"),
+)
 _M_UPLOAD = global_registry.counter(
     "gtpu_device_program_upload_bytes_total",
     "host->device bytes uploaded by dispatches of (site, program)",
@@ -180,6 +204,18 @@ _M_PCT = global_registry.gauge(
 _M_COUNT = global_registry.gauge(
     "gtpu_device_programs",
     "distinct program rows currently tracked by the profiler",
+)
+
+# the counter families published from the rows, each beside the key of
+# the row's document it carries
+_ROW_COUNTERS = (
+    (_M_CALLS, "calls"),
+    (_M_EXEC, "execute_ms_total"),
+    (_M_DISPATCH, "dispatch_ms_total"),
+    (_M_WAIT, "wait_ms_total"),
+    (_M_READBACK_MS, "readback_ms_total"),
+    (_M_UPLOAD, "upload_bytes"),
+    (_M_READBACK, "readback_bytes"),
 )
 
 OTHER = "_other"
@@ -252,7 +288,8 @@ class _Program:
 
     __slots__ = (
         "site", "prog_id", "key_text", "calls", "compile_ms",
-        "execute_ms_total", "exec_buckets", "upload_bytes",
+        "execute_ms_total", "dispatch_ms_total", "wait_ms_total",
+        "readback_ms_total", "exec_buckets", "upload_bytes",
         "readback_bytes", "dispatch_only", "errors",
         "first_seen_ms", "last_seen_ms",
         "analysis", "analysis_error", "flops", "bytes_accessed",
@@ -267,6 +304,11 @@ class _Program:
         self.calls = 0
         self.compile_ms: float | None = None
         self.execute_ms_total = 0.0
+        # the legs of the steady-state calls (those execute_ms_total
+        # counts): dispatch + wait = execute
+        self.dispatch_ms_total = 0.0
+        self.wait_ms_total = 0.0
+        self.readback_ms_total = 0.0
         self.exec_buckets = [0] * _N_BUCKETS
         self.upload_bytes = 0
         self.readback_bytes = 0
@@ -298,7 +340,8 @@ class _Program:
     # -- folding -------------------------------------------------------
     def fold_call(self, execute_ms: float | None, upload: int,
                   readback: int, *, dispatch_only: bool,
-                  run_start: float | None = None):
+                  run_start: float | None = None,
+                  dispatch_ms: float = 0.0, readback_ms: float = 0.0):
         self.calls += 1
         self.last_seen_ms = int(time.time() * 1000)
         self.upload_bytes += upload
@@ -325,6 +368,9 @@ class _Program:
         if dispatch_only:
             self.dispatch_only = True
         self.execute_ms_total += execute_ms
+        self.dispatch_ms_total += dispatch_ms
+        self.wait_ms_total += execute_ms - dispatch_ms
+        self.readback_ms_total += readback_ms
         _observe(self.exec_buckets, execute_ms)
 
     def fold_row(self, other: "_Program"):
@@ -332,6 +378,9 @@ class _Program:
         self.calls += other.calls
         self.errors += other.errors
         self.execute_ms_total += other.execute_ms_total
+        self.dispatch_ms_total += other.dispatch_ms_total
+        self.wait_ms_total += other.wait_ms_total
+        self.readback_ms_total += other.readback_ms_total
         for i in range(_N_BUCKETS):
             self.exec_buckets[i] += other.exec_buckets[i]
         self.upload_bytes += other.upload_bytes
@@ -393,6 +442,9 @@ class _Program:
             "errors": self.errors,
             "compile_ms": round(self.compile_ms or 0.0, 3),
             "execute_ms_total": round(self.execute_ms_total, 3),
+            "dispatch_ms_total": round(self.dispatch_ms_total, 3),
+            "wait_ms_total": round(self.wait_ms_total, 3),
+            "readback_ms_total": round(self.readback_ms_total, 3),
             "execute_p50_ms": round(self.exec_p50_ms(), 3),
             "execute_p99_ms": round(self.exec_p99_ms(), 3),
             "device_ms_total": round(self.device_ms(), 3),
@@ -519,12 +571,15 @@ class DeviceProgramRegistry:
     def finish(self, row: _Program, *,
                execute_ms: float | None, upload: int, readback: int,
                dispatch_only: bool = False,
-               run_start: float | None = None):
+               run_start: float | None = None,
+               dispatch_ms: float = 0.0, readback_ms: float = 0.0):
         with self._lock:
             cold = row.compile_ms is None
             row.fold_call(execute_ms, upload, readback,
                           dispatch_only=dispatch_only,
-                          run_start=run_start)
+                          run_start=run_start,
+                          dispatch_ms=dispatch_ms,
+                          readback_ms=readback_ms)
             compiled = cold and row.compile_ms is not None
         if compiled:
             # rare (once a program row): the one push-model family here
@@ -705,21 +760,18 @@ class DeviceProgramRegistry:
             lab = (d["site"], mp)
             a = agg.get(lab)
             if a is None:
-                a = agg[lab] = {"calls": 0, "exec": 0.0, "up": 0,
-                                "rb": 0, "doc": None}
-            a["calls"] += d["calls"]
-            a["exec"] += d["execute_ms_total"]
-            a["up"] += d["upload_bytes"]
-            a["rb"] += d["readback_bytes"]
+                a = agg[lab] = dict.fromkeys(
+                    (key for _fam, key in _ROW_COUNTERS), 0)
+                a["doc"] = None
+            for _fam, key in _ROW_COUNTERS:
+                a[key] += d[key]
             if mp == d["program"]:
                 a["doc"] = d
         live: set[tuple[str, str]] = set()
         for lab, a in agg.items():
             live.add(lab)
-            _set_value(_M_CALLS.labels(*lab), a["calls"])
-            _set_value(_M_EXEC.labels(*lab), a["exec"])
-            _set_value(_M_UPLOAD.labels(*lab), a["up"])
-            _set_value(_M_READBACK.labels(*lab), a["rb"])
+            for fam, key in _ROW_COUNTERS:
+                _set_value(fam.labels(*lab), a[key])
             d = a["doc"]
             if d is None:
                 # an over-cap aggregate label: per-program gauges are
@@ -739,7 +791,7 @@ class DeviceProgramRegistry:
         for lab in self._published - live:
             # vanished rows (ADMIN reset / LRU collapse): zero, don't
             # freeze — the surfaces must agree at every scrape
-            for fam in (_M_CALLS, _M_EXEC, _M_UPLOAD, _M_READBACK):
+            for fam, _key in _ROW_COUNTERS:
                 _set_value(fam.labels(*lab), 0)
             for fam in (_M_COMPILE, _M_P50, _M_P99, _M_FLOPS, _M_BYTES,
                         _M_GFLOPS, _M_GBPS, _M_PCT):
@@ -792,6 +844,16 @@ class CaptureBusyError(RuntimeError):
     """A trace capture is already in progress in this process."""
 
 
+_M_CAPTURE = global_registry.counter(
+    "gtpu_device_trace_capture_seconds_total",
+    "wall time of device trace captures by phase: start "
+    "(jax.profiler.start_trace), hold (the window asked for), stop "
+    "(stop_trace: the profile collected and written)",
+    labels=("phase",),
+)
+for _phase in ("start", "hold", "stop"):
+    _M_CAPTURE.labels(_phase)  # exported at 0 from start-up
+
 _capture_seq = itertools.count(1)
 _capture_lock = concurrency.Lock()
 _capture_active = False
@@ -800,7 +862,11 @@ _capture_active = False
 def capture_trace(seconds: float, out_dir: str | None = None) -> dict:
     """Capture `seconds` of device activity via jax.profiler into a
     TensorBoard/perfetto-loadable trace directory. One capture at a
-    time per process (CaptureBusyError otherwise)."""
+    time per process (CaptureBusyError otherwise). The document says
+    what the capture itself took: `start_s` (start_trace), `hold_s`
+    (the window) and `stop_s` (stop_trace), also exported as
+    `gtpu_device_trace_capture_seconds_total{phase}`. The profiler
+    runs with its defaults, Python tracer included."""
     global _capture_active
 
     seconds = float(seconds)
@@ -825,19 +891,28 @@ def capture_trace(seconds: float, out_dir: str | None = None) -> dict:
 
         from greptimedb_tpu.telemetry import tracing
 
+        t0 = time.monotonic()
         jax.profiler.start_trace(path)
         try:
-            # spans, background ticks and collections now also open
-            # `gtpu:<name>` events on their threads' lines: the host's
-            # stages lie on the profiler's clock beside the device's
+            # spans, device calls' legs, background ticks and
+            # collections now also open `gtpu:<name>` events on their
+            # threads' lines: the host's stages lie on the profiler's
+            # clock beside the device's. On before the hold's clock
+            # starts, so the annotations cover the whole window.
             tracing.set_annotating(True)
+            t1 = time.monotonic()
             time.sleep(seconds)
         finally:
+            t2 = time.monotonic()
             tracing.set_annotating(False)
             jax.profiler.stop_trace()
+        t3 = time.monotonic()
     finally:
         with _capture_lock:
             _capture_active = False
+    legs = {"start": t1 - t0, "hold": t2 - t1, "stop": t3 - t2}
+    for phase, took in legs.items():
+        _M_CAPTURE.labels(phase).inc(took)
     files = []
     for root, _dirs, names in os.walk(path):
         for name in names:
@@ -845,6 +920,9 @@ def capture_trace(seconds: float, out_dir: str | None = None) -> dict:
     return {
         "trace_dir": path,
         "seconds": seconds,
+        "start_s": round(legs["start"], 6),
+        "hold_s": round(legs["hold"], 6),
+        "stop_s": round(legs["stop"], 6),
         "files": sorted(files),
     }
 

@@ -17,8 +17,14 @@ Each wrapped call produces one `device.execute` span carrying:
 - compile: "first_call" (this process had not executed this static
   program shape before — the duration includes XLA compilation) or
   "cache_hit" (steady state)
-- execute_ms: time to completion of the device computation
-  (block_until_ready), excluding result readback
+- dispatch_ms / wait_ms / readback_ms: the three legs of the crossing,
+  each stamped where it happens on one monotonic clock — the jit
+  call's own return (argument flattening, the host arguments' upload,
+  the enqueue), the return of block_until_ready, and the device->host
+  copy through query/readback.py
+- execute_ms: dispatch_ms + wait_ms, the time to completion of the
+  device computation counted from the dispatch (a session hit
+  dispatches nothing and reads 0)
 - upload_bytes / readback_bytes: host->device and device->host traffic
   attributable to this call
 - program: the program-registry id (telemetry/device_programs.py);
@@ -27,10 +33,19 @@ Each wrapped call produces one `device.execute` span carrying:
 
 Dispatching THROUGH `device_call.run(fn, *args, **kw)` additionally
 folds the call into the process-wide device-program registry
-(telemetry/device_programs.py): calls, compile/execute timing, transfer
-bytes, and the argument shape specs the lazy XLA cost analysis lowers
-against. A session hit that skips the dispatch keeps its span but does
-NOT count as a program call — the registry describes real dispatches.
+(telemetry/device_programs.py): calls, compile time, the three legs,
+transfer bytes, and the argument shape specs the lazy XLA cost analysis
+lowers against. A session hit that skips the dispatch keeps its span
+but does NOT count as a program call — the registry describes real
+dispatches.
+
+While a device trace capture runs each leg also opens (`tracing.event`)
+`gtpu:device.dispatch`, `gtpu:device.wait` or `gtpu:device.readback`
+(with `site`) on the profiler's clock, inside
+the span's `gtpu:device.execute`: beside the `XLA Modules` line one
+capture shows a call's launch lag (the start of the dispatch to the
+module's start) and its wake lag (the module's end to the end of the
+wait). Off a capture that is one boolean test a leg.
 """
 
 from __future__ import annotations
@@ -61,19 +76,36 @@ def note_compile(site: str, key) -> str:
         return "first_call"
 
 
+def _host_nbytes(out) -> int:
+    """Bytes of what a readback returned: one host array
+    (`read_full`, `read_delta`) or a tuple of them (`read_outputs`)."""
+    if isinstance(out, tuple):
+        return sum(int(x.nbytes) for x in out)
+    return int(out.nbytes)
+
+
 class device_call:
     """`with device_trace.device_call("range", key=spec) as d:` — wraps
-    one jit/shard_map invocation. The span duration covers dispatch +
-    execute + readback; dispatch the program via `d.run(fn, *args,
-    **kw)` so it registers with the device-program profiler, call
-    `d.executed()` right after block_until_ready so execute time splits
-    from readback (pass `dispatch_only=True` when the caller
-    deliberately does not block — async flow applies), and
-    `d.transfer(nbytes, "upload"|"readback")` for host<->device
-    transfer bytes."""
+    one jit/shard_map invocation and stamps the three legs of its
+    crossing where they happen:
 
-    __slots__ = ("_cm", "_span", "_mono0", "site", "_stmt", "key",
-                 "_rec", "_first", "_run_t0", "_exec_ms", "_up", "_rb",
+    - `out = d.run(fn, *args, **kw)` dispatches the program (it
+      registers with the device-program profiler) and stamps the jit
+      call's return: the dispatch;
+    - `d.wait(out)` blocks until the outputs are ready and stamps that
+      return: the wait (`d.wait(dispatch_only=True)` where the caller
+      deliberately does not block — the grid's upkeep, the flow
+      applies — so the timing covers the dispatch, not the computation);
+    - `host = d.read(readback.read_full, out)` runs one crossing of
+      query/readback.py, stamps its return and counts the bytes it
+      brought back: the readback;
+    - `d.transfer(nbytes, "upload")` for the host arguments' bytes.
+
+    A session hit calls none of the first two: its legs read 0."""
+
+    __slots__ = ("_cm", "_span", "site", "_stmt", "key",
+                 "_rec", "_first", "_run_t0", "_disp_t1", "_disp_ms",
+                 "_wait_ms", "_rb_ms", "_exec_ms", "_up", "_rb",
                  "_dispatch_only")
 
     def __init__(self, site: str, *, key=None, **attrs):
@@ -82,6 +114,10 @@ class device_call:
         self._rec = None
         self._first = False
         self._run_t0 = 0.0
+        self._disp_t1 = 0.0
+        self._disp_ms = 0.0
+        self._wait_ms = 0.0
+        self._rb_ms = 0.0
         self._exec_ms = None
         self._up = 0
         self._rb = 0
@@ -106,19 +142,18 @@ class device_call:
         else:
             self._cm = tracing.child_span("device.execute")
         self._span = None
-        self._mono0 = 0.0
 
     def __enter__(self) -> "device_call":
         self._span = self._cm.__enter__()
-        self._mono0 = time.monotonic()
         return self
 
     def run(self, fn, *args, **kw):
         """Dispatch the program. Registers (site, key) with the
         device-program registry — first dispatch captures the argument
         shape specs for the lazy XLA cost analysis — and anchors the
-        execute timer at the dispatch, so session lookups before it
-        never count as device time."""
+        one clock of the legs at the dispatch, so session lookups
+        before it never count as device time. The jit call's return
+        closes the dispatch leg."""
         from greptimedb_tpu.telemetry import device_programs
 
         reg = device_programs.global_programs
@@ -126,21 +161,46 @@ class device_call:
             prep = reg.prepare(self.site, self.key, fn, args, kw)
             if prep is not None:
                 self._rec, self._first = prep
-        self._run_t0 = time.monotonic()
-        return fn(*args, **kw)
+        with tracing.event("device.dispatch", site=self.site):
+            self._run_t0 = time.monotonic()
+            out = fn(*args, **kw)
+            self._disp_t1 = time.monotonic()
+        self._disp_ms = (self._disp_t1 - self._run_t0) * 1000.0
+        return out
 
-    def executed(self, *, dispatch_only: bool = False):
-        """Mark the device computation complete (call right after
-        block_until_ready); the remainder of the span is readback.
-        dispatch_only=True records that the caller did NOT block — the
-        timing covers dispatch, not the computation — so the profiler
-        suppresses achieved-rate claims for this program."""
+    def wait(self, *outputs, dispatch_only: bool = False):
+        """Block until `outputs` (arrays, or trees of them) are ready
+        and close the wait leg, counted from the dispatch's return: the
+        device computation is complete and what is left of the span is
+        readback. dispatch_only=True records that the caller does NOT
+        block — the timing covers the dispatch, not the computation —
+        so the profiler suppresses achieved-rate claims for this
+        program."""
+        if not dispatch_only:
+            import jax
+
+            with tracing.event("device.wait", site=self.site):
+                jax.block_until_ready(outputs)
         now = time.monotonic()
-        self._exec_ms = (now - (self._run_t0 or self._mono0)) * 1000.0
+        if not self._disp_t1:
+            # no dispatch through run(): nothing to split
+            self._disp_t1 = self._run_t0 = now
+        self._wait_ms = (now - self._disp_t1) * 1000.0
+        self._exec_ms = self._disp_ms + self._wait_ms
         self._dispatch_only = dispatch_only
-        self._span.attributes["execute_ms"] = round(
-            (now - self._mono0) * 1000.0, 3
-        )
+
+    def read(self, fn, *args, **kw):
+        """One device->host crossing: `fn` is a helper of
+        query/readback.py (`read_outputs`, `read_full`, `read_delta`),
+        called with `args`. Its return closes (one part of) the
+        readback leg; the bytes of the host arrays it returned count as
+        this call's readback."""
+        with tracing.event("device.readback", site=self.site):
+            t0 = time.monotonic()
+            out = fn(*args, **kw)
+            self._rb_ms += (time.monotonic() - t0) * 1000.0
+        self.transfer(_host_nbytes(out), "readback")
+        return out
 
     def transfer(self, nbytes: int, direction: str = "readback"):
         nbytes = int(nbytes)
@@ -173,6 +233,8 @@ class device_call:
         reg = device_programs.global_programs
         if dispatched:
             reg.finish(rec, execute_ms=self._exec_ms,
+                       dispatch_ms=self._disp_ms,
+                       readback_ms=self._rb_ms,
                        upload=self._up, readback=self._rb,
                        dispatch_only=self._dispatch_only,
                        run_start=self._run_t0 or None)
@@ -246,6 +308,12 @@ class device_call:
         if rec is not None:
             self._fold_program(sp, rec, dispatched=dispatched)
         if sp is not None and sp.trace_id:
+            # the legs of this call, as the registry row folds them
+            attrs = sp.attributes
+            attrs["dispatch_ms"] = round(self._disp_ms, 3)
+            attrs["wait_ms"] = round(self._wait_ms, 3)
+            attrs["readback_ms"] = round(self._rb_ms, 3)
+            attrs["execute_ms"] = round(self._disp_ms + self._wait_ms, 3)
             # per-query device-bytes attribution: the HBM pinned by the
             # registered device pools at the moment this call finished
             # (telemetry/memory.py ledger), so every device.* span on a

@@ -21,10 +21,13 @@ spans under a shared trace_id.
 Sampling is TAIL-BASED: every span records while in flight, and the
 keep/drop decision happens when the process-local root span finishes —
 error traces, slow traces (>= slow_ms) and explicitly marked traces
-(`mark_keep`) are ALWAYS kept; the rest keep with probability
-`sample_ratio`. `[tracing]` TOML knobs: enable, sample_ratio, capacity
-(trace ring size; 0 = unbounded: the ring then grows with every kept
-trace), slow_ms.
+(`mark_keep`) are kept FOR CAUSE: against sampling, and in the ring
+past the traces kept by chance (which are evicted first; see
+`_TraceStore`); the rest keep with probability `sample_ratio`. The
+slowest finished trace of each local-root name is held beside the ring
+(`/v1/traces?slowest=1`). `[tracing]` TOML knobs: enable, sample_ratio,
+capacity (trace ring size; 0 = unbounded: the ring then grows with
+every kept trace), slow_ms.
 
 Timestamps: `start_ms` is epoch milliseconds (display/correlation);
 durations are computed on the MONOTONIC clock (an NTP slew must never
@@ -256,6 +259,21 @@ def _annotate(name: str):
     return ann
 
 
+_NO_EVENT = contextlib.nullcontext()
+
+
+def event(name: str, **meta):
+    """`with tracing.event("device.wait", site=...)`: a `gtpu:<name>`
+    event on the profiler's clock while a capture runs (`meta` rides it
+    as the event's stats), nothing off it. For what is timed without a
+    span of its own: the legs of a device call."""
+    if not _annotating:
+        return _NO_EVENT
+    import jax
+
+    return jax.profiler.TraceAnnotation("gtpu:" + name, **meta)
+
+
 @dataclass(slots=True)
 class Span:
     trace_id: str
@@ -295,18 +313,37 @@ class Span:
 
 
 class _TraceStore:
-    """Bounded ring of traces (newest kept). Spans record at START so
-    /v1/traces shows in-flight work; the tail-sampling decision at the
-    local root's finish either confirms the trace or drops it."""
+    """Bounded ring of traces. Spans record at START so /v1/traces
+    shows in-flight work; the tail-sampling decision at the local
+    root's finish either confirms the trace or drops it.
+
+    What the ring keeps, and for how long: a trace kept FOR CAUSE (an
+    error span, a root at or over `slow_ms`, `mark_keep`) is kept
+    against sampling and is the last to be evicted — traces in flight
+    or kept by chance go first, oldest first, and traces kept for cause
+    go oldest first only once they fill three quarters of the ring (so
+    a storm of errors cannot starve the ring of recent traffic). Beside
+    the ring, outside its count, the store holds the SLOWEST finished
+    trace of each local-root name (one slot a name; names are bounded,
+    see the module's docstring), served by `/v1/traces?slowest=1`:
+    after any run one GET names the stage that held the worst request
+    of each route, however many requests came after it."""
 
     def __init__(self, cap: int = _MAX_TRACES):
         self._lock = concurrency.Lock()
-        # insertion-ordered: the oldest trace is the first key, so
-        # eviction and the sampled-out drop are both O(1)
+        # in flight or kept by chance. Insertion-ordered: the oldest
+        # trace is the first key, so eviction and the sampled-out drop
+        # are both O(1)
         self._spans: collections.OrderedDict[str, list[Span]] = (
             collections.OrderedDict()
         )
+        # kept for cause, in the order they were decided
+        self._held: collections.OrderedDict[str, list[Span]] = (
+            collections.OrderedDict()
+        )
         self._kept: set[str] = set()
+        # local-root name -> (duration_ms, trace_id, the trace's spans)
+        self._slowest: dict[str, tuple[float, str, list[Span]]] = {}
         # local roots currently in flight per trace (a client may send
         # one traceparent on several concurrent requests): a sampled-
         # out sibling must never drop a trace another root is still
@@ -333,6 +370,7 @@ class _TraceStore:
         # scrape-time work under the lock every span takes
         with background_span("trace_ring.stats"), self._lock:
             n_spans = sum(len(s) for s in self._spans.values())
+            n_spans += sum(len(s) for s in self._held.values())
             return {
                 "bytes": n_spans * self.SPAN_EST_BYTES,
                 "entries": n_spans,
@@ -349,8 +387,11 @@ class _TraceStore:
     def _evict_locked(self):
         if self.cap <= 0:
             return  # unbounded: nothing is ever evicted
-        while len(self._spans) > self.cap:
-            victim, _ = self._spans.popitem(last=False)
+        held_max = self.cap - self.cap // 4
+        while len(self._spans) + len(self._held) > self.cap:
+            ring = (self._held if len(self._held) > held_max
+                    or not self._spans else self._spans)
+            victim, _ = ring.popitem(last=False)
             self._kept.discard(victim)
             self.evicted_traces += 1
 
@@ -366,9 +407,12 @@ class _TraceStore:
                 parent_sink.append(span)
             return
         with self._lock:
-            spans = self._spans.get(span.trace_id)
+            tid = span.trace_id
+            spans = self._spans.get(tid)
             if spans is None:
-                spans = self._spans[span.trace_id] = []
+                spans = self._held.get(tid)
+            if spans is None:
+                spans = self._spans[tid] = []
                 self._evict_locked()
             if len(spans) < self.MAX_SPANS_PER_TRACE:
                 spans.append(span)
@@ -380,33 +424,48 @@ class _TraceStore:
 
     def decide(self, root: Span):
         """Tail-sampling decision at a local root's finish: error spans
-        anywhere in the trace, slow roots, and marked traces always
-        keep; otherwise keep with probability sample_ratio. A drop only
-        happens when NO other local root of the trace is in flight."""
+        anywhere in the trace, slow roots, and marked traces keep for
+        cause (against sampling, and past the traces kept by chance);
+        otherwise keep with probability sample_ratio. A drop only
+        happens when NO other local root of the trace is in flight.
+        The slowest root of each name keeps its trace in that name's
+        slot whatever was decided."""
         tid = root.trace_id
+        took_ms = (0.0 if root.end_ms is None
+                   else root.end_ms - root.start_ms)
         with self._lock:
             remaining = self._active.get(tid, 1) - 1
             if remaining > 0:
                 self._active[tid] = remaining
             else:
                 self._active.pop(tid, None)
-            if tid in self._kept:
-                return
-            spans = self._spans.get(tid)
+            # the root's own list: there also once the ring has turned
+            # past a request still in flight (a stalled one, under load)
+            spans = root.sink
             if spans is None:
                 return
-            keep = False
-            for s in spans:
-                if "error" in s.attributes or s.attributes.get("keep"):
-                    keep = True
-                    break
-            if not keep and root.end_ms is not None and (
-                    root.end_ms - root.start_ms) >= _config.slow_ms:
-                keep = True
-            if not keep:
-                ratio = _config.sample_ratio
-                keep = ratio >= 1.0 or random.random() < ratio
-            if keep:
+            slowest = self._slowest.get(root.name)
+            if root.end_ms is not None and (
+                    slowest is None or took_ms > slowest[0]):
+                self._slowest[root.name] = (took_ms, tid, spans)
+            if tid in self._held:
+                return
+            cause = took_ms >= _config.slow_ms and root.end_ms is not None
+            if not cause:
+                for s in spans:
+                    if "error" in s.attributes or s.attributes.get("keep"):
+                        cause = True
+                        break
+            if cause:
+                self._spans.pop(tid, None)
+                self._kept.discard(tid)     # by chance no longer
+                self._held[tid] = spans
+                self._evict_locked()
+                return
+            if tid in self._kept or tid not in self._spans:
+                return
+            ratio = _config.sample_ratio
+            if ratio >= 1.0 or random.random() < ratio:
                 self._kept.add(tid)
             elif remaining <= 0:
                 # last root out and nothing remarkable: drop. With
@@ -414,7 +473,6 @@ class _TraceStore:
                 # over the COMPLETE span set (an error recorded later
                 # must still be able to keep the trace).
                 self._spans.pop(tid, None)
-                self._kept.discard(tid)
 
     def ingest(self, span_dicts: list, limit: int = _MAX_EXPORT_SPANS):
         """Record spans exported by ANOTHER process (gtdb:spans
@@ -438,28 +496,46 @@ class _TraceStore:
                 continue  # a malformed remote span must not kill a query
 
     def traces(self, limit: int = 50) -> list[dict]:
+        """The ring's traces, newest first (by their first span)."""
         with self._lock:
-            out = []
-            newest = reversed(self._spans)
+            both = [*self._spans.items(), *self._held.items()]
+            both.sort(key=lambda kv: kv[1][0].start_ms if kv[1] else 0.0,
+                      reverse=True)
             if limit > 0:
-                newest = itertools.islice(newest, int(limit))
-            for tid in newest:
-                spans = self._spans[tid]
-                out.append({
-                    "trace_id": tid,
-                    "spans": [s.to_json() for s in spans],
-                })
-            return out
+                both = both[:int(limit)]
+            return [{"trace_id": tid,
+                     "spans": [s.to_json() for s in spans]}
+                    for tid, spans in both]
 
     def trace(self, trace_id: str) -> list[dict]:
         with self._lock:
-            return [s.to_json() for s in self._spans.get(trace_id, [])]
+            spans = (self._spans.get(trace_id)
+                     or self._held.get(trace_id) or [])
+            return [s.to_json() for s in spans]
+
+    def slowest(self, *, reset: bool = False) -> list[dict]:
+        """The slowest finished trace of each local-root name, slowest
+        first: held outside the ring's count, so it is there however
+        many requests came after it. `reset` empties the slots once
+        read: what is read next is the worst since (a warm-up's cold
+        first query would otherwise hold its route's slot for good)."""
+        with self._lock:
+            slots = sorted(self._slowest.items(),
+                           key=lambda kv: kv[1][0], reverse=True)
+            if reset:
+                self._slowest = {}
+            return [{"name": name, "duration_ms": round(took_ms, 3),
+                     "trace_id": tid,
+                     "spans": [s.to_json() for s in spans]}
+                    for name, (took_ms, tid, spans) in slots]
 
     def clear(self):
         with self._lock:
             self._spans.clear()
+            self._held.clear()
             self._kept.clear()
             self._active.clear()
+            self._slowest.clear()
 
 
 global_traces = _TraceStore()

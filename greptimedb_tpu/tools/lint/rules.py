@@ -16,6 +16,7 @@ from greptimedb_tpu.tools.lint import callgraph
 from greptimedb_tpu.tools.lint.core import (
     FileContext,
     Rule,
+    _looks_like_device_call,
     dotted_name,
     register,
     traced_value_use,
@@ -735,19 +736,25 @@ class FullBufferReadback(Rule):
     name = "full-buffer-readback"
     description = (
         "np.asarray()/jax.device_get() on a device result buffer (a "
-        "name this function called .block_until_ready() on) reads the "
+        "name this function called .block_until_ready() on, or waited "
+        "for through a device_call's `d.wait(name)`) reads the "
         "WHOLE buffer back in one device->host transfer, "
         "unattributed. Route result readbacks through "
         "query/readback.read_full (bytes land on "
         "gtpu_readback_bytes_total) or read_delta (a since-cursor poll "
-        "slices device-side and ships only the unseen rows)."
+        "slices device-side and ships only the unseen rows), inside a "
+        "device_call as `d.read(readback.read_full, name)`."
     )
 
     @staticmethod
     def _scan_blocked(scope, *, skip_nested: bool) -> set[str]:
         """Names `X` with an `X.block_until_ready()` call in `scope`'s
-        own statements — the device-result-buffer idiom."""
+        own statements, or passed to the `.wait(...)` of a handle bound
+        by `with device_call(...) as d` — the device-result-buffer
+        idioms."""
         names: set[str] = set()
+        waited: list[tuple[str, list[str]]] = []
+        handles: set[str] = set()
         stack = list(ast.iter_child_nodes(scope))
         while stack:
             node = stack.pop()
@@ -755,12 +762,26 @@ class FullBufferReadback(Rule):
                                                  ast.AsyncFunctionDef,
                                                  ast.Lambda)):
                 continue
+            if isinstance(node, (ast.With, ast.AsyncWith)):
+                handles.update(
+                    item.optional_vars.id for item in node.items
+                    if _looks_like_device_call(item.context_expr)
+                    and isinstance(item.optional_vars, ast.Name)
+                )
             if (isinstance(node, ast.Call)
                     and isinstance(node.func, ast.Attribute)
-                    and node.func.attr == "block_until_ready"
                     and isinstance(node.func.value, ast.Name)):
-                names.add(node.func.value.id)
+                if node.func.attr == "block_until_ready":
+                    names.add(node.func.value.id)
+                elif node.func.attr == "wait":
+                    waited.append((node.func.value.id, [
+                        a.id for a in node.args
+                        if isinstance(a, ast.Name)
+                    ]))
             stack.extend(ast.iter_child_nodes(node))
+        for handle, args in waited:
+            if handle in handles:
+                names.update(args)
         return names
 
     def _blocked_names(self, ctx: FileContext) -> set[str]:
@@ -1677,11 +1698,13 @@ def run(program, arrs):
     return np.asarray(out)
 ''', '''\
 from greptimedb_tpu.query import readback
+from greptimedb_tpu.telemetry import device_trace
 
 def run(program, arrs, j0):
-    out = program(arrs)
-    out.block_until_ready()
-    return readback.read_delta(out, j0, axis=-1)
+    with device_trace.device_call("site", key=("k",)) as d:
+        out = d.run(program, arrs)
+        d.wait(out)
+        return d.read(readback.read_delta, out, j0, axis=-1)
 '''),
     "GT016": ('''\
 from collections import OrderedDict

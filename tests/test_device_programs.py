@@ -5,6 +5,7 @@ analysis, 3-surface agreement (information_schema ==
 reset, mesh twins not cross-served, on-demand trace capture, and the
 statement-statistics program link."""
 
+import functools
 import json
 import urllib.request
 
@@ -284,7 +285,7 @@ def test_lru_collapse_into_other_keeps_totals(registry):
     def dispatch(i):
         with device_trace.device_call("t", key=("t", i)) as d:
             out = d.run(lambda x: x, jnp.zeros(4))
-            d.executed()
+            d.wait(out)
             d.transfer(16)
         return out
 
@@ -313,8 +314,7 @@ def test_metric_label_cap_collapses_to_other(registry):
     registry._metric_progs.clear()
     for i in range(4):
         with device_trace.device_call("mc", key=("mc", i)) as d:
-            d.run(lambda x: x, jnp.zeros(2))
-            d.executed()
+            d.wait(d.run(lambda x: x, jnp.zeros(2)))
             d.transfer(8)
     global_registry.render()
     calls = global_registry.get("gtpu_device_program_calls_total")
@@ -331,6 +331,177 @@ def test_metric_label_cap_collapses_to_other(registry):
     docs = [d for d in registry.snapshot(analyze=False)
             if d["site"] == "mc"]
     assert len(docs) == 4
+
+
+# ---------------------------------------------------------------------------
+# the crossing's three legs: dispatch, wait, readback (PR 37)
+# ---------------------------------------------------------------------------
+
+@functools.cache
+def _crossing_prog():
+    import jax
+
+    return jax.jit(lambda x: (x * 2.0).sum(axis=0))
+
+
+def _crossing(site, *, dispatch_only=False, read=True):
+    """One real dispatch on the CPU through the whole call boundary."""
+    import jax.numpy as jnp
+
+    from greptimedb_tpu.query import readback
+    from greptimedb_tpu.telemetry import device_trace
+
+    with device_trace.device_call(site, key=(site,)) as d:
+        out = d.run(_crossing_prog(), jnp.ones((64, 128), jnp.float32))
+        d.wait(out, dispatch_only=dispatch_only)
+        if read:
+            host = d.read(readback.read_full, out)
+            assert host.shape == (128,) and host[0] == 128.0
+    return d
+
+
+def test_the_three_legs_fold_into_the_row(registry):
+    registry.config = DP.ProfilingConfig(analysis=False)
+    # the first call is the compile: its wall time is compile_ms and it
+    # stays out of all three legs, exactly as out of execute_ms
+    _crossing("legs")
+    (row,) = [r for r in registry._rows.values() if r.site == "legs"]
+    assert row.calls == 1 and row.compile_ms > 0
+    assert (row.execute_ms_total, row.dispatch_ms_total,
+            row.wait_ms_total, row.readback_ms_total) == (0, 0, 0, 0)
+    calls = [_crossing("legs") for _ in range(5)]
+    assert row.calls == 6
+    assert row.dispatch_ms_total > 0 and row.readback_ms_total > 0
+    assert row.wait_ms_total >= 0
+    # one clock: dispatch + wait is execute, to the float's rounding
+    assert row.dispatch_ms_total + row.wait_ms_total == pytest.approx(
+        row.execute_ms_total, rel=1e-9)
+    # and the row holds what the calls stamped
+    assert row.dispatch_ms_total == pytest.approx(
+        sum(d._disp_ms for d in calls))
+    assert row.readback_ms_total == pytest.approx(
+        sum(d._rb_ms for d in calls))
+    assert row.readback_bytes == 6 * 128 * 4
+    doc = [d for d in registry.snapshot(analyze=False)
+           if d["site"] == "legs"][0]
+    for leg in ("dispatch", "wait", "readback"):
+        assert doc[f"{leg}_ms_total"] == pytest.approx(
+            getattr(row, f"{leg}_ms_total"), abs=1e-3)
+    assert not doc["dispatch_only"]
+
+
+def test_a_dispatch_only_call_folds_its_dispatch_and_no_wait(registry):
+    """The upkeep and the flow applies do not block: the wait leg is
+    what lies between the jit call's return and `wait`'s, a few
+    microseconds, and the row says its timing is the dispatch's."""
+    registry.config = DP.ProfilingConfig(analysis=False)
+    _crossing("legs_async", dispatch_only=True, read=False)
+    d = _crossing("legs_async", dispatch_only=True, read=False)
+    (row,) = [r for r in registry._rows.values()
+              if r.site == "legs_async"]
+    assert row.dispatch_only and row.calls == 2
+    assert row.dispatch_ms_total == pytest.approx(d._disp_ms) and \
+        d._disp_ms > 0
+    assert 0 <= row.wait_ms_total < row.dispatch_ms_total
+    assert row.readback_ms_total == 0
+    assert row.dispatch_ms_total + row.wait_ms_total == pytest.approx(
+        row.execute_ms_total, rel=1e-9)
+
+
+def _leg_series(site):
+    global_registry.render()   # refresh the pull-model families
+    out = {}
+    for leg in ("dispatch", "wait", "readback"):
+        fam = global_registry.get(f"gtpu_device_program_{leg}_ms_total")
+        out[leg] = {key: child.value for key, child in fam._snapshot()
+                    if key[0] == site}
+    return out
+
+
+def test_metrics_export_the_legs_and_admin_reset_zeroes_them(
+        inst, server, registry):
+    _seed(inst)
+    for _ in range(3):
+        inst.sql(RANGE_Q)
+    (doc,) = [d for d in registry.snapshot(analyze=False)
+              if d["site"] == "range"]
+    legs = _leg_series("range")
+    for leg in ("dispatch", "wait", "readback"):
+        # labelled (site, program), equal to the row like the neighbours
+        assert legs[leg] == {("range", doc["program"]):
+                             doc[f"{leg}_ms_total"]}, leg
+        assert doc[f"{leg}_ms_total"] > 0 or leg == "wait"
+    _status, text = _get(server, "/metrics")
+    assert ('gtpu_device_program_dispatch_ms_total{site="range",'
+            f'program="{doc["program"]}"}}') in text
+    r = inst.sql("SELECT dispatch_ms_total, wait_ms_total, "
+                 "readback_ms_total, execute_ms_total FROM "
+                 "information_schema.device_programs WHERE site = 'range'")
+    (d_ms, w_ms, r_ms, e_ms), = r.rows()
+    assert (d_ms, w_ms, r_ms) == tuple(
+        doc[f"{leg}_ms_total"] for leg in ("dispatch", "wait", "readback"))
+    assert d_ms + w_ms == pytest.approx(e_ms, abs=2e-3)
+    inst.sql("admin reset_device_profiler()")
+    for leg, series in _leg_series("range").items():
+        assert set(series.values()) == {0.0}, leg
+
+
+def test_plane_gate_counts_only_a_wait_that_happened():
+    """`gtpu_plane_gate_wait_seconds_total{side}`: an uncontended
+    `shared()` adds nothing; one held behind an `exclusive()` adds the
+    time it was held."""
+    import threading
+    import time
+
+    from greptimedb_tpu.query import device_range as DR
+
+    gate = DR._PlaneGate()
+    shared0 = DR._GATE_WAIT_SHARED.value
+    excl0 = DR._GATE_WAIT_EXCLUSIVE.value
+    with gate.shared():
+        pass
+    with gate.exclusive():
+        pass
+    assert DR._GATE_WAIT_SHARED.value == shared0
+    assert DR._GATE_WAIT_EXCLUSIVE.value == excl0
+    inside = threading.Event()
+    waited = []
+
+    def reader():
+        inside.wait(10)
+        t0 = time.monotonic()
+        with gate.shared():
+            waited.append(time.monotonic() - t0)
+
+    th = threading.Thread(target=reader)
+    th.start()
+    with gate.exclusive():
+        inside.set()
+        time.sleep(0.2)
+    th.join(10)
+    got = DR._GATE_WAIT_SHARED.value - shared0
+    assert 0.1 <= got <= waited[0] + 1e-3
+    # and the other side: the upkeep behind a query holding the planes
+    release = threading.Event()
+    holding = threading.Event()
+
+    def query():
+        with gate.shared():
+            holding.set()
+            release.wait(10)
+
+    th = threading.Thread(target=query)
+    th.start()
+    holding.wait(10)
+    threading.Timer(0.15, release.set).start()
+    with gate.exclusive():
+        pass
+    th.join(10)
+    assert DR._GATE_WAIT_EXCLUSIVE.value - excl0 >= 0.1
+    _status_text = global_registry.render()
+    for side in ("shared", "exclusive"):
+        assert ('gtpu_plane_gate_wait_seconds_total{side="%s"}' % side
+                in _status_text)
 
 
 # ---------------------------------------------------------------------------
@@ -426,8 +597,17 @@ def test_debug_route_bad_params(server):
 def test_trace_capture_writes_loadable_trace(tmp_path, inst, registry):
     _seed(inst)
     inst.sql(RANGE_Q)
+    import time
+
+    t0 = time.monotonic()
     doc = DP.capture_trace(0.2, str(tmp_path / "traces"))
+    wall = time.monotonic() - t0
     assert doc["seconds"] == 0.2
+    # the capture's own legs: starting the profiler, the window asked
+    # for, stopping it (the profile collected and written)
+    assert doc["hold_s"] >= 0.2 and doc["start_s"] > 0 and doc["stop_s"] > 0
+    assert wall - 0.05 <= (doc["start_s"] + doc["hold_s"]
+                           + doc["stop_s"]) <= wall
     assert doc["trace_dir"].startswith(str(tmp_path / "traces"))
     # jax.profiler wrote a TensorBoard/perfetto-loadable capture
     assert any(f.endswith((".xplane.pb", ".trace.json.gz"))

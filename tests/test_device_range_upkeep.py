@@ -271,17 +271,17 @@ def test_a_query_overtaken_by_an_append_leaves_no_stale_record(
     live = _q(where=f"WHERE ts >= {40 * STEP} AND ts < {(TICKS + 5) * STEP}")
     other = _q(where=f"WHERE ts >= {30 * STEP} AND ts < {(TICKS + 5) * STEP}")
     inst.sql(other)
-    real = device_trace.device_call.executed
+    real = device_trace.device_call.wait
     overtaken = []
 
-    def executed(self, *, dispatch_only=False):
+    def wait(self, *outputs, dispatch_only=False):
         if not dispatch_only and not overtaken:
             overtaken.append(True)
             _write(tab, rng, [TICKS])
             inst.sql(other)
-        return real(self, dispatch_only=dispatch_only)
+        return real(self, *outputs, dispatch_only=dispatch_only)
 
-    monkeypatch.setattr(device_trace.device_call, "executed", executed)
+    monkeypatch.setattr(device_trace.device_call, "wait", wait)
     r_a = inst.sql(live)
     monkeypatch.undo()
     assert overtaken and _count("append") >= 1

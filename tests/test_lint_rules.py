@@ -668,6 +668,38 @@ def test_gt015_positive_asarray_and_device_get():
     assert hits == [("GT015", 7)]
 
 
+def test_gt015_positive_waited_through_a_device_call():
+    # `d.wait(out)` is the block: the name is a device result buffer
+    hits = rules_hit("""
+        import numpy as np
+        from greptimedb_tpu.telemetry import device_trace
+
+        def run(program, arrs):
+            with device_trace.device_call("site") as d:
+                out, side = d.run(program, arrs)
+                d.wait(out, side)
+                return np.asarray(side)
+    """, select="GT015")
+    assert hits == [("GT015", 9)]
+
+
+def test_gt015_negative_read_through_a_device_call():
+    assert rules_hit("""
+        import numpy as np
+        from greptimedb_tpu.query import readback
+        from greptimedb_tpu.telemetry import device_trace
+
+        def run(program, arrs, cv, timeout):
+            with device_trace.device_call("site") as d:
+                out = d.run(program, arrs)
+                d.wait(out)
+                host = d.read(readback.read_full, out)
+            # a wait that is no device_call's names no device buffer
+            cv.wait(timeout)
+            return host, np.asarray(timeout)
+    """, select="GT015") == []
+
+
 def test_gt015_negative_helper_and_host_arrays():
     # readback through the blessed helpers is the intended idiom
     assert rules_hit("""
@@ -1485,7 +1517,7 @@ def test_gt018_negative_inside_device_call_scope():
         def serve_direct(x):
             with device_trace.device_call("site") as d:
                 out = prog(x)
-                d.executed()
+                d.wait(out)
                 return out
 
         def serve_chained(x, stats):

@@ -560,6 +560,33 @@ def test_one_request_yields_the_twelve_stages_in_one_tree(
     assert max(c / r for c, r in trees) >= covered, trees
 
 
+def test_device_execute_span_carries_the_crossings_legs(panel):
+    """The operator's view of one query: `/v1/traces` and EXPLAIN
+    ANALYZE's tree show where the call's time went, on one clock."""
+    port, query = panel
+    tid = "37" * 16
+    _post_sql(port, query(300), traceparent=f"00-{tid}-{'cd' * 8}-01")
+    (dev,) = [s for s in _finished_trace(tid)
+              if s["name"] == "device.execute"]
+    a = dev["attributes"]
+    assert a["site"] == "range"
+    assert a["dispatch_ms"] > 0 and a["readback_ms"] > 0
+    assert a["wait_ms"] >= 0
+    assert a["execute_ms"] == pytest.approx(
+        a["dispatch_ms"] + a["wait_ms"], abs=2e-3)
+    # the legs lie inside the span; what is left is the host code the
+    # span also wraps (the gate, the sessions' put, the window's fold)
+    assert (a["dispatch_ms"] + a["wait_ms"] + a["readback_ms"]
+            <= dev["duration_ms"] + 0.01)
+    doc = json.loads(_post_sql(port, "EXPLAIN ANALYZE " + query(301)))
+    text = json.dumps(doc)
+    for leg in ("dispatch_ms=", "wait_ms=", "readback_ms=", "execute_ms="):
+        assert leg in text, leg
+    import time
+
+    time.sleep(0.1)     # the roots close after the last byte
+
+
 def test_span_time_is_exported_by_name(panel, monkeypatch):
     port, query = panel
     # thread CPU is read for one request in `_CPU_EVERY` and scaled up:
@@ -729,7 +756,7 @@ def test_capture_holds_gtpu_events_on_the_profilers_clock(panel, tmp_path):
     box: dict = {}
     cap = threading.Thread(
         target=lambda: box.update(DP.capture_trace(
-            1.0, str(tmp_path / "traces"))))
+            2.0, str(tmp_path / "traces"))))
     cap.start()
     try:
         import time
@@ -748,13 +775,30 @@ def test_capture_holds_gtpu_events_on_the_profilers_clock(panel, tmp_path):
     from jax.profiler import ProfileData
 
     names = set()
+    nested = set()
     for plane in ProfileData.from_file(pb[0]).planes:
         for line in plane.lines:
+            calls = []      # the device.execute events of this thread
             for ev in line.events:
-                if ev.name.startswith("gtpu:"):
-                    names.add(ev.name)
+                if not ev.name.startswith("gtpu:"):
+                    continue
+                names.add(ev.name)
+                span = (ev.start_ns, ev.start_ns + ev.duration_ns)
+                if ev.name == "gtpu:device.execute":
+                    calls.append(span)
+                elif ev.name.startswith("gtpu:device."):
+                    # a leg of the crossing: inside its call's event,
+                    # and it says whose it is
+                    assert dict(ev.stats)["site"] == "range", ev.name
+                    if any(lo <= span[0] and span[1] <= hi
+                           for lo, hi in calls):
+                        nested.add(ev.name)
     assert {"gtpu:" + s for s in STAGES} <= names, names
     assert {"gtpu:http /v1/sql", "gtpu:test.tick", "gtpu:gc.gen2"} <= names
+    assert nested == {"gtpu:device.dispatch", "gtpu:device.wait",
+                      "gtpu:device.readback"}, (nested, names)
+    # the document says what the capture itself took
+    assert box["hold_s"] >= 2.0 and box["start_s"] > 0 and box["stop_s"] > 0
     # off the capture a span opens no annotation
     with tracing.span("after") as sp:
         assert sp.trace_id
@@ -822,5 +866,119 @@ def test_ring_order_is_constant_time_and_newest_first():
                 tracing.global_traces.traces(2)] == ids[:2:-1]
         assert tracing.global_traces.evicted_traces >= 2
         assert not hasattr(tracing.global_traces, "_order")
+    finally:
+        tracing.configure({})
+
+
+def test_a_trace_kept_for_cause_outlives_the_traces_kept_by_chance():
+    """Eviction takes the traces kept by chance first: at 300 requests
+    a second the trace of the one that failed is still there."""
+    tracing.configure({"capacity": 16, "slow_ms": 50.0})
+    try:
+        with pytest.raises(RuntimeError):
+            with tracing.span("failed") as bad:
+                raise RuntimeError("boom")
+        with tracing.span("marked") as marked:
+            tracing.mark_keep()
+        ids = []
+        for i in range(300):
+            with tracing.span("sampled") as sp:
+                ids.append(sp.trace_id)
+        store = tracing.global_traces
+        assert store.trace(bad.trace_id) and store.trace(marked.trace_id)
+        got = [t["trace_id"] for t in store.traces(0)]
+        assert len(got) == 16
+        # the rest of the ring is the newest of the others, newest first
+        assert got[:14] == ids[:-15:-1]
+        assert set(got[14:]) == {bad.trace_id, marked.trace_id}
+        # kept for cause, they still go oldest first once they fill
+        # three quarters of the ring: errors cannot starve it
+        for i in range(40):
+            with tracing.span("marked"):
+                tracing.mark_keep()
+        with tracing.span("sampled") as newest:
+            pass
+        assert store.trace(bad.trace_id) == []
+        assert store.trace(newest.trace_id)
+        assert len(store.traces(0)) == 16
+    finally:
+        tracing.configure({})
+
+
+def test_the_slowest_trace_of_each_root_name_is_held_beside_the_ring(
+        tmp_path):
+    """One slot a local-root name, outside the ring's count: after any
+    run one GET names the stage that held the worst request."""
+    import time
+
+    from greptimedb_tpu.servers.http import HttpServer
+
+    tracing.configure({"capacity": 4, "sample_ratio": 0.0})
+    try:
+        took = (0.002, 0.06, 0.004, 0.001)
+        for i, t in enumerate(took):
+            with tracing.span("route") as sp:
+                with tracing.span("stage", nth=i):
+                    time.sleep(t)
+            if i == 1:
+                worst = sp.trace_id
+        with tracing.span("other"):
+            pass
+        for _ in range(50):     # the ring has long turned past it
+            with tracing.span("route"):
+                pass
+        store = tracing.global_traces
+        assert store.trace(worst) == []     # sampled out of the ring
+        slots = {d["name"]: d for d in store.slowest()}
+        assert set(slots) == {"route", "other"}
+        slot = slots["route"]
+        assert slot["trace_id"] == worst and slot["duration_ms"] >= 60.0
+        assert [s["name"] for s in slot["spans"]] == ["route", "stage"]
+        assert slot["spans"][1]["attributes"] == {"nth": 1}
+        assert [d["name"] for d in store.slowest()] == ["route", "other"]
+        inst = Standalone(str(tmp_path / "data"), warm_start=False)
+        srv = HttpServer(inst, port=0).start()
+        try:
+            doc = json.loads(urllib.request.urlopen(
+                f"http://127.0.0.1:{srv.port}/v1/traces?slowest=1",
+                timeout=10).read())
+        finally:
+            srv.stop()
+            inst.close()
+        assert doc["slowest"][0]["trace_id"] == worst
+        # read and emptied: what is read next is the worst since
+        assert store.slowest(reset=True)[0]["trace_id"] == worst
+        assert store.slowest() == []
+        with tracing.span("route") as since:
+            pass
+        assert [d["trace_id"] for d in store.slowest()] == [since.trace_id]
+    finally:
+        tracing.configure({})
+
+
+def test_a_request_the_ring_turned_past_in_flight_is_still_judged():
+    """A stalled request under load: the ring evicts its trace while it
+    is in flight; at its finish it is kept for cause and fills its
+    name's slot all the same."""
+    import contextvars
+    import time
+
+    tracing.configure({"capacity": 4, "slow_ms": 30.0})
+    try:
+        with tracing.span("stalled") as sp:
+            def others():       # ten requests of other connections
+                for _ in range(10):
+                    with tracing.span("quick"):
+                        pass
+
+            contextvars.Context().run(others)
+            assert tracing.global_traces.trace(sp.trace_id) == []
+            with tracing.span("held"):
+                time.sleep(0.04)
+        spans = tracing.global_traces.trace(sp.trace_id)
+        assert [s["name"] for s in spans] == ["stalled", "held"]
+        (slot,) = [d for d in tracing.global_traces.slowest()
+                   if d["name"] == "stalled"]
+        assert slot["trace_id"] == sp.trace_id
     finally:
         tracing.configure({})
