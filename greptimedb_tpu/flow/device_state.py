@@ -456,8 +456,7 @@ class DeviceFlowState:
             # one crossing, in the device's own widths: the host copies
             # widen to float64 below, which would double the reported
             # transfer bytes in the platform-float32 device mode
-            host = dcall.read(readback.read_outputs, dirty_dev[0], 0,
-                              dirty_dev[1:])
+            host = dcall.read(readback.read_outputs, tuple(dirty_dev))
             per_agg = {j: (host[j].astype(np.float64),
                            host[k + j].astype(bool))
                        for j in range(k)}

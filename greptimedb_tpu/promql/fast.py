@@ -288,13 +288,16 @@ def _session_exec(entry: _Entry, skey: tuple, dcall, run):
     device-array identities of the cached masks/grouping/window inputs
     (match_cache/group_cache/win_cache): same id => same immutable
     buffer, and an evicted input only costs a false miss. Entry version
-    rides the registry's validation, so any data change invalidates."""
+    rides the registry's validation, so any data change invalidates.
+    Every caller reads the whole packed result: a miss starts its copy
+    to the host behind the program (`dcall.start`) before it waits."""
     from greptimedb_tpu.query import sessions as _sessions
 
     tkey = ("promql", id(entry))
     buf = _sessions.global_sessions.get(tkey, skey, entry.version)
     if buf is None:
         buf = run()
+        dcall.start(buf)
         dcall.wait(buf)
         _sessions.global_sessions.put(
             tkey, skey, entry.version, buf, int(buf.nbytes)

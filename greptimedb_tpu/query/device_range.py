@@ -2837,21 +2837,13 @@ def execute_range_device(engine, plan, table):
                 out_dev, act_dev, extent_dev = outs
                 if win is None:
                     extras = (act_dev, extent_dev)
-            dcall.wait(out_dev)
-        if dispatch and use_sessions:
-            _file(entry, seen, sessions.global_sessions.put,
-                  session_tkey, session_key, entry.session_version, out_dev,
-                  int(out_dev.nbytes))
-        if kb and win is None:
+        rows_packed = kb and win is None
+        if rows_packed:
             # a window the memo does not know (so this call dispatched),
             # on the rows path: `packed` holds all of it, one array in
             # one crossing; the cursor's steps are cut on the host (K
             # rows: nothing to save)
-            (packed,) = dcall.read(readback.read_outputs, packed_dev, 0)
-            out, *extras = _unpack_rows(packed, out_dev.shape,
-                                        len(memo["gid_host"]))
-            out = out[..., j0_b:]
-            readback_bytes = packed.nbytes
+            crossing = (packed_dev,)
         else:
             # fold=False leaves the plane programs' series axis
             # un-folded: rows [g:] are the padded/inactive tail (fold=True
@@ -2861,9 +2853,25 @@ def execute_range_device(engine, plan, table):
             # in ONE readback (readback.read_outputs feeds
             # gtpu_readback_bytes_total{mode=full|delta}).
             sliced = out_dev if kb or memo["fold"] else out_dev[:, :g]
-            out, *extras = dcall.read(readback.read_outputs, sliced, j0_b,
-                                      extras, axis=-1)
-            readback_bytes = out.nbytes + sum(x.nbytes for x in extras)
+            crossing = readback.outputs(sliced, j0_b, extras, axis=-1)
+        if dispatch:
+            # the copy of exactly what the read brings back, queued
+            # behind the program: the read finds the bytes on their way
+            dcall.start(*crossing)
+            dcall.wait(out_dev)
+        if dispatch and use_sessions:
+            _file(entry, seen, sessions.global_sessions.put,
+                  session_tkey, session_key, entry.session_version, out_dev,
+                  int(out_dev.nbytes))
+        host = dcall.read(readback.read_outputs, crossing,
+                          delta=not rows_packed and j0_b > 0)
+        readback_bytes = sum(x.nbytes for x in host)
+        if rows_packed:
+            out, *extras = _unpack_rows(host[0], out_dev.shape,
+                                        len(memo["gid_host"]))
+            out = out[..., j0_b:]
+        else:
+            out, *extras = host
         if win is None:
             # (no record means this call dispatched: `seen` is its own)
             win = _fold_window(entry, memo, *extras)

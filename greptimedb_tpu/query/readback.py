@@ -7,11 +7,17 @@ transfer, not only the kernels, is user-visible latency), and (b) delta
 polls can slice ON DEVICE before materializing, shipping only the rows/
 steps a `since` cursor has not seen instead of the whole buffer.
 
+A call that dispatched starts the copy of what it will read right
+after the dispatch (`start`, through `device_call.start`): the copy is
+queued behind the program, so the crossing finds the bytes on their way
+instead of opening a round trip of its own. No byte crosses that the
+read would not bring back.
+
 gtlint GT015 enforces the contract: a raw `np.asarray(...)` /
-`jax.device_get(...)` on a device result buffer (a name
-`.block_until_ready()` was called on) in query-path code is a finding —
-it would read the full buffer back unattributed where these helpers
-exist.
+`jax.device_get(...)` / `.copy_to_host_async()` on a device result
+buffer (a name `.block_until_ready()` was called on) in query-path code
+is a finding — it would read the full buffer back unattributed where
+these helpers exist.
 """
 
 from __future__ import annotations
@@ -69,19 +75,32 @@ def read_delta(arr, lo: int, *, axis: int = -1, dtype=None) -> np.ndarray:
     return out
 
 
-def read_outputs(arr, lo: int, extras=(), *, axis: int = -1) -> tuple:
-    """Every output of one program in ONE crossing: `arr[..., lo:]`
-    (sliced on the device, as read_delta; lo <= 0 reads it whole) and
-    the program's small side outputs `extras`. `jax.device_get` of the
-    tuple starts all the copies before it waits for any, where one
-    `np.asarray` per output would block once each. -> (out, *extras)
-    as host arrays; the bytes of all of them count under the mode of
-    `arr` (delta where sliced, else full)."""
+def start(*arrs) -> None:
+    """Start the device->host copy of each of `arrs` now, queued behind
+    the program that computes it. Call it with exactly the arrays a
+    later crossing of this module reads (`outputs` builds them); it
+    counts nothing: the read that finds the bytes does."""
+    for a in arrs:
+        a.copy_to_host_async()
+
+
+def outputs(arr, lo: int, extras=(), *, axis: int = -1) -> tuple:
+    """The device arrays of one crossing of every output of a program:
+    `arr[..., lo:]` (sliced on the device, as read_delta; lo <= 0 the
+    whole of it), then the small side outputs `extras`."""
+    return (_tail(arr, lo, axis) if lo > 0 else arr, *extras)
+
+
+def read_outputs(dev: tuple, *, delta: bool = False) -> tuple:
+    """The device arrays `dev` (`outputs`) in ONE crossing:
+    `jax.device_get` of the tuple starts every copy not started yet
+    before it waits for any, where one `np.asarray` per output would
+    block once each. -> host arrays; the bytes of all of them count
+    under mode delta (a cursor's slice) or full."""
     import jax
 
-    dev = (_tail(arr, lo, axis) if lo > 0 else arr, *extras)
     host = jax.device_get(dev)
-    _attribute("delta" if lo > 0 else "full",
+    _attribute("delta" if delta else "full",
                sum(int(x.nbytes) for x in host))
     return host
 

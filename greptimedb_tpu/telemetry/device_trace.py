@@ -27,6 +27,9 @@ Each wrapped call produces one `device.execute` span carrying:
   dispatches nothing and reads 0)
 - upload_bytes / readback_bytes: host->device and device->host traffic
   attributable to this call
+- readback_started: "dispatch" where the call started the copy of the
+  arrays it read right after the dispatch (`start`), so the readback leg
+  holds only what the copy had left; "read" where the read opened it
 - program: the program-registry id (telemetry/device_programs.py);
   the roofline numbers live on the registry row and in EXPLAIN
   ANALYZE's `roofline_<site>` note, not on the span
@@ -54,6 +57,7 @@ import time
 
 from greptimedb_tpu import concurrency
 from greptimedb_tpu.telemetry import stmt_stats, tracing
+from greptimedb_tpu.telemetry.metrics import global_registry
 
 # (site, static program key) shapes this process has already executed:
 # membership decides first_call vs cache_hit attribution. Bounded the
@@ -76,6 +80,31 @@ def note_compile(site: str, key) -> str:
         return "first_call"
 
 
+# every device->host crossing of a call, by where its copy was started:
+# `dispatch` (the call's `start` queued it behind the program) or `read`
+_CROSSINGS = global_registry.counter(
+    "gtpu_readback_crossings_total",
+    "device->host result crossings by site and by where the copy was "
+    "started (dispatch: queued behind the program right after it was "
+    "dispatched; read: opened by the read itself)",
+    labels=("site", "started"),
+)
+for _site in ("range", "groupby", "promql", "promql_histogram",
+              "promql_binary", "topk", "compact_merge", "flow_finalize"):
+    for _started in ("dispatch", "read"):
+        # the sites that read their results back: 0 from start-up
+        _CROSSINGS.labels(_site, _started)
+
+
+def _device_arrays(args):
+    """The device arrays among a readback's arguments (tuples opened)."""
+    for a in args:
+        if isinstance(a, tuple):
+            yield from _device_arrays(a)
+        elif hasattr(a, "copy_to_host_async"):
+            yield a
+
+
 def _host_nbytes(out) -> int:
     """Bytes of what a readback returned: one host array
     (`read_full`, `read_delta`) or a tuple of them (`read_outputs`)."""
@@ -92,6 +121,9 @@ class device_call:
     - `out = d.run(fn, *args, **kw)` dispatches the program (it
       registers with the device-program profiler) and stamps the jit
       call's return: the dispatch;
+    - `d.start(*arrs)` right after `run` starts the device->host copy
+      of exactly the arrays the call will read, queued behind the
+      program (query/readback.start);
     - `d.wait(out)` blocks until the outputs are ready and stamps that
       return: the wait (`d.wait(dispatch_only=True)` where the caller
       deliberately does not block — the grid's upkeep, the flow
@@ -101,12 +133,13 @@ class device_call:
       brought back: the readback;
     - `d.transfer(nbytes, "upload")` for the host arguments' bytes.
 
-    A session hit calls none of the first two: its legs read 0."""
+    A session hit calls none of the first three: its legs read 0, and
+    its read counts under started="read"."""
 
     __slots__ = ("_cm", "_span", "site", "_stmt", "key",
                  "_rec", "_first", "_run_t0", "_disp_t1", "_disp_ms",
                  "_wait_ms", "_rb_ms", "_exec_ms", "_up", "_rb",
-                 "_dispatch_only")
+                 "_dispatch_only", "_started")
 
     def __init__(self, site: str, *, key=None, **attrs):
         self.site = site
@@ -122,6 +155,7 @@ class device_call:
         self._up = 0
         self._rb = 0
         self._dispatch_only = False
+        self._started = ()
         # skip the compile-memo lookup entirely when NEITHER a trace
         # nor a statement observation is active: the memo only feeds
         # attribution, and the bare hot path must stay zero-cost
@@ -168,6 +202,17 @@ class device_call:
         self._disp_ms = (self._disp_t1 - self._run_t0) * 1000.0
         return out
 
+    def start(self, *arrs):
+        """Start the device->host copy of `arrs`, the arrays this call's
+        `read` will bring back, right after `run`: the copy is queued
+        behind the program, and the read finds the bytes on their way
+        instead of opening a round trip of its own. A crossing of these
+        very arrays counts under started="dispatch"."""
+        from greptimedb_tpu.query import readback
+
+        readback.start(*arrs)
+        self._started += tuple(map(id, arrs))
+
     def wait(self, *outputs, dispatch_only: bool = False):
         """Block until `outputs` (arrays, or trees of them) are ready
         and close the wait leg, counted from the dispatch's return: the
@@ -194,12 +239,18 @@ class device_call:
         query/readback.py (`read_outputs`, `read_full`, `read_delta`),
         called with `args`. Its return closes (one part of) the
         readback leg; the bytes of the host arrays it returned count as
-        this call's readback."""
+        this call's readback; the crossing counts under started=
+        "dispatch" where `start` began the copy of every array it read."""
+        started = ("dispatch" if self._started and all(
+            id(a) in self._started for a in _device_arrays(args))
+            else "read")
         with tracing.event("device.readback", site=self.site):
             t0 = time.monotonic()
             out = fn(*args, **kw)
             self._rb_ms += (time.monotonic() - t0) * 1000.0
         self.transfer(_host_nbytes(out), "readback")
+        _CROSSINGS.labels(self.site, started).inc()
+        self._span.attributes["readback_started"] = started
         return out
 
     def transfer(self, nbytes: int, direction: str = "readback"):

@@ -743,7 +743,11 @@ class FullBufferReadback(Rule):
         "query/readback.read_full (bytes land on "
         "gtpu_readback_bytes_total) or read_delta (a since-cursor poll "
         "slices device-side and ships only the unseen rows), inside a "
-        "device_call as `d.read(readback.read_full, name)`."
+        "device_call as `d.read(readback.read_full, name)`. A raw "
+        "`name.copy_to_host_async()` is the same crossing started "
+        "unattributed: start it through the call's "
+        "`d.start(name)` (query/readback.start), which counts the "
+        "crossing it begins on gtpu_readback_crossings_total."
     )
 
     @staticmethod
@@ -802,6 +806,16 @@ class FullBufferReadback(Rule):
     def visit_Call(self, node: ast.Call, ctx: FileContext):
         if ctx.path.replace("\\", "/").endswith("query/readback.py"):
             return  # the helpers ARE the blessed readback point
+        f = node.func
+        if (isinstance(f, ast.Attribute) and f.attr == "copy_to_host_async"
+                and isinstance(f.value, ast.Name)
+                and f.value.id in self._blocked_names(ctx)):
+            ctx.report(self, node,
+                       f"{f.value.id}.copy_to_host_async() starts the "
+                       "device buffer's readback outside query/readback."
+                       "py; start it through the device_call's "
+                       f"`d.start({f.value.id})` so the crossing counts")
+            return
         d = dotted_name(node.func)
         if d not in _READBACK_CALLS or not node.args:
             return

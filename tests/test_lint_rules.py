@@ -700,6 +700,38 @@ def test_gt015_negative_read_through_a_device_call():
     """, select="GT015") == []
 
 
+def test_gt015_positive_raw_copy_to_host_async():
+    # starting the copy by hand is the crossing begun unattributed
+    hits = rules_hit("""
+        from greptimedb_tpu.query import readback
+        from greptimedb_tpu.telemetry import device_trace
+
+        def run(program, arrs):
+            with device_trace.device_call("site") as d:
+                out = d.run(program, arrs)
+                out.copy_to_host_async()
+                d.wait(out)
+                return d.read(readback.read_full, out)
+    """, select="GT015")
+    assert hits == [("GT015", 8)]
+
+
+def test_gt015_negative_copy_started_through_the_call():
+    # `d.start(out)` is the blessed start (query/readback.start)
+    assert rules_hit("""
+        from greptimedb_tpu.query import readback
+        from greptimedb_tpu.telemetry import device_trace
+
+        def run(program, arrs, host_value):
+            with device_trace.device_call("site") as d:
+                out = d.run(program, arrs)
+                d.start(out)
+                d.wait(out)
+                host_value.copy_to_host_async()
+                return d.read(readback.read_full, out)
+    """, select="GT015") == []
+
+
 def test_gt015_negative_helper_and_host_arrays():
     # readback through the blessed helpers is the intended idiom
     assert rules_hit("""

@@ -574,6 +574,8 @@ def test_device_execute_span_carries_the_crossings_legs(panel):
     assert a["wait_ms"] >= 0
     assert a["execute_ms"] == pytest.approx(
         a["dispatch_ms"] + a["wait_ms"], abs=2e-3)
+    # a fresh literal dispatches: the copy was started with the program
+    assert a["readback_started"] == "dispatch"
     # the legs lie inside the span; what is left is the host code the
     # span also wraps (the gate, the sessions' put, the window's fold)
     assert (a["dispatch_ms"] + a["wait_ms"] + a["readback_ms"]
