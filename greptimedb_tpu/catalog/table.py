@@ -273,10 +273,11 @@ class Table:
         -> (rows, appends, the version they reach, None). Otherwise
         (None, 0, None, reason): "mutation" (a truncate, a delete or a
         schema change lies between the two versions), "multi_region"
-        (each region numbers its own series), "flushed" (the rows have
-        left the memtable). The parts of a version compare one by one:
-        schema and tags, then per region the truncate marks, then the
-        sequence, which only a write moves."""
+        (each region numbers its own series), "flushed" (the rows are
+        out of the region's reach by sequence: two flushes since). The
+        parts of a version compare one by one: schema and tags, then per
+        region the truncate marks, then the sequence, which only a write
+        moves. `rows` are this table's own (`_own_rows`)."""
         if len(self.regions) != 1:
             return None, 0, None, "multi_region"
         region = self.regions[0]
@@ -288,9 +289,16 @@ class Table:
         rows, appends, seq_now = region.rows_since(then[0])
         if rows is False:
             return None, 0, None, "flushed"
+        if rows is not None:
+            rows = self._own_rows(region, rows)
         if rows is not None and (rows.op != OP_PUT).any():
             return None, 0, None, "mutation"
         return rows, appends, (((seq_now,) + then[1:],),) + version[1:], None
+
+    def _own_rows(self, region, rows):
+        """The rows of `region` that belong to this table (all of them,
+        or None where none do)."""
+        return rows
 
     def physical_version(self) -> tuple:
         """data_version extended with each region's manifest version:

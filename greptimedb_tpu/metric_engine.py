@@ -17,7 +17,7 @@ import numpy as np
 from greptimedb_tpu.catalog.table import Table, TableScanData
 from greptimedb_tpu.datatypes.schema import ColumnSchema, Schema, SemanticType
 from greptimedb_tpu.datatypes.types import ConcreteDataType
-from greptimedb_tpu.storage.memtable import OP_PUT
+from greptimedb_tpu.storage.memtable import OP_PUT, _slice_rows
 
 PHYSICAL_TABLE = "greptime_physical_table"
 TABLE_ID_TAG = "__table_id"
@@ -77,6 +77,22 @@ class LogicalTable(Table):
         per-table scoping stays flat as logical tables multiply onto
         the shared region (engine.rs's tsid-prefix analog)."""
         return region.match_sids([(TABLE_ID_TAG, "eq", self._tid)])
+
+    def _own_rows(self, region, rows):
+        """Rows of the shared physical region narrowed to this table's
+        series (its `__table_id`): what `appended_since` hands a grid of
+        this metric. The membership mask is kept until the region's
+        registry interns a series."""
+        memo = getattr(self, "_own_memo", None)
+        if memo is None or memo[0] != region.series.version:
+            own = np.zeros(region.series.num_series, bool)
+            own[self.scoped_sids(region)] = True
+            memo = self._own_memo = (region.series.version, own)
+        own = memo[1]
+        keep = own[np.minimum(rows.sid, len(own) - 1)] & (rows.sid < len(own))
+        if keep.all():
+            return rows
+        return _slice_rows(rows, keep) if keep.any() else None
 
     def flush(self):
         self.physical.flush()

@@ -27,6 +27,9 @@ from greptimedb_tpu.errors import (
     UnsupportedError,
 )
 from greptimedb_tpu.promql import parser as P
+# the fast path's counters (the selector grid's upkeep outcomes among
+# them) exist from start-up, every label at 0
+from greptimedb_tpu.promql import fast as _fast  # noqa: F401
 from greptimedb_tpu.telemetry import tracing
 from greptimedb_tpu.query.expr import compile_matcher
 from greptimedb_tpu.promql.parser import (

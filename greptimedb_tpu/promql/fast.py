@@ -28,6 +28,7 @@ do queries whose step/range don't align with the cached grid resolution.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 
@@ -49,6 +50,9 @@ from greptimedb_tpu.telemetry import tracing
 from greptimedb_tpu.telemetry.metrics import global_registry
 
 from greptimedb_tpu import concurrency
+from greptimedb_tpu.query.grid_upkeep import (
+    PlaneGate, cell_capacity, file_unless_appended,
+)
 
 # range functions computable from per-series prefix sums: O(S*T) memory,
 # no (S, J, L) window materialisation, safe at 1M series.
@@ -83,6 +87,36 @@ _GRID_ENTRIES = global_registry.counter(
     "do not sit one to a cell or the span outgrows the byte budget",
     ("outcome",),
 )
+
+
+# what became of an entry that a query found behind its table's version:
+# brought forward by `_upkeep` ("append"; "grow" where the time axis was
+# lengthened on the device first), or evicted and rebuilt because what
+# was written since is not a plain append ("rebuild_<reason>"). A family
+# of its own: the range grid's `gtpu_grid_upkeep_total` is read as it is
+_UPKEEP = global_registry.counter(
+    "gtpu_promql_grid_upkeep_total",
+    "selector grid entries found behind their table's version, by "
+    "outcome: append (the samples written since were scattered into the "
+    "resident planes), grow (the same, the time axis first lengthened "
+    "on the device) or rebuild_<reason> (evicted and built again)",
+    labels=("outcome",),
+)
+# every outcome reads 0 from the import, so that a scrape can tell "no
+# rebuild" from "no such counter"
+_REBUILD_REASONS = ("out_of_order", "new_series", "irregular", "mutation",
+                    "mesh", "flushed", "capacity", "multi_region")
+for _outcome in ("append", "grow") + tuple(
+        "rebuild_" + r for r in _REBUILD_REASONS):
+    _UPKEEP.labels(_outcome)
+_UPKEEP_ROWS = global_registry.counter(
+    "gtpu_promql_grid_upkeep_rows_total",
+    "samples written into resident selector grid planes by the upkeep",
+)
+_NO_ROW = np.iinfo(np.int64).min
+# samples a dispatch of the append program takes at most; a batch is
+# padded to the power of two at or above its size, from this floor
+_APPEND_MIN, _APPEND_MAX = 1024, 65536
 
 
 def _budget_bytes() -> int:
@@ -121,6 +155,20 @@ class _Entry:
     match_cache: dict = field(default_factory=dict)
     group_cache: dict = field(default_factory=dict)
     win_cache: dict = field(default_factory=dict)
+    # what the upkeep (`_upkeep`) holds a batch of samples against: the
+    # newest ts of every series (`_NO_ROW` for none), and the cells that
+    # reach the newest sample (the rest of the time axis is spare)
+    last_ts: np.ndarray | None = None
+    data_cells: int = 0
+    # the version the entry was built at: the session registry's stamp
+    # for its result buffers, which the upkeep purges by window instead
+    session_version: tuple = ()
+    # queries read the planes shared, the upkeep donates them exclusive;
+    # `appends` counts the batches applied, which a query reads with the
+    # planes and checks before it files a window or a buffer
+    gate: object = field(default_factory=PlaneGate)
+    appends: int = 0
+    upkeep_lock: object = field(default_factory=concurrency.Lock)
 
 
 class SelectorGridCache:
@@ -147,11 +195,26 @@ class SelectorGridCache:
         version = table.data_version()
         with self._lock:
             e = self._entries.get(key)
-            if e is not None and e.table is table and e.version == version:
+            if e is not None and e.table is not table:
+                e = None
+            if e is not None and e.version == version:
                 e.last_used = time.monotonic()
                 self._hits += 1
                 tracing.set_attr(grid_cache="hit")
                 return e
+        if e is not None and e.spec is not None:
+            # a grid behind its table: brought forward by what was
+            # written since, or dropped where that is no plain append
+            if _upkeep(e, table) != "rebuild":
+                with self._lock:
+                    e.last_used = time.monotonic()
+                    self._hits += 1
+                tracing.set_attr(grid_cache="upkeep")
+                return e
+            with self._lock:
+                if self._entries.get(key) is e:
+                    self._release(self._entries.pop(key))
+        with self._lock:
             self._misses += 1
         tracing.set_attr(grid_cache="miss")
         with tracing.child_span("grid.build", site="promql_grid"):
@@ -278,30 +341,36 @@ class SelectorGridCache:
 _CACHE = SelectorGridCache()
 
 
-def _session_exec(entry: _Entry, skey: tuple, dcall, run):
+def _session_exec(entry: _Entry, wins, skey: tuple, dcall, run):
     """Persistent query session for a fused program's packed result: an
     identical repeated poll serves the HBM-resident buffer without
     re-dispatching the program (query/sessions.py — each dispatch is a
     host->device round trip); a miss dispatches (`run`, a call of
     `dcall.run`) and waits for the result through `dcall`. The shape
-    key embeds the
-    device-array identities of the cached masks/grouping/window inputs
-    (match_cache/group_cache/win_cache): same id => same immutable
-    buffer, and an evicted input only costs a false miss. Entry version
-    rides the registry's validation, so any data change invalidates.
-    Every caller reads the whole packed result: a miss starts its copy
-    to the host behind the program (`dcall.start`) before it waits."""
+    key embeds the device-array identities of the cached masks and
+    grouping inputs (match_cache/group_cache: same id => same immutable
+    buffer, and an evicted input only costs a false miss) and leads with
+    the windows `wins` read (`_plan_windows`' key, the last cell first):
+    the upkeep purges by window the buffers of cells it wrote to, and a
+    query files its buffer only if no batch was applied since it read
+    the planes. Every caller reads the whole packed result: a miss
+    starts its copy to the host behind the program (`dcall.start`)
+    before it waits."""
     from greptimedb_tpu.query import sessions as _sessions
 
     tkey = ("promql", id(entry))
-    buf = _sessions.global_sessions.get(tkey, skey, entry.version)
+    wkeys = tuple(w[6] for w in wins)
+    key = (max(k[0] for k in wkeys), wkeys, skey)
+    buf = _sessions.global_sessions.get(tkey, key, entry.session_version)
     if buf is None:
-        buf = run()
+        with entry.gate.shared():
+            seen = entry.appends
+            buf = run()
         dcall.start(buf)
         dcall.wait(buf)
-        _sessions.global_sessions.put(
-            tkey, skey, entry.version, buf, int(buf.nbytes)
-        )
+        file_unless_appended(
+            entry, seen, _sessions.global_sessions.put, tkey, key,
+            entry.session_version, buf, int(buf.nbytes))
     return buf
 
 
@@ -450,11 +519,223 @@ def _build_entry(table, fieldname: str, version, mesh=None,
     ).set(time.perf_counter() - t0_build)
     entry = _Entry(
         table, fieldname, version, registry, spec, gvals, ghas, gtsg,
-        s, s_pad, nbytes,
+        s, s_pad, nbytes, session_version=version, data_cells=nc,
+        last_ts=_series_newest(sid, ts, s),
     )
     entry.mesh = mesh
     entry.mesh_decision = mesh_decision
     return entry
+
+
+def _series_newest(sid: np.ndarray, ts: np.ndarray, s: int) -> np.ndarray:
+    """(s,) int64: the newest ts of each series among the rows,
+    `_NO_ROW` for a series with none. Rows come in (series, ts) order,
+    so each series' run ends on its newest."""
+    last = np.full(s, _NO_ROW, np.int64)
+    if len(sid) and bool((np.diff(sid) >= 0).all()):
+        ends = np.r_[np.nonzero(np.diff(sid))[0], len(sid) - 1]
+        last[sid[ends]] = ts[ends]
+    elif len(sid):
+        np.maximum.at(last, sid, ts)
+    return last
+
+
+# ----------------------------------------------------------------------
+# upkeep: the grid follows its table under writes
+# ----------------------------------------------------------------------
+
+def _upkeep(entry: _Entry, table) -> str:
+    """Bring `entry` forward to what its table holds now, if what was
+    written since its stamp is a plain append: samples of series the
+    entry knows, each strictly newer than the newest its series holds
+    and in a cell of its own, with no truncate, delete or schema change
+    between, the entry not on a mesh. A sample past the time axis first
+    lengthens it on the device (`cell_capacity`'s rule from the span the
+    data reaches), with no scan. Returns "append" (also when another
+    query did it first), "grow", or "rebuild" with the entry untouched
+    and the reason counted: the caller evicts it. Runs under
+    `promql.resolve`."""
+    with tracing.child_span("promql.upkeep") as span, entry.upkeep_lock:
+        rows, batch, reason = None, None, None
+        if entry.mesh is not None:
+            reason = "mesh"
+        else:
+            rows, _appends, version, reason = table.appended_since(
+                entry.version)
+        if reason is None and rows is None:
+            # another query brought it forward first, or what was
+            # written is another metric's on the same physical region
+            entry.version = version
+            span.attributes["outcome"] = "current"
+            return "append"
+        if reason is None:
+            batch, reason = _append_batch(entry, rows)
+        cells_new = 0
+        if reason is None:
+            outcome = ("grow" if batch["nc"] > entry.spec.num_cells
+                       else "append")
+            cells_new = batch["data_cells"] - entry.data_cells
+            _apply_append(entry, batch)
+            entry.version = version
+            _UPKEEP_ROWS.inc(len(rows))
+        else:
+            outcome = "rebuild_" + reason
+        span.attributes.update(
+            outcome=outcome, cells_new=cells_new,
+            samples=0 if rows is None else len(rows))
+        _UPKEEP.labels(outcome).inc()
+        return outcome if reason is None else "rebuild"
+
+
+def _append_batch(entry: _Entry, rows):
+    """The samples written since the entry's stamp as the append program
+    takes them -> (batch, None), or (None, reason) where they are no
+    plain append of this grid."""
+    from greptimedb_tpu.ops import grid as G
+
+    sid, ts = rows.sid.astype(np.int64), np.asarray(rows.ts, np.int64)
+    if int(sid.max()) >= entry.num_series:
+        return None, "new_series"
+    vals = np.asarray(rows.fields[entry.fieldname])
+    valid = (rows.field_valid or {}).get(entry.fieldname)
+    order = np.lexsort((ts, sid))
+    sid, ts, vals = sid[order], ts[order], vals[order]
+    valid = (np.ones(len(ts), bool) if valid is None
+             else np.asarray(valid, bool)[order])
+    spec = entry.spec
+    first = np.r_[True, sid[1:] != sid[:-1]]
+    prev = entry.last_ts[sid[first]]
+    if ((ts[1:] <= ts[:-1]) & ~first[1:]).any() or (ts[first] <= prev).any():
+        # a sample at or before the newest its series holds: an
+        # overwrite or a late arrival, which a cell cannot take back
+        return None, "out_of_order"
+    cell = spec.cell_of(ts)
+    if int(cell.min()) < 1:
+        return None, "out_of_order"     # at or before the grid's origin
+    held = prev != _NO_ROW
+    if ((cell[1:] == cell[:-1]) & ~first[1:]).any() or (
+            cell[first][held] == spec.cell_of(prev[held])).any():
+        return None, "irregular"        # two samples in one cell
+    need = int(cell.max()) + 1
+    nc = spec.num_cells
+    if need > nc:
+        limit = max(_budget_bytes() // 2 // (9 * entry.s_pad), 16)
+
+        def fits(cells):
+            # the build's budget, and the tick unit the planes hold
+            return cells <= limit and G.GridSpec.build(
+                spec.t0, spec.res, cells).unit == spec.unit
+
+        nc = cell_capacity(need, fits)
+        if not fits(nc):
+            return None, "capacity"
+    keep = valid
+    upd = np.stack([
+        sid[keep], cell[keep], spec.device_ts(ts[keep]),
+        vals[keep].astype(np.float32).view(np.int32)]).astype(np.int32)
+    run_ends = np.r_[np.nonzero(first)[0][1:] - 1, len(sid) - 1]
+    return {
+        "upd": upd, "nc": nc, "c0": int(cell.min()),
+        "data_cells": max(entry.data_cells, need),
+        "newest": (sid[run_ends], ts[run_ends]),
+    }, None
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1, 2))
+def selector_grid_append(vals, has, tsg, upd):
+    """The planes of a selector grid, donated, with one batch of
+    appended samples written in: `upd` int32[4, Kb] holds a sample a
+    column (series, cell, tick, the float32 value's bits), padded with
+    series past the axis, which the scatters drop. Each sample's cell is
+    empty (newer than its series' newest, one a cell), so a write is
+    what a build's scatter puts there."""
+    import jax.numpy as jnp
+
+    at = (upd[0], upd[1])
+
+    def put(plane, value):
+        return plane.at[at].set(value, mode="drop", unique_indices=True)
+
+    value = jax.lax.bitcast_convert_type(upd[3], jnp.float32)
+    return put(vals, value), put(has, True), put(tsg, upd[2])
+
+
+@functools.partial(jax.jit, static_argnames=("nc",))
+def selector_grid_grow(vals, has, tsg, *, nc: int):
+    """The planes copied into planes of `nc` cells, the new ones empty
+    as a build leaves a cell no sample reaches."""
+    import jax.numpy as jnp
+
+    pad = ((0, 0), (0, nc - vals.shape[1]))
+    return jnp.pad(vals, pad), jnp.pad(has, pad), jnp.pad(tsg, pad)
+
+
+def _apply_append(entry: _Entry, batch: dict) -> None:
+    """Lengthen the time axis where the batch needs it, then write the
+    batch in: one dispatch of the append program a bucket of samples,
+    the planes donated and taken back updated, not waited for (the
+    query's own program queues behind it). Under the gate, exclusive,
+    with what the queries filed of the windows it wrote to forgotten."""
+    from greptimedb_tpu.ops import grid as G
+    from greptimedb_tpu.query import sessions as _sessions
+    from greptimedb_tpu.telemetry import device_trace
+
+    nc, upd = batch["nc"], batch["upd"]
+    with entry.gate.exclusive():
+        if nc > entry.spec.num_cells:
+            with device_trace.device_call(
+                    "promql_grid_upkeep", key=("promql_grid_grow", nc),
+                    cells=nc) as dcall:
+                entry.vals, entry.has, entry.tsg = dcall.run(
+                    selector_grid_grow, entry.vals, entry.has, entry.tsg,
+                    nc=nc)
+                dcall.wait(dispatch_only=True)
+            spec = entry.spec
+            entry.spec = G.GridSpec.build(spec.t0, spec.res, nc)
+            entry.nbytes = entry.s_pad * nc * 9
+            # windows were clipped to the old axis: none stands
+            entry.win_cache.clear()
+            _sessions.global_sessions.purge_table(("promql", id(entry)))
+        for at in range(0, upd.shape[1], _APPEND_MAX):
+            part = upd[:, at:at + _APPEND_MAX]
+            k = part.shape[1]
+            kb = _pow2_bucket(k, _APPEND_MIN)
+            if k < kb:
+                pad = np.zeros((4, kb - k), np.int32)
+                pad[0] = entry.s_pad + np.arange(kb - k)
+                part = np.concatenate([part, pad], axis=1)
+            with device_trace.device_call(
+                    "promql_grid_upkeep", key=("promql_grid_upkeep", kb),
+                    samples=k) as dcall:
+                entry.vals, entry.has, entry.tsg = dcall.run(
+                    selector_grid_append, entry.vals, entry.has,
+                    entry.tsg, part)
+                dcall.transfer(part.nbytes, "upload")
+                dcall.wait(dispatch_only=True)
+        # with the queries still shut out: what one of them files from
+        # here on was read from these planes
+        entry.appends += 1
+        _forget_windows_from(entry, batch["c0"])
+    sids, ts = batch["newest"]
+    entry.last_ts[sids] = ts
+    entry.data_cells = batch["data_cells"]
+    from greptimedb_tpu.telemetry import memory as _memory
+
+    _memory.note_device_bytes()
+
+
+def _forget_windows_from(entry: _Entry, c0: int) -> None:
+    """What a window record holds (its cells, clipped to the axis) and
+    what a session's buffer answers hold only for windows whose last
+    cell is before `c0`, the first a batch was written to; matcher
+    masks and groupings depend on the registry alone and stay. Under
+    the gate, exclusive."""
+    from greptimedb_tpu.query import sessions as _sessions
+
+    entry.win_cache = {k: w for k, w in entry.win_cache.items()
+                       if w[6][0] < c0}
+    _sessions.global_sessions.purge_table(
+        ("promql", id(entry)), keep=lambda key: key[0] < c0)
 
 
 # ----------------------------------------------------------------------
@@ -520,6 +801,7 @@ def _plan_windows(entry: _Entry, ev, range_ms: int, offset_ms: int,
     hit = entry.win_cache.get(key)
     if hit is not None:
         return hit
+    seen = entry.appends
     steps = np.arange(start, end + 1, ev.step_ms, dtype=np.int64)
     hi_raw = (steps - spec.t0) // res
     w = max(range_ms // res, 1)
@@ -531,14 +813,21 @@ def _plan_windows(entry: _Entry, ev, range_ms: int, offset_ms: int,
     ).astype(np.int32)
     import jax.numpy as jnp
 
-    # device-resident window indices: a repeated query uploads nothing
+    # device-resident window indices: a repeated query uploads nothing.
+    # The last element names the window: its last cell, then its key
     out = (
         jnp.asarray(lo), jnp.asarray(hi), jnp.asarray(t_end),
         int(range_ms // spec.unit), range_ms / 1000.0, int(w),
+        (int(hi.max()),) + key,
     )
-    if len(entry.win_cache) >= 64:
-        entry.win_cache.pop(next(iter(entry.win_cache)))
-    entry.win_cache[key] = out
+
+    def put():
+        if len(entry.win_cache) >= 64:
+            entry.win_cache.pop(next(iter(entry.win_cache)))
+        entry.win_cache[key] = out
+
+    # clipped to the axis as it stood: not filed if it has grown since
+    file_unless_appended(entry, seen, put)
     return out
 
 
@@ -1088,7 +1377,7 @@ def try_fast_histogram(engine, phi: float, inner, ev):
             d_gid = jnp.zeros(entry.s_pad, jnp.int32)
             g_agg = 1
             agg_op = ""
-        lo, hi, t_end, range_ticks, range_seconds, l_cells = win
+        lo, hi, t_end, range_ticks, range_seconds, l_cells, _w = win
         matchers = engine._to_registry_matchers(raw_matchers, table)
         smask, any_match = _matcher_mask_dev(entry, matchers)
     if not any_match:
@@ -1110,7 +1399,7 @@ def try_fast_histogram(engine, phi: float, inner, ev):
                                      range_ticks, range_seconds,
                                      l_cells, entry.spec.tps, fargs,
                                      lookback_ticks)) as dcall:
-        packed = _session_exec(entry, skey, dcall, lambda: dcall.run(
+        packed = _session_exec(entry, (win,), skey, dcall, lambda: dcall.run(
             _fused_hist_query,
             entry.vals, entry.has, entry.tsg, smask, d_gid, d_slot,
             jnp.asarray(uniq_le, jnp.float32), lo, hi, t_end,
@@ -1156,7 +1445,7 @@ def try_fast(engine, e, ev):
         if win is None:
             _FAST_HITS.labels("fallback").inc()
             return None
-        lo, hi, t_end, range_ticks, range_seconds, l_cells = win
+        lo, hi, t_end, range_ticks, range_seconds, l_cells, _w = win
         matchers = engine._to_registry_matchers(raw_matchers, table)
         smask, any_match = _matcher_mask_dev(entry, matchers)
         if not any_match:
@@ -1181,7 +1470,7 @@ def try_fast(engine, e, ev):
                            g, range_ticks, range_seconds, l_cells,
                            entry.spec.tps, fargs, lookback_ticks),
             groups=g) as dcall:
-        packed = _session_exec(entry, skey, dcall, lambda: dcall.run(
+        packed = _session_exec(entry, (win,), skey, dcall, lambda: dcall.run(
             program,
             entry.vals, entry.has, entry.tsg, smask, gid,
             lo, hi, t_end,
@@ -1420,7 +1709,7 @@ def try_fast_topk(engine, e, ev):
         _FAST_HITS.labels("hit").inc()
         return _empty_vector(ev)
     entry, table, raw_matchers, fname, fargs, win = resolved
-    lo, hi, t_end, range_ticks, range_seconds, l_cells = win
+    lo, hi, t_end, range_ticks, range_seconds, l_cells, _w = win
     matchers = engine._to_registry_matchers(raw_matchers, table)
     smask, any_match = _matcher_mask_dev(entry, matchers)
     if not any_match:
@@ -1447,14 +1736,15 @@ def try_fast_topk(engine, e, ev):
                          e.op == "topk", range_ticks, range_seconds,
                          l_cells, entry.spec.tps, fargs,
                          lookback_ticks)) as dcall:
-        packed_dev = _session_exec(entry, skey, dcall, lambda: dcall.run(
-            topk_prog,
-            entry.vals, entry.has, entry.tsg, smask, lo, hi, t_end,
-            fname=fname, k=kk, largest=e.op == "topk",
-            range_ticks=range_ticks, range_seconds=range_seconds,
-            l_cells=l_cells, tps=entry.spec.tps, fargs=fargs,
-            lookback_ticks=lookback_ticks,
-        ))
+        packed_dev = _session_exec(
+            entry, (win,), skey, dcall, lambda: dcall.run(
+                topk_prog,
+                entry.vals, entry.has, entry.tsg, smask, lo, hi, t_end,
+                fname=fname, k=kk, largest=e.op == "topk",
+                range_ticks=range_ticks, range_seconds=range_seconds,
+                l_cells=l_cells, tps=entry.spec.tps, fargs=fargs,
+                lookback_ticks=lookback_ticks,
+            ))
         packed = dcall.read(_readback.read_full, packed_dev)
     jj = packed.shape[0] // 3
     top_vals = packed[:jj].astype(np.float64)      # (J, k)
@@ -1636,8 +1926,8 @@ def try_fast_binary(engine, e, ev, *, agg=None):
     if not (any_l and any_r):
         _FAST_HITS.labels("hit").inc()
         return _empty_vector(ev)
-    lo_l, hi_l, t_end_l, rt_l, rs_l, lc_l = win_l
-    lo_r, hi_r, t_end_r, rt_r, rs_r, lc_r = win_r
+    lo_l, hi_l, t_end_l, rt_l, rs_l, lc_l, _w = win_l
+    lo_r, hi_r, t_end_r, rt_r, rs_r, lc_r, _w = win_r
     if gid is None:
         gid = jnp.zeros(entry_l.s_pad, jnp.int32)
     lookback_ticks = max(int(ev.lookback_ms // entry_l.spec.unit), 1)
@@ -1658,21 +1948,28 @@ def try_fast_binary(engine, e, ev, *, agg=None):
                                   rt_r, rs_l, rs_r, lc_l, lc_r,
                                   entry_l.spec.tps, fargs_l, fargs_r,
                                   lookback_ticks)) as dcall:
-        packed = _session_exec(entry_l, skey, dcall, lambda: dcall.run(
-            _fused_binary,
-            entry_l.vals, entry_l.has, entry_l.tsg, smask_l,
-            lo_l, hi_l, t_end_l,
-            entry_r.vals, entry_r.has, entry_r.tsg, smask_r,
-            lo_r, hi_r, t_end_r,
-            gid,
-            fname_l=fname_l, fname_r=fname_r, op=e.op,
-            bool_mod=bool(e.bool_mod), agg_op=agg_op, g=g,
-            range_ticks_l=rt_l, range_ticks_r=rt_r,
-            range_seconds_l=rs_l, range_seconds_r=rs_r,
-            l_cells_l=lc_l, l_cells_r=lc_r, tps=entry_l.spec.tps,
-            fargs_l=fargs_l, fargs_r=fargs_r,
-            lookback_ticks=lookback_ticks,
-        ))
+        def run():
+            # the right side's planes, where they are another entry's,
+            # are read under that entry's gate too
+            with (entry_r.gate.shared() if entry_r is not entry_l
+                  else contextlib.nullcontext()):
+                return dcall.run(
+                    _fused_binary,
+                    entry_l.vals, entry_l.has, entry_l.tsg, smask_l,
+                    lo_l, hi_l, t_end_l,
+                    entry_r.vals, entry_r.has, entry_r.tsg, smask_r,
+                    lo_r, hi_r, t_end_r,
+                    gid,
+                    fname_l=fname_l, fname_r=fname_r, op=e.op,
+                    bool_mod=bool(e.bool_mod), agg_op=agg_op, g=g,
+                    range_ticks_l=rt_l, range_ticks_r=rt_r,
+                    range_seconds_l=rs_l, range_seconds_r=rs_r,
+                    l_cells_l=lc_l, l_cells_r=lc_r, tps=entry_l.spec.tps,
+                    fargs_l=fargs_l, fargs_r=fargs_r,
+                    lookback_ticks=lookback_ticks,
+                )
+
+        packed = _session_exec(entry_l, (win_l, win_r), skey, dcall, run)
         packed_np = dcall.read(_readback.read_full, packed, np.float64)
     if agg_op:
         vals_np = packed_np[:g]

@@ -76,7 +76,6 @@ bounds, expression-valued aggregate args, quantiles).
 
 from __future__ import annotations
 
-import contextlib
 import functools
 import logging
 import math
@@ -94,6 +93,9 @@ from greptimedb_tpu.telemetry import tracing
 from greptimedb_tpu.telemetry.metrics import global_registry
 
 from greptimedb_tpu import concurrency
+from greptimedb_tpu.query.grid_upkeep import PlaneGate as _PlaneGate
+from greptimedb_tpu.query.grid_upkeep import cell_capacity as _cell_capacity
+from greptimedb_tpu.query.grid_upkeep import file_unless_appended
 
 _log = logging.getLogger("greptimedb_tpu.query.device_range")
 
@@ -141,19 +143,6 @@ _UPKEEP_ROWS = global_registry.counter(
     "rows scattered into resident range grid planes by the upkeep",
 )
 
-# time spent held at an entry's `_PlaneGate`, stamped only by a caller
-# that actually waits: a query behind the upkeep's donation ("shared"),
-# the upkeep behind the queries reading the planes or another upkeep
-# ("exclusive"). One connection never contends; many will.
-_GATE_WAIT = global_registry.counter(
-    "gtpu_plane_gate_wait_seconds_total",
-    "seconds callers waited at a range grid entry's plane gate, by the "
-    "side they asked for (shared: a query; exclusive: the upkeep)",
-    labels=("side",),
-)
-_GATE_WAIT_SHARED = _GATE_WAIT.labels("shared")
-_GATE_WAIT_EXCLUSIVE = _GATE_WAIT.labels("exclusive")
-
 DEVICE_THRESHOLD = 262_144       # min table rows before the cache pays off
 _CELL_CAP = 256 * 1024 * 1024    # max S*NB cells per cached array (1GB f32)
 _MAX_ENTRIES = 8                 # LRU entry-count cap across all tables
@@ -188,56 +177,6 @@ _STATE_KEYS = {
     "first_value": ("vf", "if", "vl", "il", "n"),
     "last_value": ("vf", "if", "vl", "il", "n"),
 }
-
-
-class _PlaneGate:
-    """Who may touch an entry's planes and what is filed about them:
-    any number of queries, from reading the plane references to the
-    return of their dispatch, and again to file a window's record or a
-    session's buffer (`shared`), or the one upkeep that donates the
-    planes to its program, and so deletes the arrays a query might
-    still hold, and forgets the windows it wrote to (`exclusive`)."""
-
-    def __init__(self):
-        self._cv = concurrency.Condition()
-        self._readers = 0
-        self._writer = False
-
-    @contextlib.contextmanager
-    def shared(self):
-        with self._cv:
-            if self._writer:
-                t0 = time.monotonic()
-                while self._writer:
-                    self._cv.wait()
-                _GATE_WAIT_SHARED.inc(time.monotonic() - t0)
-            self._readers += 1
-        try:
-            yield
-        finally:
-            with self._cv:
-                self._readers -= 1
-                if not self._readers:
-                    self._cv.notify_all()
-
-    @contextlib.contextmanager
-    def exclusive(self):
-        with self._cv:
-            t0 = (time.monotonic() if self._writer or self._readers
-                  else None)
-            while self._writer:
-                self._cv.wait()
-            self._writer = True
-            while self._readers:
-                self._cv.wait()
-            if t0 is not None:
-                _GATE_WAIT_EXCLUSIVE.inc(time.monotonic() - t0)
-        try:
-            yield
-        finally:
-            with self._cv:
-                self._writer = False
-                self._cv.notify_all()
 
 
 @dataclass
@@ -617,23 +556,6 @@ def _series_pad(s: int, mesh) -> int:
         n = mesh.shape[AXIS_SHARD]
         mult = mult * n // math.gcd(mult, n)
     return -(-s // mult) * mult
-
-
-_LANE = 128     # the chip tiles an array's last axis by this many
-
-
-def _cell_capacity(nb: int, fits) -> int:
-    """Cells to give a time axis whose data spans `nb`: a quarter more
-    (a geometric rule from the entry's own span: a table that holds
-    days has hours to spare, one that holds minutes has minutes), to a
-    whole lane tile once past one (the chip pads to it anyway), so that
-    appended ticks change no shape; `nb` itself where `fits(cells)`
-    refuses that (the cell cap, the byte budget): an entry that fits
-    without spare cells is still built, and rebuilds when it grows."""
-    cap = nb + max(nb // 4, 1)
-    if cap > _LANE:
-        cap = -(-cap // _LANE) * _LANE
-    return cap if fits(cap) else nb
 
 
 _NO_ROW = np.iinfo(np.int64).min
@@ -1559,17 +1481,6 @@ def _forget_windows_from(entry: _Entry, c0: int) -> None:
                            if k[1] <= c0}
     _sessions.global_sessions.purge_table(
         ("range", id(entry)), keep=lambda key: key[1][1] <= c0)
-
-
-def _file(entry: _Entry, seen: int, put, *args) -> None:
-    """File what a query learned from the planes as it read them when
-    `entry.appends` stood at `seen`: not if a batch was applied since,
-    whose purge has run and would have missed it (a later query, sent
-    after that batch's rows were acknowledged, would be answered
-    without them)."""
-    with entry.gate.shared():
-        if entry.appends == seen:
-            put(*args)
 
 
 def _append_batch(entry: _Entry, table, rows):
@@ -2860,9 +2771,10 @@ def execute_range_device(engine, plan, table):
             dcall.start(*crossing)
             dcall.wait(out_dev)
         if dispatch and use_sessions:
-            _file(entry, seen, sessions.global_sessions.put,
-                  session_tkey, session_key, entry.session_version, out_dev,
-                  int(out_dev.nbytes))
+            file_unless_appended(
+                entry, seen, sessions.global_sessions.put, session_tkey,
+                session_key, entry.session_version, out_dev,
+                int(out_dev.nbytes))
         host = dcall.read(readback.read_outputs, crossing,
                           delta=not rows_packed and j0_b > 0)
         readback_bytes = sum(x.nbytes for x in host)
@@ -2875,7 +2787,7 @@ def execute_range_device(engine, plan, table):
         if win is None:
             # (no record means this call dispatched: `seen` is its own)
             win = _fold_window(entry, memo, *extras)
-            _file(entry, seen, lambda: _memo_put(
+            file_unless_appended(entry, seen, lambda: _memo_put(
                 memo["windows"], win_key, win))
         # the exact window, from the exact extent of the selected rows:
         # the steps to keep of the bound window's

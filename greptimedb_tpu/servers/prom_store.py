@@ -131,7 +131,9 @@ def remote_write(instance, body: bytes, *, db: str = "public",
         if compressed:
             body = snappy.decompress(body)
         serieses = parse_write_request(body)
-    return len(serieses), apply_series(instance, serieses, db=db)
+    # labels to tag columns and the table's write, beside the decode
+    with tracing.child_span("prom_write.apply", series=len(serieses)):
+        return len(serieses), apply_series(instance, serieses, db=db)
 
 
 def apply_series(instance, serieses, *, db: str = "public") -> int:

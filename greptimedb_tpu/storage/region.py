@@ -222,6 +222,10 @@ class Region:
         # rows with a sequence at or above this are all in the active
         # memtable (`rows_since`); a flush's freeze moves it up
         self._memtable_floor_seq = self._seq
+        # the memtable the last flush froze and the floor it had: its
+        # rows stay readable by sequence after they reach their SST, so
+        # that a grid one flush behind is brought forward, not rebuilt
+        self._flushed_tail: tuple | None = None
         self._truncate_epoch = 0
         self._scan_cache: tuple | None = None  # (data_version, ColumnarRows)
         self._lock = concurrency.RLock()
@@ -366,19 +370,29 @@ class Region:
             return base_seq
 
     def rows_since(self, seq: int):
-        """What was written from sequence `seq` on, read from the
-        memtable by sequence with no scan of the region -> (rows, the
-        appends they came in, the sequence they reach up to). `rows` is
-        None when nothing was written, and False when a flush has moved
-        some of them out of the memtable. Taken under the write lock,
-        which a write holds from its sequence bump to its memtable
-        insert, so the rows are exactly those below the sequence
-        returned."""
+        """What was written from sequence `seq` on, read by sequence with
+        no scan of the region: from the memtable, and across one flush
+        from the memtable that flush froze -> (rows, the appends they
+        came in, the sequence they reach up to). `rows` is None when
+        nothing was written, and False when a second flush has moved
+        some of them out of reach. Taken under the write lock, which a
+        write holds from its sequence bump to its memtable insert, so
+        the rows are exactly those below the sequence returned."""
         with self._lock:
-            if seq < self._memtable_floor_seq:
+            if seq >= self._memtable_floor_seq:
+                rows, appends = self.memtable.rows_since(seq)
+                return rows, appends, self._seq
+            tail = self._flushed_tail
+            if tail is None or seq < tail[1]:
                 return False, 0, self._seq
-            rows, appends = self.memtable.rows_since(seq)
-            return rows, appends, self._seq
+            older, n_old = tail[0].rows_since(seq)
+            newer, n_new = self.memtable.rows_since(seq)
+            parts = [r for r in (older, newer) if r is not None]
+            if len(parts) == 2:
+                rows = _concat_rows(parts, self.meta.field_names)
+            else:
+                rows = parts[0] if parts else None
+            return rows, n_old + n_new, self._seq
 
     def _make_rows(self, tag_columns, ts, fields, field_valid, op, base_seq):
         """Intern tags and normalize fields into sid-resolved ColumnarRows.
@@ -506,6 +520,7 @@ class Region:
             )
             self._frozen.append(frozen)
             flushed_entry_id = self.wal.next_entry_id - 1
+            self._flushed_tail = (frozen, self._memtable_floor_seq)
             seq_now = self._memtable_floor_seq = self._seq
         rows = frozen.scan()
         file_id = uuid.uuid4().hex
@@ -727,6 +742,7 @@ class Region:
             _scan_pool.drop(self)
             self._truncate_epoch += 1
             self._memtable_floor_seq = self._seq
+            self._flushed_tail = None
             entry_id = self.wal.next_entry_id - 1
             self.memtable = Memtable(
                 self.meta.field_names,

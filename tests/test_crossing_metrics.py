@@ -29,6 +29,7 @@ from greptimedb_tpu.telemetry import device_programs as DP  # noqa: E402
 
 LATENCY, FLEET = "tsbs-single-groupby-1-1-1", "tsbs-double-groupby-all"
 LIVE, PROMQL = "tsbs-panel-under-ingest", "prom-100k-histogram-quantile"
+PROM_LIVE = "prom-100k-quantile-under-remote-write"
 
 # name -> (site, leg, the end-to-end metric it moves, its cells)
 METRICS = {
@@ -41,11 +42,11 @@ METRICS = {
     "upkeep_dispatch_ms_per_call": (
         "grid_upkeep", "dispatch", "query_p95_ms", [LIVE]),
     "hist_dispatch_ms_per_call": (
-        "promql_histogram", "dispatch", "query_p50_ms", [PROMQL]),
+        "promql_histogram", "dispatch", "query_p50_ms", [PROMQL, PROM_LIVE]),
     "hist_wait_ms_per_call": (
-        "promql_histogram", "wait", "query_p50_ms", [PROMQL]),
+        "promql_histogram", "wait", "query_p50_ms", [PROMQL, PROM_LIVE]),
     "hist_readback_ms_per_call": (
-        "promql_histogram", "readback", "query_p50_ms", [PROMQL]),
+        "promql_histogram", "readback", "query_p50_ms", [PROMQL, PROM_LIVE]),
 }
 
 RANGE_Q = ("SELECT ts, host, max(v) RANGE '60s' FROM cpu WHERE host = "

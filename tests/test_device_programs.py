@@ -456,17 +456,17 @@ def test_plane_gate_counts_only_a_wait_that_happened():
     import threading
     import time
 
-    from greptimedb_tpu.query import device_range as DR
+    from greptimedb_tpu.query import grid_upkeep as GU
 
-    gate = DR._PlaneGate()
-    shared0 = DR._GATE_WAIT_SHARED.value
-    excl0 = DR._GATE_WAIT_EXCLUSIVE.value
+    gate = GU.PlaneGate()
+    shared0 = GU.GATE_WAIT_SHARED.value
+    excl0 = GU.GATE_WAIT_EXCLUSIVE.value
     with gate.shared():
         pass
     with gate.exclusive():
         pass
-    assert DR._GATE_WAIT_SHARED.value == shared0
-    assert DR._GATE_WAIT_EXCLUSIVE.value == excl0
+    assert GU.GATE_WAIT_SHARED.value == shared0
+    assert GU.GATE_WAIT_EXCLUSIVE.value == excl0
     inside = threading.Event()
     waited = []
 
@@ -482,7 +482,7 @@ def test_plane_gate_counts_only_a_wait_that_happened():
         inside.set()
         time.sleep(0.2)
     th.join(10)
-    got = DR._GATE_WAIT_SHARED.value - shared0
+    got = GU.GATE_WAIT_SHARED.value - shared0
     assert 0.1 <= got <= waited[0] + 1e-3
     # and the other side: the upkeep behind a query holding the planes
     release = threading.Event()
@@ -500,7 +500,7 @@ def test_plane_gate_counts_only_a_wait_that_happened():
     with gate.exclusive():
         pass
     th.join(10)
-    assert DR._GATE_WAIT_EXCLUSIVE.value - excl0 >= 0.1
+    assert GU.GATE_WAIT_EXCLUSIVE.value - excl0 >= 0.1
     _status_text = global_registry.render()
     for side in ("shared", "exclusive"):
         assert ('gtpu_plane_gate_wait_seconds_total{side="%s"}' % side

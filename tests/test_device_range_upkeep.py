@@ -179,8 +179,12 @@ def _past_the_spare_cells(inst, tab, rng):
     _write(tab, rng, [TICKS * 3])
 
 
-def _flush_between(inst, tab, rng):
+def _two_flushes_between(inst, tab, rng):
+    """One flush is followed (`test_a_flush_between_is_followed`); a
+    second puts the first one's rows out of reach by sequence."""
     _write(tab, rng, [TICKS])
+    tab.flush()
+    _write(tab, rng, [TICKS + 1])
     tab.flush()
 
 
@@ -188,7 +192,7 @@ def _flush_between(inst, tab, rng):
     (_older_row, "out_of_order"), (_same_row_again, "out_of_order"),
     (_new_series, "new_series"), (_delete, "mutation"),
     (_truncate, "mutation"), (_alter, "mutation"),
-    (_past_the_spare_cells, "capacity"), (_flush_between, "flushed"),
+    (_past_the_spare_cells, "capacity"), (_two_flushes_between, "flushed"),
 ], ids=lambda v: v.__name__.strip("_") if callable(v) else v)
 def test_what_is_no_plain_append_rebuilds(inst, rng, change, reason):
     tab = inst.catalog.table("public", "cpu")
@@ -330,6 +334,44 @@ def test_rows_since_reads_by_sequence(inst, rng):
     assert sorted(rows.ts.tolist()) == [10 * STEP, 10 * STEP, 11 * STEP]
     assert rows.seq.min() == v0[0][0][0]
     tab.flush()
-    assert tab.appended_since(v0)[3] == "flushed"
+    # one flush: the frozen memtable's rows are still read by sequence
+    rows2, appends2, v2, reason = tab.appended_since(v0)
+    assert reason is None and (appends2, v2) == (2, v1)
+    assert sorted(rows2.ts.tolist()) == sorted(rows.ts.tolist())
     assert tab.appended_since(v1) == (None, 0, v1, None)
     assert region.rows_since(v1[0][0][0])[0] is None
+    # rows on both sides of the flush come back together, in order
+    _write(tab, rng, [12], hosts=[4])
+    rows3, appends3, _v3, reason = tab.appended_since(v0)
+    assert reason is None and appends3 == 3
+    assert rows3.ts.tolist()[-1] == 12 * STEP
+    s0 = v0[0][0][0]
+    assert sorted(rows3.seq.tolist()) == list(range(s0, s0 + 4))
+    tab.flush()
+    assert tab.appended_since(v0)[3] == "flushed"
+    assert tab.appended_since(v1)[3] is None
+
+
+@pytest.mark.parametrize("op", ["max", "avg", "last_value"])
+def test_a_flush_between_is_followed(inst, rng, op):
+    """A flush between the write and the query moves the rows out of
+    the memtable; the upkeep reads them from the memtable the flush
+    froze and appends, and the planes are those of a build."""
+    tab = inst.catalog.table("public", "cpu")
+    _write(tab, rng, range(TICKS))
+    inst.sql(_q(op))
+    engine = inst.query_engine
+    entry = _entry(engine)
+    appended, flushed = _count("append"), _count("rebuild_flushed")
+    _write(tab, rng, [TICKS])
+    tab.flush()
+    _write(tab, rng, [TICKS + 1], hosts=range(3))
+    r = inst.sql(_q(op))
+    assert _entry(engine) is entry
+    assert (_count("append"), _count("rebuild_flushed")) == (
+        appended + 1, flushed)
+    fresh_engine = QueryEngine(prefer_device=True)
+    fresh_engine.persist_device_cache = False
+    inst.query_engine = fresh_engine
+    assert inst.sql(_q(op)).rows() == r.rows()
+    _assert_bit_equal(entry, _entry(fresh_engine))

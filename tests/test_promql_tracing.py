@@ -69,6 +69,16 @@ def prom(tmp_path):
         assert len(doc["data"]["result"][0]["values"]) == 17
         return _finished_trace(tid)
 
+    def write_next_scrape(tid):
+        """One scrape more of every instance (the last values again,
+        15 s later): a plain append of the grid's table."""
+        ds.ts[:, -1] += dg.INTERVAL_MS
+        for body in dg.scrape_bodies(np, ds, ds.scrapes - 1, np.arange(16),
+                                     heads):
+            post("/v1/prometheus/write", body, **dg._RW_HEADERS,
+                 traceparent=f"00-{tid}-{'cd' * 8}-01")
+
+    ask.write_next_scrape = write_next_scrape
     try:
         yield srv.port, ask
     finally:
@@ -130,5 +140,36 @@ def test_remote_write_decode_has_its_span(prom):
     assert names.count("http.read") == 1 and names.count("http.send") == 1
     decode = next(s for s in spans if s["name"] == "prom_write.decode")
     assert decode["attributes"]["body_bytes"] > 0
-    # the write path's own stages beneath the same request
+    # the write path's own stages beneath the same request, under the
+    # apply (labels to tag columns, the table's write) beside the decode
     assert {"wal.append", "memtable.append"} <= set(names)
+    by_id = {s["span_id"]: s for s in spans}
+    (apply,) = [s for s in spans if s["name"] == "prom_write.apply"]
+    assert apply["attributes"]["series"] == 16 * 12
+    assert apply["parent_id"] == decode["parent_id"]
+    wal = next(s for s in spans if s["name"] == "wal.append")
+    up = wal
+    while up["name"] != "prom_write.apply":
+        up = by_id[up["parent_id"]]
+
+
+def test_a_query_after_a_write_brings_the_grid_forward(prom):
+    """`promql.resolve` says grid_cache=upkeep and holds `promql.upkeep`
+    (outcome, samples, cells_new) with the append's `device.execute`
+    (site promql_grid_upkeep) beneath it."""
+    _port, ask = prom
+    ask(0.9, 9, "c3" * 16)
+    ask.write_next_scrape("d4" * 16)
+    spans = ask(0.5, 10, "e5" * 16)
+    (resolve,) = [s for s in spans if s["name"] == "promql.resolve"]
+    assert resolve["attributes"]["grid_cache"] == "upkeep"
+    (upkeep,) = [s for s in spans if s["name"] == "promql.upkeep"]
+    assert upkeep["parent_id"] == resolve["span_id"]
+    # the grid has no spare cells: the first scrape past it grows it
+    assert upkeep["attributes"]["outcome"] == "grow"
+    assert upkeep["attributes"]["samples"] == 16 * 12
+    assert upkeep["attributes"]["cells_new"] == 1
+    sites = [s["attributes"]["site"] for s in spans
+             if s["name"] == "device.execute"
+             and s["parent_id"] == upkeep["span_id"]]
+    assert sites == ["promql_grid_upkeep"] * 2    # the growth, the append

@@ -229,7 +229,8 @@ SHARES = {
          "tsbs-panel-under-ingest"]),
     "hist_readback_started_share": (
         "promql_histogram", "promql/fast.py",
-        ["prom-100k-histogram-quantile"]),
+        ["prom-100k-histogram-quantile",
+         "prom-100k-quantile-under-remote-write"]),
 }
 
 

@@ -594,11 +594,12 @@ def test_span_time_is_exported_by_name(panel, monkeypatch):
     # thread CPU is read for one request in `_CPU_EVERY` and scaled up:
     # read every one here, so that three requests give exact numbers
     monkeypatch.setattr(tracing, "_CPU_EVERY", 1)
+    import time
+
+    time.sleep(0.1)     # the fixture's last root closes after its byte
     m0 = _render()
     for h in (5, 6, 7):
         _post_sql(port, query(h))
-    import time
-
     time.sleep(0.1)     # the roots close after the last byte
     m1 = _render()
 
