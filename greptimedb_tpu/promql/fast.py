@@ -51,7 +51,7 @@ from greptimedb_tpu.telemetry.metrics import global_registry
 
 from greptimedb_tpu import concurrency
 from greptimedb_tpu.query.grid_upkeep import (
-    PlaneGate, cell_capacity, file_unless_appended,
+    PlaneGate, cell_capacity, file_unless_appended, lane_cells,
 )
 
 # range functions computable from per-series prefix sums: O(S*T) memory,
@@ -87,6 +87,19 @@ _GRID_ENTRIES = global_registry.counter(
     "do not sit one to a cell or the span outgrows the byte budget",
     ("outcome",),
 )
+# the cells of the selector grids built: those the data spans ("data"),
+# and the empty ones after them that make the time axis whole lane
+# tiles ("lane_pad", 0 where `lane_cells` leaves a count as it is or
+# the budget refuses the rounded one). Both read 0 from the import
+_BUILD_CELLS = global_registry.counter(
+    "gtpu_promql_grid_build_cells_total",
+    "cells of the time axis of the selector grids built: data (the span "
+    "the samples reach) or lane_pad (empty cells after them, to a whole "
+    "number of lane tiles)",
+    ("kind",),
+)
+_BUILD_DATA_CELLS = _BUILD_CELLS.labels("data")
+_BUILD_LANE_PAD = _BUILD_CELLS.labels("lane_pad")
 
 
 # what became of an entry that a query found behind its table's version:
@@ -123,6 +136,21 @@ def _budget_bytes() -> int:
     return int(os.environ.get(
         "GREPTIMEDB_TPU_PROMQL_CACHE_BYTES", 4 * 1024**3
     ))
+
+
+def _axis_fits(s_pad: int, spec):
+    """Whether planes of `cells` cells may be held for a grid of `s_pad`
+    series rows on `spec`'s origin and resolution: within the build's
+    budget (half the cache's bytes), at the tick unit `spec` holds."""
+    from greptimedb_tpu.ops import grid as G
+
+    limit = max(_budget_bytes() // 2 // (9 * s_pad), 16)
+
+    def fits(cells):
+        return cells <= limit and G.GridSpec.build(
+            spec.t0, spec.res, cells).unit == spec.unit
+
+    return fits
 
 
 def _pow2_bucket(n: int, minimum: int = 8) -> int:
@@ -465,6 +493,7 @@ def _build_entry(table, fieldname: str, version, mesh=None,
         s_pad = max(s_pad, mesh.shape[AXIS_SHARD])
     nc = int(-((-(t_max - t0)) // res)) + 1
     spec = G.GridSpec.build(t0, res, nc)
+    fits = _axis_fits(s_pad, spec)
     cell = spec.cell_of(ts).astype(np.int32)
     # exactness: a grid holds one sample a cell. A table whose span
     # outgrows half the cache budget at its cadence, or a series with
@@ -472,7 +501,7 @@ def _build_entry(table, fieldname: str, version, mesh=None,
     # no grid: the generic engine answers, and the refusal is kept so
     # that the table is not scanned again until its data changes
     refused = ""
-    if nc > max(_budget_bytes() // 2 // (9 * s_pad), 16):
+    if not fits(nc):
         refused = "budget"
     elif bool((in_series & (np.diff(cell) == 0)).any()):
         refused = "irregular"
@@ -482,6 +511,13 @@ def _build_entry(table, fieldname: str, version, mesh=None,
             table, fieldname, version, registry, None, None, None, None,
             s, s_pad, 0, refused=refused,
         )
+    # the planes in whole lane tiles where that fits too: the cells
+    # after the data are empty, as a build leaves any cell no sample
+    # reaches, so every window reads the same samples in the same order
+    cells = lane_cells(nc)
+    if not fits(cells):
+        cells = nc
+    spec = G.GridSpec.build(t0, res, cells)
 
     tsrel = spec.device_ts(ts)
     mask = np.ones(len(ts), bool)
@@ -493,7 +529,7 @@ def _build_entry(table, fieldname: str, version, mesh=None,
         jnp.asarray(tsrel),
         jnp.asarray(np.asarray(vals_np, np.float32)),
         jnp.asarray(mask),
-        s_pad, nc,
+        s_pad, cells,
     )
     if mesh is not None:
         # resident grids shard over the series axis; queries then run
@@ -504,15 +540,17 @@ def _build_entry(table, fieldname: str, version, mesh=None,
         gvals = jax.device_put(gvals, sh2)
         ghas = jax.device_put(ghas, sh2)
         gtsg = jax.device_put(gtsg, sh2)
-    nbytes = s_pad * nc * 9
+    nbytes = s_pad * cells * 9
     # the grid BUILD is the big host->device transfer of this path:
     # attribute it on the trace (a first query over a cold selector
     # pays it; steady-state queries hit the resident grid)
     with tracing.child_span("device.upload", site="promql_grid",
-                            upload_bytes=nbytes):
+                            upload_bytes=nbytes, cells=cells, cells_data=nc):
         gvals.block_until_ready()
     _FAST_HITS.labels("grid_build").inc()
     _GRID_ENTRIES.labels("built").inc()
+    _BUILD_DATA_CELLS.inc(nc)
+    _BUILD_LANE_PAD.inc(cells - nc)
     global_registry.gauge(
         "greptime_promql_grid_build_seconds",
         "wall seconds of the last selector grid build",
@@ -591,8 +629,6 @@ def _append_batch(entry: _Entry, rows):
     """The samples written since the entry's stamp as the append program
     takes them -> (batch, None), or (None, reason) where they are no
     plain append of this grid."""
-    from greptimedb_tpu.ops import grid as G
-
     sid, ts = rows.sid.astype(np.int64), np.asarray(rows.ts, np.int64)
     if int(sid.max()) >= entry.num_series:
         return None, "new_series"
@@ -619,13 +655,7 @@ def _append_batch(entry: _Entry, rows):
     need = int(cell.max()) + 1
     nc = spec.num_cells
     if need > nc:
-        limit = max(_budget_bytes() // 2 // (9 * entry.s_pad), 16)
-
-        def fits(cells):
-            # the build's budget, and the tick unit the planes hold
-            return cells <= limit and G.GridSpec.build(
-                spec.t0, spec.res, cells).unit == spec.unit
-
+        fits = _axis_fits(entry.s_pad, spec)
         nc = cell_capacity(need, fits)
         if not fits(nc):
             return None, "capacity"

@@ -8,7 +8,9 @@ to one program, and so need:
 - a gate (`PlaneGate`) that shuts queries out of the planes while the
   upkeep donates them, and out of filing what they read from planes
   that the upkeep has since replaced (`file_unless_appended`);
-- one rule for how many cells a time axis is given (`cell_capacity`).
+- one rule for how many cells a time axis is given: whole lane tiles
+  (`lane_cells`), and a quarter more where it is to take appends
+  (`cell_capacity`).
 """
 
 from __future__ import annotations
@@ -85,17 +87,23 @@ class PlaneGate:
                 self._cv.notify_all()
 
 
+def lane_cells(n: int) -> int:
+    """`n` cells as the chip lays a time axis out: past one lane tile,
+    rounded up to a whole number of tiles (the chip pads the last axis
+    to one anyway, and a whole-plane fusion runs faster over whole
+    tiles than over a ragged last one); within one tile, `n` itself."""
+    return -(-n // LANE) * LANE if n > LANE else n
+
+
 def cell_capacity(nb: int, fits) -> int:
     """Cells to give a time axis whose data spans `nb`: a quarter more
     (a geometric rule from the entry's own span: a table that holds
-    days has hours to spare, one that holds minutes has minutes), to a
-    whole lane tile once past one (the chip pads to it anyway), so that
-    appended ticks change no shape; `nb` itself where `fits(cells)`
-    refuses that (the cell cap, the byte budget): an entry that fits
-    without spare cells is still built, and rebuilds when it grows."""
-    cap = nb + max(nb // 4, 1)
-    if cap > LANE:
-        cap = -(-cap // LANE) * LANE
+    days has hours to spare, one that holds minutes has minutes), in
+    whole lane tiles (`lane_cells`), so that appended ticks change no
+    shape; `nb` itself where `fits(cells)` refuses that (the cell cap,
+    the byte budget): an entry that fits without spare cells is still
+    built, and rebuilds when it grows."""
+    cap = lane_cells(nb + max(nb // 4, 1))
     return cap if fits(cap) else nb
 
 

@@ -32,6 +32,7 @@ from greptimedb_tpu.parallel import mesh as M  # noqa: E402
 from greptimedb_tpu.promql import fast as F  # noqa: E402
 from greptimedb_tpu.promql.engine import PromEngine  # noqa: E402
 from greptimedb_tpu.query import sessions  # noqa: E402
+from greptimedb_tpu.query.grid_upkeep import LANE  # noqa: E402
 from greptimedb_tpu.servers import prom_store  # noqa: E402
 
 REF = reference(load_json(ROOT, "benchmark", "configs",
@@ -219,13 +220,14 @@ def test_each_write_is_followed_bit_for_bit(inst, ds):
 
 
 def test_growth_past_the_axis_keeps_the_answers(inst, ds):
-    """The held grid has no spare cells: the first live scrape past
-    its end lengthens the axis on the device (counted `grow`), by the
-    capacity rule, once; a grid that sees no write keeps its shape."""
+    """The held grid spans less than one lane tile, so it has no spare
+    cells: the first live scrape past its end lengthens the axis on the
+    device (counted `grow`), by the capacity rule, once; a grid that
+    sees no write keeps its shape."""
     _ask(inst, "quantile", _end(ds, 0))
     entry = _entry()
     built = entry.spec.num_cells
-    assert built == entry.data_cells          # no spare cells up front
+    assert built == entry.data_cells <= LANE   # no spare cells up front
     assert entry.nbytes == entry.s_pad * built * 9
     assert np.asarray(entry.vals).shape[1] == built
     grown = _count("grow")
@@ -246,6 +248,39 @@ def test_growth_past_the_axis_keeps_the_answers(inst, ds):
     # part takes one growth more, and no rebuild
     assert _count("grow") == grown + 2
     assert sum(_count("rebuild_" + r) for r in F._REBUILD_REASONS) == rebuilds
+
+
+def test_a_held_grid_past_one_tile_takes_the_live_scrapes_as_appends(
+        tmp_path):
+    """45 held minutes span 183 cells, built in two whole lane tiles
+    (256): every live scrape of the next 15 minutes, up to cell 243,
+    lands in the empty cells after the data, counted `append`, never
+    `grow`, and the kept grid answers as a fresh build."""
+    ds_h = live.make(np, 2**31 + 41, {"instances": 4, "minutes": 60,
+                                      "held_minutes": 45})
+    i = _serve(tmp_path, ds_h)
+    try:
+        _ask(i, "quantile", _end(ds_h, 0))
+        entry = _entry()
+        assert (entry.data_cells, entry.spec.num_cells) == (183, 256)
+        grown, appended = _count("grow"), _count("append")
+        per = 480
+        last = live.requests(ds_h, per)
+        acked, r, asked = 0, 0, 0
+        while r < last:
+            acked = _write(i, ds_h, r, min(r + 2, last), per=per)
+            r += 2
+            _ask(i, "rate", _end(ds_h, acked))
+            asked += 1
+        assert acked == ds_h.live_samples
+        assert _entry() is entry
+        assert (entry.data_cells, entry.spec.num_cells) == (243, 256)
+        assert _count("grow") == grown
+        assert _count("append") == appended + asked
+        _check_against_fresh_and_reference(i, ds_h, acked)
+    finally:
+        i.close()
+        F.invalidate_cache()
 
 
 def test_a_flush_between_write_and_query_is_followed(inst, ds):
@@ -336,6 +371,8 @@ def test_an_overtaken_query_leaves_no_stale_record(inst, ds, monkeypatch):
 
 
 def test_a_grid_that_sees_no_write_keeps_its_shape_and_bytes(inst, ds):
+    """Built within one lane tile, the grid holds its data's cells and
+    no more, and keeps that shape and its bytes while no write comes."""
     end = _end(ds, 0)
     _ask(inst, "quantile", end)
     entry = _entry()
@@ -344,7 +381,7 @@ def test_a_grid_that_sees_no_write_keeps_its_shape_and_bytes(inst, ds):
         _ask(inst, "quantile", end - 4 * STEP, phi=phi)
     assert _entry() is entry
     assert (np.asarray(entry.vals).shape, entry.nbytes) == (shape, nbytes)
-    assert shape[1] == entry.data_cells
+    assert shape[1] == entry.data_cells <= LANE
 
 
 def test_another_metric_on_the_physical_table(inst, ds):
